@@ -170,7 +170,8 @@ def main() -> None:
     if args.check_only:
         raise SystemExit(1 if check_records() else 0)
 
-    from repro.runtime import telemetry
+    from repro.runtime import compile_cache, telemetry
+    compile_cache.configure(_ROOT)
     if args.telemetry:
         telemetry.enable()
 

@@ -204,7 +204,7 @@ class CholeskyFactor:
         return 2.0 * total
 
 
-def _corner_dense_cholesky(c: jnp.ndarray, impl: Optional[str]) -> jnp.ndarray:
+def _corner_dense_cholesky(c: jnp.ndarray) -> jnp.ndarray:
     """Blocked dense Cholesky of the (nat, nat, t, t) corner.
 
     Left-looking over columns as a single ``lax.fori_loop``: each step does
@@ -213,6 +213,9 @@ def _corner_dense_cholesky(c: jnp.ndarray, impl: Optional[str]) -> jnp.ndarray:
     O(nat) instead of the O(nat²) of the previous Python-unrolled tile
     loops — the difference between seconds and minutes of XLA compile for
     thick arrows — while tiny corners lower to the same handful of kernels.
+    Its tile ops are always the jnp ones: the corner is small and dense,
+    so XLA's own Cholesky and triangular solve serve it, and the whole
+    factorization stays one Pallas launch on the fused path.
     """
     nat, t = c.shape[0], c.shape[-1]
     rows = jnp.arange(nat)
@@ -223,12 +226,12 @@ def _corner_dense_cholesky(c: jnp.ndarray, impl: Optional[str]) -> jnp.ndarray:
         rk = jnp.where(done, row_k, 0.0)                # L[k, :k], zero-padded
         ckk = jax.lax.dynamic_slice(c, (k, k, 0, 0), (1, 1, t, t))[0, 0]
         syrk_acc = jnp.einsum("jab,jcb->ac", rk, rk, precision=_HI)
-        lkk = ops.potrf(ckk - syrk_acc, impl=impl)
+        lkk = ops.potrf(ckk - syrk_acc, impl="ref")
         col_k = jax.lax.dynamic_slice(c, (0, k, 0, 0), (nat, 1, t, t))[:, 0]
         # masked rk zeroes the j>=k terms, so unfactorized columns of c
         # contribute nothing to the GEMM accumulation
         gemm_acc = jnp.einsum("mjab,jcb->mac", c, rk, precision=_HI)
-        panel = ops.trsm(lkk, col_k - gemm_acc, impl=impl)
+        panel = ops.trsm(lkk, col_k - gemm_acc, impl="ref")
         new_col = jnp.where((rows > k)[:, None, None], panel,
                             jnp.where((rows == k)[:, None, None],
                                       lkk[None], col_k))
@@ -386,7 +389,7 @@ def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
         if nat:
             # one Schur leaf per partition: combine them with the Alg. 3
             # binary tree before the shared separator/corner factorization
-            C_out = _corner_dense_cholesky(C - tree_combine(schur), impl)
+            C_out = _corner_dense_cholesky(C - tree_combine(schur))
         else:
             C_out = C
         return Dr_out, R_out, C_out, fold_corner_status(
@@ -399,7 +402,7 @@ def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
         status = sweep_status(Dr_out, R_out)
         if nat:
             C_out = _corner_dense_cholesky(
-                C - _corner_schur(R_out, tree_chunks), impl)
+                C - _corner_schur(R_out, tree_chunks))
         else:
             C_out = C
         return Dr_out, R_out, C_out, fold_corner_status(
@@ -414,7 +417,7 @@ def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
     if nat:
         # the chunks are the tree-reduction leaves; summing them is the
         # root combine of the paper's Alg. 3 chain
-        C_out = _corner_dense_cholesky(C - jnp.sum(schur, axis=0), impl)
+        C_out = _corner_dense_cholesky(C - jnp.sum(schur, axis=0))
     else:
         C_out = C
     return Dr_out, R_out, C_out, fold_corner_status(

@@ -14,7 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from .cholesky import (CholeskyFactor, _factorize_window_impl,
                        factorize_window_batched)
@@ -65,6 +65,18 @@ def stack_ctsf(mats: list, policy=None) -> BandedCTSF:
     )
 
 
+def _per_device(fn, mesh: Mesh, axis: str, n_out: int):
+    """jit ``fn`` (vmapped over a batch of whole problems) so each device
+    along ``axis`` runs it on its own slice of the batch.  The Pallas
+    sweeps inside cannot be partitioned by the compiler, so the batch is
+    split explicitly with ``shard_map``; every output keeps the batch
+    sharding."""
+    spec = P(axis)
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                                 out_specs=(spec,) * n_out,
+                                 check_vma=False))
+
+
 def concurrent_factorize(batch: BandedCTSF, mesh: Optional[Mesh] = None,
                          axis: str = "data", impl=UNSET,
                          tree_chunks: int = 8,
@@ -86,8 +98,8 @@ def concurrent_factorize(batch: BandedCTSF, mesh: Optional[Mesh] = None,
     ``regularize`` (bool or :class:`~repro.core.robustness.RegularizePolicy`)
     enables per-element breakdown recovery: the escalating-jitter ladder
     retries only the failed elements (on the mesh path the retries ride
-    the same sharded callable — the per-sweep status words are replicated
-    host-side, everything else stays sharded) and the returned
+    the same sharded callable; only the (B, 3) status words are read back
+    to the host) and the returned
     ``factor.info`` flags each element OK / RECOVERED / FAILED instead of
     one bad θ-candidate raising mid-sweep.
     """
@@ -113,11 +125,7 @@ def concurrent_factorize(batch: BandedCTSF, mesh: Optional[Mesh] = None,
         fn = jax.vmap(
             lambda dr, r, c: _factorize_window_impl(
                 dr, r, c, batch.grid, impl, tree_chunks, sweep, 0, plan))
-    spec = (NamedSharding(mesh, P(axis)),) * 3
-    # the (B, 3) status words are tiny — replicate them so the ladder's
-    # host readback never gathers factor data
-    st_spec = NamedSharding(mesh, P())
-    fn = jax.jit(fn, in_shardings=spec, out_shardings=spec + (st_spec,))
+    fn = _per_device(fn, mesh, axis, n_out=4)
     if pol is None:
         dr, r, c, _status = fn(batch.Dr, batch.R, batch.C)
         info = None
@@ -200,9 +208,8 @@ def concurrent_selinv(factor: CholeskyFactor, mesh: Optional[Mesh] = None,
             lambda dr, r, c: _selinv_impl(dr, r, c, g, impl, start))
     else:
         fn = jax.vmap(lambda dr, r, c: _selinv_impl(dr, r, c, g, impl))
-    spec = (NamedSharding(mesh, P(axis)),) * 3
-    fn = jax.jit(fn, in_shardings=spec, out_shardings=spec)
-    sd, sr, sc = fn(ctsf.Dr, ctsf.R, ctsf.C)
+    sd, sr, sc = _per_device(fn, mesh, axis, n_out=3)(ctsf.Dr, ctsf.R,
+                                                      ctsf.C)
     out = SelectedInverse(g, sd, sr, sc)
     if src is not None:
         from .gridpolicy import restrict_selinv

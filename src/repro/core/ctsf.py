@@ -42,9 +42,7 @@ def _dense_padded(mat: sp.spmatrix, grid: TileGrid) -> np.ndarray:
     coo = sp.coo_matrix(mat)
     n_pad = grid.padded_n
     out = np.zeros((n_pad, n_pad), dtype=np.float64)
-    pi = np.vectorize(grid.padded_index, otypes=[np.int64])
-    r, c = pi(coo.row), pi(coo.col)
-    out[r, c] = coo.data
+    out[grid.padded_index(coo.row), grid.padded_index(coo.col)] = coo.data
     # pad diagonal with identity so padded tiles stay SPD
     for k in range(grid.structure.n_diag, grid.n_diag_tiles * grid.t):
         out[k, k] = 1.0
@@ -123,8 +121,33 @@ class BandedCTSF:
 
     @classmethod
     def from_sparse(cls, mat: sp.spmatrix, grid: TileGrid) -> "BandedCTSF":
-        dense = _dense_padded(mat, grid)
-        return cls.from_dense_padded(dense, grid)
+        """Scatter the COO entries straight into ``Dr``/``R``/``C`` (the
+        layout :meth:`from_dense_padded` reads out of the padded dense
+        matrix, without materializing it): entries outside the stored tiles
+        — upper-triangle tiles, band tiles past ``band_tiles`` — are
+        dropped, and padding diagonals are set to 1 so padded tiles stay
+        SPD.  Host memory is the CTSF arrays plus O(nnz) indices."""
+        t, ndt, nat, bt = grid.t, grid.n_diag_tiles, grid.n_arrow_tiles, grid.band_tiles
+        s = grid.structure
+        coo = sp.coo_matrix(mat)
+        rt, ri = np.divmod(grid.padded_index(coo.row), t)
+        ct, ci = np.divmod(grid.padded_index(coo.col), t)
+        v = coo.data
+        Dr = np.zeros((ndt, bt + 1, t, t), dtype=np.float32)
+        R = np.zeros((ndt, nat, t, t), dtype=np.float32)
+        C = np.zeros((nat, nat, t, t), dtype=np.float32)
+        band = (rt < ndt) & (ct <= rt) & (rt - ct <= bt)
+        Dr[rt[band], (rt - ct)[band], ri[band], ci[band]] = v[band]
+        arrow = (rt >= ndt) & (ct < ndt)
+        R[ct[arrow], rt[arrow] - ndt, ri[arrow], ci[arrow]] = v[arrow]
+        corner = (rt >= ndt) & (ct >= ndt) & (ct <= rt)
+        C[rt[corner] - ndt, ct[corner] - ndt, ri[corner], ci[corner]] = \
+            v[corner]
+        pad = np.arange(s.n_diag, ndt * t)
+        Dr[pad // t, 0, pad % t, pad % t] = 1.0
+        pad = np.arange(s.arrow, nat * t)
+        C[pad // t, pad // t, pad % t, pad % t] = 1.0
+        return cls(grid, jnp.asarray(Dr), jnp.asarray(R), jnp.asarray(C))
 
     @classmethod
     def eye(cls, grid: TileGrid) -> "BandedCTSF":

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.sharding.collectives import tree_allreduce
 from .cholesky import (CholeskyFactor, _band_arrow_sweep_ring,
@@ -106,7 +105,7 @@ def distributed_factorize(pm: PartitionedCTSF, mesh: Mesh, axis: str = "model",
         if nat:
             partial = sch.sum(axis=(0, 1))             # parts x chunks
             schur = tree_allreduce(partial, axis)      # GEADD tree on ICI
-            c_l = _corner_dense_cholesky(c - schur, impl)
+            c_l = _corner_dense_cholesky(c - schur)
         else:
             c_l = c
         return dr_l, r_l, c_l
@@ -115,16 +114,10 @@ def distributed_factorize(pm: PartitionedCTSF, mesh: Mesh, axis: str = "model",
     spec_rep = P()
     # check_vma=False: the ppermute GEADD tree yields replicated values, but
     # that can't be statically inferred (only psum can); we assert it in tests.
-    try:
-        fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                        in_specs=(spec_part, spec_part, spec_rep),
                        out_specs=(spec_part, spec_part, spec_rep),
                        check_vma=False)
-    except TypeError:  # older jax spelling
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(spec_part, spec_part, spec_rep),
-                       out_specs=(spec_part, spec_part, spec_rep),
-                       check_rep=False)
     dr, r, c = jax.jit(fn)(pm.Dr, pm.R, pm.C)
     return PartitionedCTSF(grid, pm.n_parts, dr, r, c)
 

@@ -108,8 +108,7 @@ class SelectedInverse:
             full = db
         if padded:
             return full
-        idx = np.vectorize(g.padded_index, otypes=[np.int64])(
-            np.arange(g.structure.n))
+        idx = g.padded_index(np.arange(g.structure.n))
         return jnp.take(full, jnp.asarray(idx), axis=-1)
 
     def covariance(self, i: int, j: int) -> jnp.ndarray:

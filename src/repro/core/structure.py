@@ -150,16 +150,17 @@ class TileGrid:
     def elem_to_tile(self, i: int, j: int) -> Tuple[int, int]:
         return i // self.t, j // self.t
 
-    def padded_index(self, i: int) -> int:
-        """Map an element index of the original matrix into the padded one.
+    def padded_index(self, i):
+        """Map element indices of the original matrix into the padded one
+        (an int for an int, an int64 array for an array).
 
         Diagonal part occupies [0, n_diag) -> [0, n_diag) (pad after), arrow
         part occupies [n_diag, n) -> [n_diag_tiles*t, ...).
         """
-        s = self.structure
-        if i < s.n_diag:
-            return i
-        return self.n_diag_tiles * self.t + (i - s.n_diag)
+        nd = self.structure.n_diag
+        idx = np.asarray(i, dtype=np.int64)
+        out = np.where(idx < nd, idx, self.n_diag_tiles * self.t + (idx - nd))
+        return int(out) if out.ndim == 0 else out
 
 
 def measure_arrowhead(pattern: sp.spmatrix, arrow_hint: Optional[int] = None,
@@ -211,9 +212,8 @@ def tile_pattern_from_coo(pattern: sp.spmatrix, grid: TileGrid) -> np.ndarray:
     coo = sp.coo_matrix(pattern)
     nt = grid.n_tiles
     out = np.zeros((nt, nt), dtype=bool)
-    pi = np.vectorize(grid.padded_index, otypes=[np.int64])
-    r = pi(np.maximum(coo.row, coo.col))
-    c = pi(np.minimum(coo.row, coo.col))
+    r = grid.padded_index(np.maximum(coo.row, coo.col))
+    c = grid.padded_index(np.minimum(coo.row, coo.col))
     out[r // grid.t, c // grid.t] = True
     out[np.arange(nt), np.arange(nt)] = True  # diagonal tiles always exist
     return np.tril(out)

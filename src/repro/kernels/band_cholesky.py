@@ -31,8 +31,10 @@ the paper's left-looking accumulator reading of GEMM chains (§II):
   Alg. 3 tree-reduction association).
 
 VMEM budget per step: the panel ring bt·(bt+1)·t², the arrow ring
-bt·nat·t², the Schur accumulator nat²·t² and the (bt+1+nat)·t² in/out
-blocks — e.g. bt=8, t=128, nat=2: ~6.1 MB, under the ~16 MB/core of v5e.
+bt·nat·t², the Schur accumulator nat²·t², the (bt+1+nat)·t² in/out blocks
+and the step's live values; the kernel asks Mosaic for exactly that
+(``_compiler_params``) — e.g. 35.6 MiB at bt=16, t=128, nat=2, of the
+128 MiB of VMEM on a v5e core.
 
 Matches ``ref.band_cholesky_sweep_ref`` (the lax.scan oracle) to fp32
 tolerance; ``kernels.ops.band_cholesky_sweep`` dispatches between them.
@@ -47,10 +49,106 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .potrf import factorize_tile
-from .ring import chunk_layout, identity_prefix_panel, ring_read, ring_write
+from .ring import (chunk_layout, identity_prefix_panel, ring_read, ring_write,
+                   sweep_compiler_params, tile_dot)
 from .trsm import substitute_right
 
 __all__ = ["band_cholesky_sweep_pallas", "band_cholesky_partitioned_sweep_pallas"]
+
+
+# The breakdown status word [min_pivot, nonfinite, first_bad] rides the
+# sweep as one (8, 128) VMEM vector — lanes 0, 1 and 2 hold the three
+# entries — because Mosaic cannot store scalars to VMEM and a (1, 3) block
+# of a (P, 3) array breaks the TPU block-shape rule.  Its output block has
+# a constant index map within a sweep, so it stays resident across the
+# sequential grid and doubles as the carry.
+_ST_SHAPE = (8, 128)
+
+
+def _status_init(st_ref):
+    lane = jax.lax.broadcasted_iota(jnp.int32, _ST_SHAPE, 1)
+    st_ref[0] = jnp.where(lane == 0, jnp.inf,
+                          jnp.where(lane == 1, 0.0, -1.0)).astype(jnp.float32)
+
+
+def _status_fold(st_ref, piv, nonfinite, bad, col):
+    """Fold one column into the carry: the per-column update
+    ``ref.sweep_status`` applies to the emitted factor, so both backends
+    report identical words."""
+    st = st_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, _ST_SHAPE, 1)
+    colv = jnp.full(_ST_SHAPE, col, jnp.int32).astype(jnp.float32)
+    st_ref[0] = jnp.where(
+        lane == 0, jnp.minimum(st, piv),
+        jnp.where(lane == 1, jnp.maximum(st, nonfinite),
+                  jnp.where((st < 0.0) & bad, colv, st)))
+
+
+def _cholesky_column(ac_ref, r_ref, p_ref, ro_ref, sch_ref, st_ref,
+                     ring_ref, ringa_ref, sacc_ref, k, col,
+                     *, bt: int, nat_p: int):
+    """One left-looking column step shared by both sweep kernels.  ``k``
+    drives the rings (the local column of the partition); ``col`` is the
+    global column the status word records."""
+    t = ac_ref.shape[-1]
+    # rhs_j = L[k, k-j] = panel_{k-j}[j], read from the VMEM ring (zeros for
+    # k-j < 0 from the step-0 init).  Ring tiles are loaded one at a time
+    # where they are used: loading whole panels would hold bt·(bt+1) tiles
+    # of values live next to the ring itself.
+    rhs = [ring_read(ring_ref, k - j, bt, j) for j in range(1, bt + 1)]
+
+    # left-looking band update: U[e] = sum_j L[k+e, k-j] @ L[k, k-j]^T
+    # (e = 0 is the SYRK chain, e > 0 the GEMM chains; e+j > bt pairs are
+    # structurally outside the band)
+    def band_term(e):
+        def body(j, acc):
+            return acc + tile_dot(ring_read(ring_ref, k - j, bt, e + j),
+                                  ring_read(ring_ref, k - j, bt, j),
+                                  trans_b=True)
+        return jax.lax.fori_loop(1, bt + 1 - e, body,
+                                 jnp.zeros((t, t), jnp.float32))
+
+    # arrow-row update: V[i] = sum_j L[ndt+i, k-j] @ L[k, k-j]^T
+    va = [sum((tile_dot(ring_read(ringa_ref, k - j, bt, i), rhs[j - 1],
+                        trans_b=True) for j in range(1, bt + 1)),
+              jnp.zeros((t, t), jnp.float32))
+          for i in range(nat_p)]
+
+    # diagonal tile, then the whole sub-diagonal panel + arrow rows in one
+    # batched right-substitution against the fresh L_kk
+    lkk = factorize_tile(ac_ref[0, 0].astype(jnp.float32) - band_term(0))
+    stack = jnp.stack(
+        [ac_ref[0, e].astype(jnp.float32) - band_term(e)
+         for e in range(1, bt + 1)]
+        + [r_ref[0, i].astype(jnp.float32) - va[i] for i in range(nat_p)])
+    sol = substitute_right(lkk, stack)                  # (bt+nat_p, t, t)
+    panel = jnp.concatenate([lkk[None], sol[:bt]], axis=0)
+    la = sol[bt:]
+
+    if bt:
+        ring_write(ring_ref, k, bt, panel)
+        ring_write(ringa_ref, k, bt, la)
+
+    # corner-Schur partial sums on the fly: sacc[i,j] += La[i] @ La[j]^T
+    for i in range(nat_p):
+        for j in range(nat_p):
+            sacc_ref[i, j] += tile_dot(la[i], la[j], trans_b=True)
+    sch_ref[0] = sacc_ref[...].astype(sch_ref.dtype)
+    p_ref[0] = panel.astype(p_ref.dtype)
+    ro_ref[0] = la.astype(ro_ref.dtype)
+
+    # in-sweep breakdown detection (masked 2-D reductions only)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    dmask = rows == cols
+    dsq = jnp.where(dmask, lkk * lkk, jnp.float32(jnp.inf))
+    nonfin_d = jnp.max(jnp.where(dmask & ~jnp.isfinite(lkk), 1.0, 0.0))
+    piv = jnp.where(nonfin_d > 0.0, jnp.float32(jnp.inf), jnp.min(dsq))
+    nonfinite = jnp.maximum(
+        jnp.max(jnp.max(jnp.where(jnp.isfinite(panel), 0.0, 1.0), axis=0)),
+        jnp.max(jnp.max(jnp.where(jnp.isfinite(la), 0.0, 1.0), axis=0)))
+    bad = (nonfinite > 0.0) | (piv <= 0.0)
+    _status_fold(st_ref, piv, nonfinite, bad, col)
 
 
 def _band_cholesky_kernel(start_ref, ac_ref, r_ref, p_ref, ro_ref, sch_ref,
@@ -64,12 +162,7 @@ def _band_cholesky_kernel(start_ref, ac_ref, r_ref, p_ref, ro_ref, sch_ref,
     def _init():
         ring_ref[...] = jnp.zeros_like(ring_ref)
         ringa_ref[...] = jnp.zeros_like(ringa_ref)
-        # breakdown status carry [min_pivot, nonfinite, first_bad]: the
-        # (1, 3) output block's index map is constant, so it stays VMEM
-        # resident across the sequential grid and doubles as the carry
-        st_ref[0, 0] = jnp.float32(jnp.inf)
-        st_ref[0, 1] = jnp.float32(0.0)
-        st_ref[0, 2] = jnp.float32(-1.0)
+        _status_init(st_ref)
 
     @pl.when(jax.lax.rem(k, csz) == 0)
     def _chunk_init():
@@ -89,84 +182,32 @@ def _band_cholesky_kernel(start_ref, ac_ref, r_ref, p_ref, ro_ref, sch_ref,
         sch_ref[0] = sacc_ref[...].astype(sch_ref.dtype)
         # identity panel: pivot 1, finite — same fold ref.sweep_status
         # applies to the emitted identity column
-        st_ref[0, 0] = jnp.minimum(st_ref[0, 0], jnp.float32(1.0))
+        _status_fold(st_ref, jnp.float32(1.0), jnp.float32(0.0),
+                     jnp.bool_(False), k)
 
     @pl.when(k >= start)
     def _work():
-        # The last bt finalized column panels from the VMEM rings (zeros
-        # for k-j < 0 from the step-0 init).  bt is small and static, so
-        # the j/e loops unroll — every pair is one MXU matmul with no
-        # gather/masking.
-        prev = [ring_read(ring_ref, k - j, bt) for j in range(1, bt + 1)]
-        preva = [ring_read(ringa_ref, k - j, bt) for j in range(1, bt + 1)]
-        # rhs_j = L[k, k-j] = panel_{k-j}[j]
-        rhs = [prev[j - 1][j] for j in range(1, bt + 1)]
+        _cholesky_column(ac_ref, r_ref, p_ref, ro_ref, sch_ref, st_ref,
+                         ring_ref, ringa_ref, sacc_ref, k, k,
+                         bt=bt, nat_p=nat_p)
 
-        # left-looking band update: U[e] = sum_j L[k+e, k-j] @ L[k, k-j]^T
-        # (e = 0 is the SYRK chain, e > 0 the GEMM chains; e+j > bt pairs
-        # are structurally outside the band)
-        u = []
-        for e in range(bt + 1):
-            acc = jnp.zeros((t, t), jnp.float32)
-            for j in range(1, bt + 1 - e):
-                acc = acc + jax.lax.dot_general(
-                    prev[j - 1][e + j], rhs[j - 1], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            u.append(acc)
 
-        # arrow-row update: V[i] = sum_j L[ndt+i, k-j] @ L[k, k-j]^T
-        va = jnp.zeros((nat_p, t, t), jnp.float32)
-        for j in range(1, bt + 1):
-            va = va + jax.lax.dot_general(
-                preva[j - 1], rhs[j - 1], (((2,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        # diagonal tile, then the whole sub-diagonal panel + arrow rows in
-        # one batched right-substitution against the fresh L_kk
-        lkk = factorize_tile(ac_ref[0, 0].astype(jnp.float32) - u[0])
-        band_rhs = [ac_ref[0, e].astype(jnp.float32) - u[e]
-                    for e in range(1, bt + 1)]
-        arrow_rhs = r_ref[0].astype(jnp.float32) - va
-        stack = jnp.concatenate([jnp.stack(band_rhs), arrow_rhs], axis=0) \
-            if bt else arrow_rhs
-        sol = substitute_right(lkk, stack)                # (bt+nat_p, t, t)
-        panel = jnp.concatenate([lkk[None], sol[:bt]], axis=0)
-        la = sol[bt:]
-
-        if bt:
-            ring_write(ring_ref, k, bt, panel)
-            ring_write(ringa_ref, k, bt, la)
-
-        # corner-Schur partial sums on the fly: sacc[i,j] += La[i] @ La[j]^T
-        ss = jax.lax.dot_general(la, la, (((2,), (2,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        sacc_ref[...] += jnp.transpose(ss, (0, 2, 1, 3))
-        sch_ref[0] = sacc_ref[...].astype(sch_ref.dtype)
-
-        p_ref[0] = panel.astype(p_ref.dtype)
-        ro_ref[0] = la.astype(ro_ref.dtype)
-
-        # in-sweep breakdown detection: fold this column into the status
-        # carry — the same per-column update ``ref.sweep_status`` applies
-        # to the emitted factor, so both backends report identical words.
-        # Masked 2-D reductions only (no 1-D iota/vectors on TPU).
-        rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-        dmask = rows == cols
-        dsq = jnp.where(dmask, lkk * lkk, jnp.float32(jnp.inf))
-        fin_d = jnp.all(jnp.isfinite(jnp.where(dmask, lkk, 0.0)))
-        piv = jnp.where(fin_d, jnp.min(dsq), jnp.float32(jnp.inf))
-        fin = jnp.all(jnp.isfinite(panel)) & jnp.all(jnp.isfinite(la))
-        bad = jnp.logical_not(fin) | (piv <= 0.0)
-        st_ref[0, 0] = jnp.minimum(st_ref[0, 0], piv)
-        st_ref[0, 1] = jnp.maximum(st_ref[0, 1], jnp.where(fin, 0.0, 1.0))
-        st_ref[0, 2] = jnp.where((st_ref[0, 2] < 0.0) & bad,
-                                 k.astype(jnp.float32), st_ref[0, 2])
+def _compiler_params(b1, nat_p, t, semantics):
+    """VMEM budget of one Cholesky sweep step: the panel and arrow rings
+    and the Schur accumulator (scratch), the in/out column blocks, and the
+    step's live values (band update, substitution stack and carry)."""
+    tile = t * t * 4
+    bt = max(b1 - 1, 1)
+    return sweep_compiler_params(
+        scratch=(bt * b1 + bt * nat_p + nat_p * nat_p) * tile,
+        blocks=(2 * (b1 + nat_p) + nat_p * nat_p) * tile,
+        temps=6 * (b1 + nat_p) * tile,
+        semantics=semantics)
 
 
 @functools.partial(jax.jit, static_argnames=("nchunks", "interpret"))
 def band_cholesky_sweep_pallas(Ac, R, nchunks: int = 1, start_tile=0,
-                               interpret: bool = True):
+                               *, interpret: bool):
     """Fused band+arrow Cholesky sweep.  Ac: (ndt, bt+1, t, t) column-band
     tiles (``Ac[k, e] = A[k+e, k]``, see ``ring.band_row_to_col``), R:
     (ndt, nat, t, t) arrow rows ->
@@ -216,22 +257,23 @@ def band_cholesky_sweep_pallas(Ac, R, nchunks: int = 1, start_tile=0,
             pl.BlockSpec((1, nat_p, t, t), lambda k: (k, 0, 0, 0)),
             pl.BlockSpec((1, nat_p, nat_p, t, t),
                          lambda k: (k // csz, 0, 0, 0, 0)),
-            pl.BlockSpec((1, 3), lambda k: (0, 0)),
+            pl.BlockSpec((1,) + _ST_SHAPE, lambda k: (0, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((ndt, b1, t, t), Ac.dtype),
             jax.ShapeDtypeStruct((ndt, nat_p, t, t), Ac.dtype),
             jax.ShapeDtypeStruct((nch, nat_p, nat_p, t, t), Ac.dtype),
-            jax.ShapeDtypeStruct((1, 3), jnp.float32),
+            jax.ShapeDtypeStruct((1,) + _ST_SHAPE, jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((max(bt, 1), b1, t, t), jnp.float32),
             pltpu.VMEM((max(bt, 1), nat_p, t, t), jnp.float32),
             pltpu.VMEM((nat_p, nat_p, t, t), jnp.float32),
         ],
+        compiler_params=_compiler_params(b1, nat_p, t, ("arbitrary",)),
         interpret=interpret,
     )(start, Ac, rp)
-    return panels, ro[:, :nat], schur[:, :nat, :nat], st[0]
+    return panels, ro[:, :nat], schur[:, :nat, :nat], st[0, 0, :3]
 
 
 def _band_cholesky_partitioned_kernel(bounds_ref, start_ref, ac_ref, r_ref,
@@ -255,9 +297,7 @@ def _band_cholesky_partitioned_kernel(bounds_ref, start_ref, ac_ref, r_ref,
         ring_ref[...] = jnp.zeros_like(ring_ref)
         ringa_ref[...] = jnp.zeros_like(ringa_ref)
         sacc_ref[...] = jnp.zeros_like(sacc_ref)
-        st_ref[0, 0] = jnp.float32(jnp.inf)
-        st_ref[0, 1] = jnp.float32(0.0)
-        st_ref[0, 2] = jnp.float32(-1.0)
+        _status_init(st_ref)
 
     # Steps k >= size are padding of the rectangular (P, max_tiles) grid:
     # they touch nothing — the clamped index maps revisit the partition's
@@ -270,76 +310,25 @@ def _band_cholesky_partitioned_kernel(bounds_ref, start_ref, ac_ref, r_ref,
         p_ref[0] = identity_prefix_panel(bt, t).astype(p_ref.dtype)
         ro_ref[0] = jnp.zeros_like(ro_ref[0])
         sch_ref[0] = sacc_ref[...].astype(sch_ref.dtype)
-        st_ref[0, 0] = jnp.minimum(st_ref[0, 0], jnp.float32(1.0))
+        _status_fold(st_ref, jnp.float32(1.0), jnp.float32(0.0),
+                     jnp.bool_(False), g)
 
     @pl.when(active & (g >= start))
     def _work():
-        # identical left-looking step to _band_cholesky_kernel, with the
-        # *local* index k driving the rings (panel k-j of this partition;
-        # k-j < 0 reads the step-0 zeros, exactly the cross-boundary
-        # zeros block-separability guarantees)
-        prev = [ring_read(ring_ref, k - j, bt) for j in range(1, bt + 1)]
-        preva = [ring_read(ringa_ref, k - j, bt) for j in range(1, bt + 1)]
-        rhs = [prev[j - 1][j] for j in range(1, bt + 1)]
-
-        u = []
-        for e in range(bt + 1):
-            acc = jnp.zeros((t, t), jnp.float32)
-            for j in range(1, bt + 1 - e):
-                acc = acc + jax.lax.dot_general(
-                    prev[j - 1][e + j], rhs[j - 1], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            u.append(acc)
-
-        va = jnp.zeros((nat_p, t, t), jnp.float32)
-        for j in range(1, bt + 1):
-            va = va + jax.lax.dot_general(
-                preva[j - 1], rhs[j - 1], (((2,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        lkk = factorize_tile(ac_ref[0, 0].astype(jnp.float32) - u[0])
-        band_rhs = [ac_ref[0, e].astype(jnp.float32) - u[e]
-                    for e in range(1, bt + 1)]
-        arrow_rhs = r_ref[0].astype(jnp.float32) - va
-        stack = jnp.concatenate([jnp.stack(band_rhs), arrow_rhs], axis=0) \
-            if bt else arrow_rhs
-        sol = substitute_right(lkk, stack)
-        panel = jnp.concatenate([lkk[None], sol[:bt]], axis=0)
-        la = sol[bt:]
-
-        if bt:
-            ring_write(ring_ref, k, bt, panel)
-            ring_write(ringa_ref, k, bt, la)
-
-        # one Schur chunk per partition: the tree-reduction leaf this
-        # partition contributes to the shared corner factorization
-        ss = jax.lax.dot_general(la, la, (((2,), (2,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        sacc_ref[...] += jnp.transpose(ss, (0, 2, 1, 3))
-        sch_ref[0] = sacc_ref[...].astype(sch_ref.dtype)
-
-        p_ref[0] = panel.astype(p_ref.dtype)
-        ro_ref[0] = la.astype(ro_ref.dtype)
-
-        rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-        dmask = rows == cols
-        dsq = jnp.where(dmask, lkk * lkk, jnp.float32(jnp.inf))
-        fin_d = jnp.all(jnp.isfinite(jnp.where(dmask, lkk, 0.0)))
-        piv = jnp.where(fin_d, jnp.min(dsq), jnp.float32(jnp.inf))
-        fin = jnp.all(jnp.isfinite(panel)) & jnp.all(jnp.isfinite(la))
-        bad = jnp.logical_not(fin) | (piv <= 0.0)
-        st_ref[0, 0] = jnp.minimum(st_ref[0, 0], piv)
-        st_ref[0, 1] = jnp.maximum(st_ref[0, 1], jnp.where(fin, 0.0, 1.0))
-        # first_bad is recorded in *global* columns, so the per-partition
-        # words fold with ref.combine_sweep_status directly
-        st_ref[0, 2] = jnp.where((st_ref[0, 2] < 0.0) & bad,
-                                 g.astype(jnp.float32), st_ref[0, 2])
+        # the *local* index k drives the rings (panel k-j of this
+        # partition; k-j < 0 reads the step-0 zeros, exactly the
+        # cross-boundary zeros block-separability guarantees), one Schur
+        # chunk per partition is the tree-reduction leaf it contributes to
+        # the shared corner, and first_bad is recorded in *global* columns
+        # so the per-partition words fold with ref.combine_sweep_status
+        _cholesky_column(ac_ref, r_ref, p_ref, ro_ref, sch_ref, st_ref,
+                         ring_ref, ringa_ref, sacc_ref, k, g,
+                         bt=bt, nat_p=nat_p)
 
 
 @functools.partial(jax.jit, static_argnames=("boundaries", "interpret"))
 def band_cholesky_partitioned_sweep_pallas(Ac, R, boundaries, start_tile=0,
-                                           interpret: bool = True):
+                                           *, interpret: bool):
     """Partition-parallel fused band+arrow Cholesky: one launch over all
     ND partitions.
 
@@ -406,7 +395,7 @@ def band_cholesky_partitioned_sweep_pallas(Ac, R, boundaries, start_tile=0,
             pl.BlockSpec((1, nat_p, t, t), col),
             pl.BlockSpec((1, nat_p, nat_p, t, t),
                          lambda p, k, b, s: (p, 0, 0, 0, 0)),
-            pl.BlockSpec((1, 3), lambda p, k, b, s: (p, 0)),
+            pl.BlockSpec((1,) + _ST_SHAPE, lambda p, k, b, s: (p, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((max(bt, 1), b1, t, t), jnp.float32),
@@ -422,11 +411,11 @@ def band_cholesky_partitioned_sweep_pallas(Ac, R, boundaries, start_tile=0,
             jax.ShapeDtypeStruct((ndt, b1, t, t), Ac.dtype),
             jax.ShapeDtypeStruct((ndt, nat_p, t, t), Ac.dtype),
             jax.ShapeDtypeStruct((P, nat_p, nat_p, t, t), Ac.dtype),
-            jax.ShapeDtypeStruct((P, 3), jnp.float32),
+            jax.ShapeDtypeStruct((P,) + _ST_SHAPE, jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(b1, nat_p, t,
+                                         ("parallel", "arbitrary")),
         interpret=interpret,
     )(bounds_arr, start, Ac, rp)
     return (panels, ro[:, :nat], schur[:, :nat, :nat],
-            combine_sweep_status(st))
+            combine_sweep_status(st[:, 0, :3]))

@@ -38,7 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ring import band_row_to_col, ring_accumulate, ring_read, ring_write
+from .ring import (band_row_to_col, ring_accumulate, ring_read, ring_write,
+                   sweep_compiler_params, tile_dot)
 from .trsm import substitute_panel
 
 # ring_read/ring_write are re-exported for backward compatibility; the
@@ -47,12 +48,23 @@ __all__ = ["band_forward_sweep_pallas", "band_backward_sweep_pallas",
            "ring_read", "ring_write"]
 
 
+def _compiler_params(bt, nat_p, t, k):
+    """VMEM budget of one band-solve step: the (t, k) panel ring and arrow
+    accumulator (scratch), the factor column/row block, arrow rows and
+    (t, k) panels in and out, and the step's live panels."""
+    panel = t * k * 4
+    return sweep_compiler_params(
+        scratch=(max(bt, 1) + nat_p) * panel,
+        blocks=(bt + 1 + nat_p) * t * t * 4 + (2 + 2 * nat_p) * panel,
+        temps=8 * panel + 2 * t * t * 4)
+
+
 # ---------------------------------------------------------------------------
 # Forward sweep: L Y = B over the band, + on-the-fly arrow accumulation
 # ---------------------------------------------------------------------------
 
 def _band_forward_kernel(start_ref, dr_ref, r_ref, b_ref, y_ref, acca_ref,
-                         ring_ref, arr_ref, *, ndt: int, bt: int):
+                         ring_ref, arr_ref, *, ndt: int, bt: int, nat_p: int):
     m = pl.program_id(0)
     start = start_ref[0]
     t = dr_ref.shape[-1]
@@ -79,10 +91,8 @@ def _band_forward_kernel(start_ref, dr_ref, r_ref, b_ref, y_ref, acca_ref,
         # zeros, so no masking is needed beyond the zero-init.
         acc = ring_accumulate(
             ring_ref, m, bt, jnp.zeros((t, k), jnp.float32),
-            lambda j, yprev: jax.lax.dot_general(
-                dr_ref[0, j].astype(jnp.float32), yprev,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32),
+            lambda j, yprev: tile_dot(dr_ref[0, j].astype(jnp.float32),
+                                      yprev),
             step=-1)
 
         rhs = b_ref[0].astype(jnp.float32) - acc
@@ -92,10 +102,8 @@ def _band_forward_kernel(start_ref, dr_ref, r_ref, b_ref, y_ref, acca_ref,
             ring_write(ring_ref, m, bt, ym)
 
         # arrow rows ride the sweep: arr[i] += R[m, i] @ Y_m
-        r = r_ref[0].astype(jnp.float32)                 # (nat_p, t, t)
-        arr_ref[...] += jax.lax.dot_general(
-            r, ym, (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for i in range(nat_p):
+            arr_ref[i] += tile_dot(r_ref[0, i].astype(jnp.float32), ym)
 
     @pl.when(m == ndt - 1)
     def _emit():
@@ -103,7 +111,7 @@ def _band_forward_kernel(start_ref, dr_ref, r_ref, b_ref, y_ref, acca_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def band_forward_sweep_pallas(Dr, R, bd, start_tile=0, interpret: bool = True):
+def band_forward_sweep_pallas(Dr, R, bd, start_tile=0, *, interpret: bool):
     """Fused forward band sweep.  Dr: (ndt, bt+1, t, t) row-band factor
     tiles, R: (ndt, nat, t, t) arrow rows, bd: (ndt, t, k) RHS panel ->
     (yd (ndt, t, k), acc_a (nat, t, k)) with ``L Y = B`` on the band and
@@ -124,7 +132,7 @@ def band_forward_sweep_pallas(Dr, R, bd, start_tile=0, interpret: bool = True):
     rp = R if nat else jnp.zeros((ndt, 1, t, t), Dr.dtype)
     start = jnp.reshape(jnp.asarray(start_tile, jnp.int32), (1,))
     yd, acca = pl.pallas_call(
-        functools.partial(_band_forward_kernel, ndt=ndt, bt=bt),
+        functools.partial(_band_forward_kernel, ndt=ndt, bt=bt, nat_p=nat_p),
         grid=(ndt,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -142,6 +150,7 @@ def band_forward_sweep_pallas(Dr, R, bd, start_tile=0, interpret: bool = True):
         ],
         scratch_shapes=[pltpu.VMEM((max(bt, 1), t, k), jnp.float32),
                         pltpu.VMEM((nat_p, t, k), jnp.float32)],
+        compiler_params=_compiler_params(bt, nat_p, t, k),
         interpret=interpret,
     )(start, Dr, rp, bd)
     return yd, acca[:nat]
@@ -152,7 +161,7 @@ def band_forward_sweep_pallas(Dr, R, bd, start_tile=0, interpret: bool = True):
 # ---------------------------------------------------------------------------
 
 def _band_backward_kernel(start_ref, lcol_ref, r_ref, y_ref, xa_ref, x_ref,
-                          ring_ref, *, ndt: int, bt: int):
+                          ring_ref, *, ndt: int, bt: int, nat_p: int):
     s = pl.program_id(0)
     m = ndt - 1 - s
     start = start_ref[0]
@@ -178,17 +187,16 @@ def _band_backward_kernel(start_ref, lcol_ref, r_ref, y_ref, xa_ref, x_ref,
         # is zero-padded past ndt and unvisited ring slots hold zeros.
         acc = ring_accumulate(
             ring_ref, m, bt, jnp.zeros((t, k), jnp.float32),
-            lambda j, xnext: jax.lax.dot_general(
-                lcol_ref[0, j].astype(jnp.float32), xnext,
-                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32),
+            lambda j, xnext: tile_dot(lcol_ref[0, j].astype(jnp.float32),
+                                      xnext, trans_a=True),
             step=1)
 
-        # arrow term: sum_i R[m, i]^T @ Xa_i (contract arrow tile + row dims)
-        r = r_ref[0].astype(jnp.float32)                 # (nat_p, t, t)
-        xa = xa_ref[...].astype(jnp.float32)             # (nat_p, t, k)
-        acc2 = acc + jax.lax.dot_general(
-            r, xa, (((0, 1), (0, 1)), ((), ())),
-            preferred_element_type=jnp.float32)
+        # arrow term: sum_i R[m, i]^T @ Xa_i
+        acc2 = acc
+        for i in range(nat_p):
+            acc2 = acc2 + tile_dot(r_ref[0, i].astype(jnp.float32),
+                                   xa_ref[i].astype(jnp.float32),
+                                   trans_a=True)
 
         rhs = y_ref[0].astype(jnp.float32) - acc2
         xm = substitute_panel(lcol_ref[0, 0].astype(jnp.float32), rhs,
@@ -200,7 +208,7 @@ def _band_backward_kernel(start_ref, lcol_ref, r_ref, y_ref, xa_ref, x_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def band_backward_sweep_pallas(Dr, R, yd, xa, start_tile=0,
-                               interpret: bool = True):
+                               *, interpret: bool):
     """Fused backward band sweep.  Dr: (ndt, bt+1, t, t), R: (ndt, nat, t, t),
     yd: (ndt, t, k) forward-solved panel, xa: (nat, t, k) already-solved
     arrow panel -> xd (ndt, t, k) with ``L^T X = Y - R^T Xa`` on the band.
@@ -225,7 +233,8 @@ def band_backward_sweep_pallas(Dr, R, yd, xa, start_tile=0,
     xap = xa if nat else jnp.zeros((1, t, k), yd.dtype)
     start = jnp.reshape(jnp.asarray(start_tile, jnp.int32), (1,))
     return pl.pallas_call(
-        functools.partial(_band_backward_kernel, ndt=ndt, bt=bt),
+        functools.partial(_band_backward_kernel, ndt=ndt, bt=bt,
+                          nat_p=nat_p),
         grid=(ndt,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -237,5 +246,6 @@ def band_backward_sweep_pallas(Dr, R, yd, xa, start_tile=0,
         out_specs=pl.BlockSpec((1, t, k), lambda s: (ndt - 1 - s, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((ndt, t, k), yd.dtype),
         scratch_shapes=[pltpu.VMEM((max(bt, 1), t, k), jnp.float32)],
+        compiler_params=_compiler_params(bt, nat_p, t, k),
         interpret=interpret,
     )(start, lcol, rp, yd, xap)

@@ -57,7 +57,7 @@ def _band_update_kernel(a_ref, b_ref, o_ref, acc_ref, *, b1: int, jb: int, njb: 
 
 @functools.partial(jax.jit, static_argnames=("jblock", "interpret"))
 def band_update_pallas(w: jnp.ndarray, jblock: int = 8,
-                       interpret: bool = True) -> jnp.ndarray:
+                       *, interpret: bool) -> jnp.ndarray:
     """Fused band-panel update.  w: (b+1, b+1, t, t) -> u: (b+1, t, t).
 
     Matches ``ref.band_update_ref`` bit-for-bit in float32.

@@ -38,7 +38,7 @@ def _gemm_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int):
 
 @functools.partial(jax.jit, static_argnames=("kblock", "interpret"))
 def gemm_pallas(c_mk: jnp.ndarray, a_mn: jnp.ndarray, b_kn: jnp.ndarray,
-                kblock: int = 256, interpret: bool = True) -> jnp.ndarray:
+                kblock: int = 256, *, interpret: bool) -> jnp.ndarray:
     """Tile update C - A @ B^T, batched over leading dims."""
     t = c_mk.shape[-1]
     batch_shape = c_mk.shape[:-2]
@@ -66,7 +66,7 @@ def gemm_pallas(c_mk: jnp.ndarray, a_mn: jnp.ndarray, b_kn: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("kblock", "interpret"))
 def syrk_pallas(c_kk: jnp.ndarray, a_kn: jnp.ndarray,
-                kblock: int = 256, interpret: bool = True) -> jnp.ndarray:
+                kblock: int = 256, *, interpret: bool) -> jnp.ndarray:
     """Symmetric rank-t tile update C - A @ A^T."""
     return gemm_pallas(c_kk, a_kn, a_kn, kblock=kblock, interpret=interpret)
 
@@ -76,7 +76,7 @@ def _geadd_kernel(a_ref, b_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def geadd_pallas(a: jnp.ndarray, b: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def geadd_pallas(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Generalized tile addition (tree-reduction combine, paper Alg. 3)."""
     t = a.shape[-1]
     batch_shape = a.shape[:-2]

@@ -67,7 +67,7 @@ def _potrf_kernel(a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def potrf_pallas(a: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def potrf_pallas(a: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Cholesky of one (t, t) tile (or a batch (..., t, t) via grid)."""
     batch_shape = a.shape[:-2]
     t = a.shape[-1]

@@ -19,6 +19,10 @@ In-kernel helpers (operate on VMEM scratch refs):
   :func:`ring_read` / :func:`ring_write` — modular slot addressing.
   :func:`ring_accumulate` — the j = 1..depth accumulation loop over ring
   entries that forms each sweep's bounded-history contraction.
+  :func:`tile_dot` — the one 2-D float32 MXU contraction every sweep uses.
+
+Compile-time helper: :func:`sweep_compiler_params` — each sweep's VMEM
+limit, computed from its own scratch, block and live-value sizes.
 
 Host-side helpers (plain jnp, used by the kernel wrappers and the ref
 oracles):
@@ -39,7 +43,43 @@ import jax.numpy as jnp
 
 __all__ = ["ring_read", "ring_write", "ring_accumulate",
            "band_row_to_col", "band_col_to_row", "chunk_layout",
-           "eye_tile", "identity_prefix_panel"]
+           "eye_tile", "identity_prefix_panel", "tile_dot",
+           "sweep_compiler_params"]
+
+# v5e has 128 MiB of VMEM per core; leave the rest to Mosaic's own scratch.
+VMEM_CAP_BYTES = 100 * 2 ** 20
+# the default scoped VMEM limit on v5e: never ask for less
+_VMEM_FLOOR_BYTES = 16 * 2 ** 20
+_VMEM_HEADROOM_BYTES = 4 * 2 ** 20
+
+
+def tile_dot(a, b, trans_a: bool = False, trans_b: bool = False):
+    """``a @ b`` (``a.T`` / ``b.T`` with ``trans_a`` / ``trans_b``) of two
+    2-D tiles in float32 at full precision — ``ref.py`` contracts at
+    ``Precision.HIGHEST``, and Mosaic's default for float32 operands is not
+    guaranteed to match it.
+    Batched or multi-axis contractions are written as loops over this one
+    form, which is what Mosaic lowers to the MXU."""
+    if trans_a:
+        a = a.T
+    dims = (((1,), (1 if trans_b else 0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def sweep_compiler_params(*, scratch: int, blocks: int, temps: int,
+                          semantics=("arbitrary",)):
+    """Mosaic compiler params for one sequential sweep: the VMEM limit is
+    the kernel's scratch, its double-buffered in/out blocks and its live
+    values (all in bytes) plus headroom, floored at the default scoped
+    limit and capped below the chip's VMEM."""
+    from jax.experimental.pallas import tpu as pltpu
+    need = scratch + 2 * blocks + temps + _VMEM_HEADROOM_BYTES
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(semantics),
+        vmem_limit_bytes=int(min(max(need, _VMEM_FLOOR_BYTES),
+                                 VMEM_CAP_BYTES)))
 
 
 def eye_tile(t: int, dtype=jnp.float32) -> jnp.ndarray:
@@ -67,12 +107,13 @@ def identity_prefix_panel(bt: int, t: int, dtype=jnp.float32) -> jnp.ndarray:
 # In-kernel ring-scratch helpers
 # ---------------------------------------------------------------------------
 
-def ring_read(ring_ref, row, depth: int):
+def ring_read(ring_ref, row, depth: int, *idx):
     """Read the panel for absolute row index ``row`` from a depth-``depth``
-    VMEM ring.  Valid for ``row >= -depth`` (the modular shift keeps the
-    slot index nonnegative); slots for rows the sweep has not visited hold
-    the zero panels written by the ``step == 0`` initialization."""
-    return ring_ref[jax.lax.rem(row + depth, depth)]
+    VMEM ring (``idx`` selects one tile of it).  Valid for ``row >= -depth``
+    (the modular shift keeps the slot index nonnegative); slots for rows the
+    sweep has not visited hold the zero panels written by the ``step == 0``
+    initialization."""
+    return ring_ref[(jax.lax.rem(row + depth, depth),) + idx]
 
 
 def ring_write(ring_ref, row, depth: int, panel):

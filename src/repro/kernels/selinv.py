@@ -27,8 +27,8 @@ band-Cholesky sweeps), the L_jj^{-1} seed solved in-kernel
 (:func:`trsm.substitute_panel` against the identity) and the full corner
 Σ_cc broadcast to every step.  VMEM budget per step: the Σ ring
 bt·(bt+1)·t², the arrow ring bt·nat·t², the corner nat²·t² and the
-(bt+1+nat)·t² blocks — e.g. bt=8, t=128, nat=2: ~6.1 MB, under the ~16
-MB/core of v5e.
+(bt+1+nat)·t² blocks plus the normalized-column scratch bt·t²; the
+wrapper sets the kernel's VMEM limit from these sizes.
 
 Both match their ``kernels/ref.py`` oracles to fp32 tolerance;
 ``kernels.ops.selinv_step`` / ``kernels.ops.selinv_sweep`` dispatch.
@@ -42,7 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ring import eye_tile, identity_prefix_panel, ring_read, ring_write
+from .ring import (eye_tile, identity_prefix_panel, ring_read, ring_write,
+                   sweep_compiler_params, tile_dot)
 from .trsm import substitute_panel
 
 __all__ = ["selinv_step_pallas", "selinv_sweep_pallas"]
@@ -73,7 +74,7 @@ def _selinv_step_kernel(s_ref, g_ref, o_ref, acc_ref, *, jb: int, njb: int):
 
 @functools.partial(jax.jit, static_argnames=("jblock", "interpret"))
 def selinv_step_pallas(s_row: jnp.ndarray, g_col: jnp.ndarray,
-                       jblock: int = 8, interpret: bool = True) -> jnp.ndarray:
+                       jblock: int = 8, *, interpret: bool) -> jnp.ndarray:
     """Fused Takahashi tile step.  s_row: (e_n, j_n, t, t), g_col:
     (j_n, t, t) -> u: (e_n, t, t).
 
@@ -106,7 +107,8 @@ def selinv_step_pallas(s_row: jnp.ndarray, g_col: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def _selinv_sweep_kernel(start_ref, lcol_ref, r_ref, sc_ref, p_ref, a_ref,
-                         ring_ref, ringa_ref, *, ndt: int, bt: int):
+                         ring_ref, ringa_ref, g_ref, *, ndt: int, bt: int,
+                         nat_p: int):
     s = pl.program_id(0)
     j = ndt - 1 - s
     start = start_ref[0]
@@ -116,8 +118,6 @@ def _selinv_sweep_kernel(start_ref, lcol_ref, r_ref, sc_ref, p_ref, a_ref,
     def _init():
         ring_ref[...] = jnp.zeros_like(ring_ref)
         ringa_ref[...] = jnp.zeros_like(ringa_ref)
-
-    eye = eye_tile(t)
 
     # Canonical-grid fast finish (core/gridpolicy.py): columns j < start
     # are the identity-embedding prefix — decoupled, so their Σ panel is
@@ -132,86 +132,81 @@ def _selinv_sweep_kernel(start_ref, lcol_ref, r_ref, sc_ref, p_ref, a_ref,
     @pl.when(j >= start)
     def _work():
         _selinv_sweep_body(lcol_ref, r_ref, sc_ref, p_ref, a_ref,
-                           ring_ref, ringa_ref, eye, j, bt=bt)
+                           ring_ref, ringa_ref, g_ref, j, bt=bt, nat_p=nat_p)
 
 
 def _selinv_sweep_body(lcol_ref, r_ref, sc_ref, p_ref, a_ref,
-                       ring_ref, ringa_ref, eye, j, *, bt: int):
+                       ring_ref, ringa_ref, g_ref, j, *, bt: int, nat_p: int):
     t = lcol_ref.shape[-1]
-    lc = lcol_ref[0].astype(jnp.float32)                  # (b1, t, t)
-    rc = r_ref[0].astype(jnp.float32)                     # (nat_p, t, t)
-    sc = sc_ref[...].astype(jnp.float32)                  # (nat_p, nat_p, t, t)
+    zero = jnp.zeros((t, t), jnp.float32)
+
+    def col(d, e):
+        # Σ_{j+d+e, j+d}: offset e of the Σ column j+d (from the VMEM ring;
+        # zeros past ndt-1 / from the step-0 init)
+        return ring_read(ring_ref, j + d, bt, e)
 
     # seed: winv = L_jj^{-1} (in-kernel substitution against the identity),
     # s0 = (L_jj L_jj^T)^{-1} = winv^T winv
-    winv = substitute_panel(lc[0], eye)
-    s0 = jax.lax.dot_general(winv, winv, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    winv = substitute_panel(lcol_ref[0, 0].astype(jnp.float32), eye_tile(t))
+    s0 = tile_dot(winv, winv, trans_a=True)
 
-    # normalized column: G_d = L_{j+d, j} L_jj^{-1}, arrow Ga_i = R[j,i] winv
-    g = [jax.lax.dot_general(lc[d], winv, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-         for d in range(1, bt + 1)]
-    ga = jax.lax.dot_general(rc, winv, (((2,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    # normalized column: G_d = L_{j+d, j} L_jj^{-1} (kept in VMEM scratch
+    # so the loops below can index it), arrow Ga_i = R[j,i] winv
+    for d in range(1, bt + 1):
+        g_ref[d - 1] = tile_dot(lcol_ref[0, d].astype(jnp.float32), winv)
+    ga = [tile_dot(r_ref[0, i].astype(jnp.float32), winv)
+          for i in range(nat_p)]
 
-    # Σ columns j+1..j+bt from the VMEM rings (zeros past ndt-1 / from the
-    # step-0 init); bt is static and small, so the d/e loops unroll.
-    colp = [ring_read(ring_ref, j + d, bt) for d in range(1, bt + 1)]
-    arow = [ring_read(ringa_ref, j + d, bt) for d in range(1, bt + 1)]
+    def loop(lo, hi, term, init):
+        return jax.lax.fori_loop(lo, hi, lambda d, acc: acc + term(d), init)
 
     # off-diagonal band targets:  Σ_{j+e, j} = -sum_{k>j} Σ_{j+e, k} G_{k, j}
     off = []
     for e in range(1, bt + 1):
-        acc = jnp.zeros((t, t), jnp.float32)
-        for d in range(1, bt + 1):
-            if e >= d:
-                # Σ_{j+e, j+d} lives in column j+d at offset e-d
-                acc = acc + jax.lax.dot_general(
-                    colp[d - 1][e - d], g[d - 1], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            else:
-                # Σ_{j+e, j+d} = Σ_{j+d, j+e}^T, from column j+e
-                acc = acc + jax.lax.dot_general(
-                    colp[e - 1][d - e], g[d - 1], (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+        # d <= e: Σ_{j+e, j+d} lives in column j+d at offset e-d
+        acc = loop(1, e + 1, lambda d: tile_dot(col(d, e - d),
+                                                g_ref[d - 1]), zero)
+        # d > e: Σ_{j+e, j+d} = Σ_{j+d, j+e}^T, from column j+e
+        acc = loop(e + 1, bt + 1,
+                   lambda d: tile_dot(col(e, d - e), g_ref[d - 1],
+                                      trans_a=True), acc)
         # arrow sources: sum_i Σ_{j+e, ndt+i} @ Ga_i = sum_i arow_e[i]^T Ga_i
-        acc = acc + jax.lax.dot_general(
-            arow[e - 1], ga, (((0, 1), (0, 1)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for i in range(nat_p):
+            acc = acc + tile_dot(ring_read(ringa_ref, j + e, bt, i), ga[i],
+                                 trans_a=True)
         off.append(-acc)
 
     # arrow targets:  Σ_{ndt+i, j} = -(sum_d Σ_{ndt+i, j+d} G_d
     #                                  + sum_i' Σ_cc[i, i'] Ga_i')
-    ua = jax.lax.dot_general(sc, ga, (((1, 3), (0, 1)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    for d in range(1, bt + 1):
-        ua = ua + jax.lax.dot_general(
-            arow[d - 1], g[d - 1], (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    acol = -ua
+    acol = []
+    for i in range(nat_p):
+        ua = sum((tile_dot(sc_ref[i, i2].astype(jnp.float32), ga[i2])
+                  for i2 in range(nat_p)), zero)
+        ua = loop(1, bt + 1,
+                  lambda d: tile_dot(ring_read(ringa_ref, j + d, bt, i),
+                                     g_ref[d - 1]), ua)
+        acol.append(-ua)
 
     # diagonal: Σ_jj = s0 - sum_{k>j} Σ_kj^T G_kj (the fresh off-diagonals)
-    corr = jax.lax.dot_general(acol, ga, (((0, 1), (0, 1)), ((), ())),
-                               preferred_element_type=jnp.float32)
+    corr = sum((tile_dot(acol[i], ga[i], trans_a=True)
+                for i in range(nat_p)), zero)
     for e in range(1, bt + 1):
-        corr = corr + jax.lax.dot_general(
-            off[e - 1], g[e - 1], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        corr = corr + tile_dot(off[e - 1], g_ref[e - 1], trans_a=True)
     sjj = s0 - corr
     sjj = 0.5 * (sjj + sjj.T)
 
-    panel = jnp.concatenate([sjj[None]] + [o[None] for o in off], axis=0)
+    panel = jnp.stack([sjj] + off)
+    acols = jnp.stack(acol)
     if bt:
         ring_write(ring_ref, j, bt, panel)
-        ring_write(ringa_ref, j, bt, acol)
+        ring_write(ringa_ref, j, bt, acols)
     p_ref[0] = panel.astype(p_ref.dtype)
-    a_ref[0] = acol.astype(a_ref.dtype)
+    a_ref[0] = acols.astype(a_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def selinv_sweep_pallas(lcol, R, sc_full, start_tile=0,
-                        interpret: bool = True):
+                        *, interpret: bool):
     """Fused backward Takahashi sweep.  lcol: (ndt, bt+1, t, t) column view
     of the factor (``lcol[j, d] = L[j+d, j]``, see ``ring.band_row_to_col``),
     R: (ndt, nat, t, t) arrow rows of the factor, sc_full: (nat, nat, t, t)
@@ -236,8 +231,9 @@ def selinv_sweep_pallas(lcol, R, sc_full, start_tile=0,
     rp = R if nat else jnp.zeros((ndt, 1, t, t), lcol.dtype)
     scp = sc_full if nat else jnp.zeros((1, 1, t, t), lcol.dtype)
     start = jnp.reshape(jnp.asarray(start_tile, jnp.int32), (1,))
+    tile = t * t * 4
     panels, acols = pl.pallas_call(
-        functools.partial(_selinv_sweep_kernel, ndt=ndt, bt=bt),
+        functools.partial(_selinv_sweep_kernel, ndt=ndt, bt=bt, nat_p=nat_p),
         grid=(ndt,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -256,7 +252,12 @@ def selinv_sweep_pallas(lcol, R, sc_full, start_tile=0,
         scratch_shapes=[
             pltpu.VMEM((max(bt, 1), b1, t, t), jnp.float32),
             pltpu.VMEM((max(bt, 1), nat_p, t, t), jnp.float32),
+            pltpu.VMEM((max(bt, 1), t, t), jnp.float32),
         ],
+        compiler_params=sweep_compiler_params(
+            scratch=max(bt, 1) * (b1 + nat_p + 1) * tile,
+            blocks=(2 * (b1 + nat_p) + nat_p * nat_p) * tile,
+            temps=(3 * b1 + 2 * nat_p + 4) * tile),
         interpret=interpret,
     )(start, lcol, rp, scp)
     return panels, acols[:, :nat]

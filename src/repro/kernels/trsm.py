@@ -20,29 +20,28 @@ def substitute_panel(l: jnp.ndarray, b: jnp.ndarray,
                      trans: bool = False) -> jnp.ndarray:
     """In-kernel multi-RHS substitution: solve ``L X = B`` (``trans`` ->
     ``L^T X = B``) for one (t, t) lower-triangular tile against a (t, k)
-    panel, using only masked vector ops (no gather/scatter) so it lowers
-    inside a Pallas kernel body.  Shared by :func:`solve_panel_pallas` and
-    the fused band sweeps in ``kernels/band_solve.py``.  Operates in and
-    returns float32."""
+    panel, using only masked 2-D vector ops (no gather/scatter, no 1-D
+    vectors, no matrix-vector ``dot``) so it lowers inside a Mosaic kernel
+    body.  Shared by :func:`solve_panel_pallas` and the fused band sweeps
+    in ``kernels/band_solve.py``.  Operates in and returns float32."""
     t, k = l.shape[-1], b.shape[-1]
     lrows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
     lcols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
     prows = jax.lax.broadcasted_iota(jnp.int32, (t, k), 0)
-    rvec = jax.lax.broadcasted_iota(jnp.int32, (t,), 0)
+    # the coefficients of X's row j must run down the sublanes next to X's
+    # rows: column j of L (trans) or of L^T (row j of L)
+    lsrc = l if trans else l.T
 
     def step(s, x):
         j = (t - 1 - s) if trans else s
-        if trans:
-            # row j of U = L^T is column j of L; only i > j contribute
-            lj = jnp.sum(jnp.where(lcols == j, l, 0.0), axis=1)
-            lj_m = jnp.where(rvec > j, lj, 0.0)
-        else:
-            lj = jnp.sum(jnp.where(lrows == j, l, 0.0), axis=0)
-            lj_m = jnp.where(rvec < j, lj, 0.0)
-        ljj = jnp.sum(jnp.where(rvec == j, lj, 0.0))
-        bj = jnp.sum(jnp.where(prows == j, b, 0.0), axis=0)         # B[j, :]
-        xrow = (bj - jnp.dot(lj_m, x, precision=jax.lax.Precision.HIGHEST)) / ljj
-        return jnp.where(prows == j, xrow[None, :], x)
+        keep = (lrows > j) if trans else (lrows < j)
+        lj = jnp.sum(jnp.where((lcols == j) & keep, lsrc, 0.0), axis=1,
+                     keepdims=True)                                # (t, 1)
+        ljj = jnp.sum(jnp.where((lrows == j) & (lcols == j), l, 0.0))
+        bj = jnp.sum(jnp.where(prows == j, b, 0.0), axis=0,
+                     keepdims=True)                                # B[j, :]
+        xrow = (bj - jnp.sum(lj * x, axis=0, keepdims=True)) / ljj
+        return jnp.where(prows == j, xrow, x)
 
     return jax.lax.fori_loop(0, t, step, jnp.zeros((t, k), jnp.float32))
 
@@ -51,23 +50,22 @@ def substitute_right(l: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
     """In-kernel right triangular substitution: solve ``X L^T = A`` (i.e.
     ``X = A L^{-T}``, the TRSM of the tile Cholesky) for a ``(..., t, t)``
     batch of tiles A against one (t, t) lower tile L, using only masked
-    vector ops.  Shared by :func:`trsm_pallas` and the fused band-Cholesky
-    sweep in ``kernels/band_cholesky.py`` (which substitutes its whole
-    sub-diagonal panel + arrow rows in one batched call).  Operates in and
-    returns float32."""
+    2-D vector ops.  Shared by :func:`trsm_pallas` and the fused
+    band-Cholesky sweep in ``kernels/band_cholesky.py`` (which substitutes
+    its whole sub-diagonal panel + arrow rows in one batched call).
+    Operates in and returns float32."""
     t = l.shape[-1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-    cvec = jax.lax.broadcasted_iota(jnp.int32, (t,), 0)
 
     def step(j, x):
         # X[..., j] = (A[..., j] - X[..., :j] @ L[j, :j]^T) / L[j, j]
-        lrow = jnp.sum(jnp.where(rows == j, l, 0.0), axis=0)       # L[j, :]
-        lrow_m = jnp.where(cvec < j, lrow, 0.0)
-        ljj = jnp.sum(jnp.where(cvec == j, lrow, 0.0))
-        acol = jnp.sum(jnp.where(cols == j, a, 0.0), axis=-1)      # A[..., j]
-        xcol = (acol - jnp.dot(x, lrow_m, precision=jax.lax.Precision.HIGHEST)) / ljj
-        return jnp.where(cols == j, xcol[..., None], x)
+        lrow = jnp.sum(jnp.where((rows == j) & (cols < j), l, 0.0), axis=0,
+                       keepdims=True)                              # (1, t)
+        ljj = jnp.sum(jnp.where((rows == j) & (cols == j), l, 0.0))
+        acol = jnp.sum(jnp.where(cols == j, a, 0.0), axis=-1, keepdims=True)
+        xcol = (acol - jnp.sum(x * lrow, axis=-1, keepdims=True)) / ljj
+        return jnp.where(cols == j, xcol, x)
 
     return jax.lax.fori_loop(0, t, step, jnp.zeros(a.shape, jnp.float32))
 
@@ -79,7 +77,7 @@ def _trsm_kernel(l_ref, a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def trsm_pallas(l_kk: jnp.ndarray, a_mk: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def trsm_pallas(l_kk: jnp.ndarray, a_mk: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Batched tile TRSM: broadcasting L over a batch of A tiles."""
     t = a_mk.shape[-1]
     batch_shape = a_mk.shape[:-2]
@@ -110,7 +108,7 @@ def _solve_panel_kernel(l_ref, b_ref, o_ref, *, trans):
 @functools.partial(jax.jit, static_argnames=("trans", "interpret"))
 def solve_panel_pallas(l_kk: jnp.ndarray, b_panel: jnp.ndarray,
                        trans: bool = False,
-                       interpret: bool = True) -> jnp.ndarray:
+                       *, interpret: bool) -> jnp.ndarray:
     """Batched multi-RHS panel solve, broadcasting L over leading dims of B."""
     t, k = b_panel.shape[-2], b_panel.shape[-1]
     batch_shape = b_panel.shape[:-2]

@@ -1337,7 +1337,7 @@ def _build_arrivals(stream, t: int = 8):
         rhs = None
         if spec["k"]:
             rhs = np.zeros((grid.padded_n, spec["k"]), np.float32)
-            rows = np.array([grid.padded_index(i) for i in range(n)])
+            rows = grid.padded_index(np.arange(n))
             rhs[rows] = rng.standard_normal((n, spec["k"])).astype(np.float32)
         arrivals.append((spec["arrival"], mat, rhs, spec["deadline"]))
     return arrivals

@@ -15,7 +15,6 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.optim.adamw import adamw_update, clip_by_global_norm
 from repro.optim.compress import ef_compress_allreduce, ef_init
@@ -50,14 +49,9 @@ def make_compressed_dp_step(loss_fn: Callable, mesh: Mesh, axis: str = "data",
     def step(state, batch):
         params, opt, ef = state
         specs_b = batch_specs(batch)
-        try:
-            fn = shard_map(local_step, mesh=mesh,
+        fn = jax.shard_map(local_step, mesh=mesh,
                            in_specs=(rep, rep, rep, specs_b),
                            out_specs=(rep, rep, rep, rep), check_vma=False)
-        except TypeError:
-            fn = shard_map(local_step, mesh=mesh,
-                           in_specs=(rep, rep, rep, specs_b),
-                           out_specs=(rep, rep, rep, rep), check_rep=False)
         p, o, e, m = jax.jit(fn)(params, opt, ef, batch)
         return (p, o, e), m
 

@@ -19,7 +19,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_forward", "split_stages"]
 
@@ -87,11 +86,7 @@ def pipeline_forward(stage_fn: Callable, stage_params: Any, x: jnp.ndarray,
 
     in_specs = (P(axis), P())
     out_specs = P()
-    try:
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    except TypeError:
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
     outs = jax.jit(fn)(stage_params, xs)
     return outs.reshape((B,) + x.shape[1:])

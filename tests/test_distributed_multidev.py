@@ -72,7 +72,6 @@ def test_tree_allreduce_matches_psum():
     run_multidevice("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.sharding.collectives import tree_allreduce, ring_allreduce
 mesh = Mesh(np.array(jax.devices()), ("x",))
 data = jnp.arange(8 * 5, dtype=jnp.float32).reshape(8, 5)
@@ -81,10 +80,7 @@ def f(kind):
         if kind == "tree": return tree_allreduce(x, "x")
         if kind == "ring": return ring_allreduce(x, "x")
         return jax.lax.psum(x, "x")
-    try:
-        fn = shard_map(local, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)
-    except TypeError:
-        fn = shard_map(local, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)
     return np.asarray(jax.jit(fn)(data))
 ref = f("psum")
 np.testing.assert_allclose(f("tree"), ref, rtol=1e-6)
@@ -98,16 +94,12 @@ def test_quantized_allreduce_accuracy():
     run_multidevice("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.sharding.collectives import quantized_allreduce
 mesh = Mesh(np.array(jax.devices()), ("x",))
 rng = np.random.default_rng(0)
 data = jnp.asarray(rng.standard_normal((8, 64)), jnp.float32)
 def local(x): return quantized_allreduce(x, "x")
-try:
-    fn = shard_map(local, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)
-except TypeError:
-    fn = shard_map(local, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_rep=False)
+fn = jax.shard_map(local, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)
 got = np.asarray(jax.jit(fn)(data))
 ref = np.asarray(data).sum(0, keepdims=True).repeat(8, 0)
 err = np.abs(got - ref).max() / np.abs(ref).max()

@@ -86,7 +86,7 @@ def test_kernels_bf16(rng, t):
     c = jnp.asarray(rng.standard_normal((t, t)), jnp.bfloat16)
     a = jnp.asarray(rng.standard_normal((t, t)), jnp.bfloat16)
     b = jnp.asarray(rng.standard_normal((t, t)), jnp.bfloat16)
-    got = gemm_pallas(c, a, b)
+    got = gemm_pallas(c, a, b, interpret=True)
     want = ref.gemm_ref(c.astype(jnp.float32), a.astype(jnp.float32),
                         b.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),

@@ -208,3 +208,24 @@ def test_check_only_fails_on_missing_registered_record(tmp_path, capsys):
     assert check_records(root=str(tmp_path)) == 1
     out = capsys.readouterr().out
     assert f"BENCH_{RECORD_SUITES[0]}.json" in out and "no committed" in out
+
+
+def test_compile_cache_location(tmp_path, monkeypatch):
+    """The entry scripts keep JAX's compile cache where
+    JAX_COMPILATION_CACHE_DIR says and set nothing themselves; unset, the
+    cache sits at a fixed <root>/.jax_cache, the same on every run."""
+    import jax
+    from repro.runtime import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert compile_cache.configure(str(tmp_path)) == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(str(tmp_path), ".jax_cache")
+        assert compile_cache.configure(str(tmp_path)) == want
+        assert compile_cache.configure(str(tmp_path)) == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,5 +1,7 @@
 """Per-kernel validation: sweep shapes/dtypes, assert allclose vs the
 pure-jnp oracles in kernels/ref.py (Pallas in interpret mode on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +32,7 @@ def _spd(rng, t, dtype):
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_potrf(rng, t, dtype):
     a = _spd(rng, t, dtype)
-    out = potrf_pallas(a)
+    out = potrf_pallas(a, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.potrf_ref(a)),
                                rtol=2e-4, atol=2e-4)
 
@@ -38,7 +40,7 @@ def test_potrf(rng, t, dtype):
 @pytest.mark.parametrize("t", TILES)
 def test_potrf_batched(rng, t):
     a = jnp.stack([_spd(rng, t, jnp.float32) for _ in range(3)])
-    out = potrf_pallas(a)
+    out = potrf_pallas(a, interpret=True)
     for i in range(3):
         np.testing.assert_allclose(np.asarray(out[i]),
                                    np.asarray(ref.potrf_ref(a[i])),
@@ -49,7 +51,7 @@ def test_potrf_batched(rng, t):
 def test_trsm(rng, t):
     l = ref.potrf_ref(_spd(rng, t, jnp.float32))
     a = jnp.asarray(rng.standard_normal((t, t)), jnp.float32)
-    np.testing.assert_allclose(np.asarray(trsm_pallas(l, a)),
+    np.testing.assert_allclose(np.asarray(trsm_pallas(l, a, interpret=True)),
                                np.asarray(ref.trsm_ref(l, a)),
                                rtol=2e-3, atol=2e-3)
 
@@ -58,7 +60,7 @@ def test_trsm(rng, t):
 def test_trsm_batched(rng, t):
     l = ref.potrf_ref(_spd(rng, t, jnp.float32))
     a = jnp.asarray(rng.standard_normal((4, t, t)), jnp.float32)
-    out = trsm_pallas(l, a)
+    out = trsm_pallas(l, a, interpret=True)
     for i in range(4):
         np.testing.assert_allclose(np.asarray(out[i]),
                                    np.asarray(ref.trsm_ref(l, a[i])),
@@ -72,10 +74,10 @@ def test_gemm_syrk(rng, t, kblock):
     a = jnp.asarray(rng.standard_normal((t, t)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((t, t)), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(gemm_pallas(c, a, b, kblock=kblock)),
+        np.asarray(gemm_pallas(c, a, b, kblock=kblock, interpret=True)),
         np.asarray(ref.gemm_ref(c, a, b)), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(
-        np.asarray(syrk_pallas(c, a, kblock=kblock)),
+        np.asarray(syrk_pallas(c, a, kblock=kblock, interpret=True)),
         np.asarray(ref.syrk_ref(c, a)), rtol=1e-4, atol=1e-4)
 
 
@@ -83,7 +85,7 @@ def test_gemm_syrk(rng, t, kblock):
 def test_geadd(rng, t):
     a = jnp.asarray(rng.standard_normal((5, t, t)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((5, t, t)), jnp.float32)
-    np.testing.assert_allclose(np.asarray(geadd_pallas(a, b)),
+    np.testing.assert_allclose(np.asarray(geadd_pallas(a, b, interpret=True)),
                                np.asarray(a + b), rtol=1e-6, atol=1e-6)
 
 
@@ -92,7 +94,7 @@ def test_geadd(rng, t):
 @pytest.mark.parametrize("jblock", [2, 4, 16])
 def test_band_update(rng, b1, t, jblock):
     w = jnp.asarray(rng.standard_normal((b1, b1, t, t)), jnp.float32)
-    out = band_update_pallas(w, jblock=jblock)
+    out = band_update_pallas(w, jblock=jblock, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.band_update_ref(w)),
                                rtol=2e-4, atol=2e-4)
@@ -104,7 +106,7 @@ def test_band_update(rng, b1, t, jblock):
 def test_selinv_step(rng, e_n, j_n, t, jblock):
     s = jnp.asarray(rng.standard_normal((e_n, j_n, t, t)), jnp.float32)
     g = jnp.asarray(rng.standard_normal((j_n, t, t)), jnp.float32)
-    out = selinv_step_pallas(s, g, jblock=jblock)
+    out = selinv_step_pallas(s, g, jblock=jblock, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.selinv_step_ref(s, g)),
                                rtol=2e-4, atol=2e-4)
@@ -113,10 +115,10 @@ def test_selinv_step(rng, e_n, j_n, t, jblock):
 def test_selinv_step_empty():
     s = jnp.zeros((0, 3, 8, 8), jnp.float32)
     g = jnp.zeros((3, 8, 8), jnp.float32)
-    assert selinv_step_pallas(s, g).shape == (0, 8, 8)
+    assert selinv_step_pallas(s, g, interpret=True).shape == (0, 8, 8)
     s2 = jnp.zeros((2, 0, 8, 8), jnp.float32)
     g2 = jnp.zeros((0, 8, 8), jnp.float32)
-    assert np.abs(np.asarray(selinv_step_pallas(s2, g2))).max() == 0.0
+    assert np.abs(np.asarray(selinv_step_pallas(s2, g2, interpret=True))).max() == 0.0
 
 
 def _band_factor(rng, ndt, bt, nat, t):
@@ -141,7 +143,7 @@ def test_band_forward_sweep(rng, ndt, bt, nat, k):
     t = 8
     Dr, R = _band_factor(rng, ndt, bt, nat, t)
     bd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
-    yd, acca = band_forward_sweep_pallas(Dr, R, bd)
+    yd, acca = band_forward_sweep_pallas(Dr, R, bd, interpret=True)
     yr, accr = ref.band_forward_sweep_ref(Dr, R, bd)
     np.testing.assert_allclose(np.asarray(yd), np.asarray(yr),
                                rtol=2e-4, atol=2e-4)
@@ -156,7 +158,8 @@ def test_band_forward_sweep_start_tile(rng, start_tile):
     ndt, bt, nat, t, k = 7, 2, 1, 8, 4
     Dr, R = _band_factor(rng, ndt, bt, nat, t)
     bd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
-    yd, acca = band_forward_sweep_pallas(Dr, R, bd, start_tile=start_tile)
+    yd, acca = band_forward_sweep_pallas(Dr, R, bd, start_tile=start_tile,
+                                           interpret=True)
     yr, accr = ref.band_forward_sweep_ref(Dr, R, bd, start_tile=start_tile)
     np.testing.assert_allclose(np.asarray(yd), np.asarray(yr),
                                rtol=2e-4, atol=2e-4)
@@ -172,7 +175,7 @@ def test_band_backward_sweep(rng, ndt, bt, nat, k):
     Dr, R = _band_factor(rng, ndt, bt, nat, t)
     yd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
     xa = jnp.asarray(rng.standard_normal((nat, t, k)), jnp.float32)
-    xd = band_backward_sweep_pallas(Dr, R, yd, xa)
+    xd = band_backward_sweep_pallas(Dr, R, yd, xa, interpret=True)
     xr = ref.band_backward_sweep_ref(Dr, R, yd, xa)
     np.testing.assert_allclose(np.asarray(xd), np.asarray(xr),
                                rtol=2e-4, atol=2e-4)
@@ -186,8 +189,10 @@ def test_band_sweeps_vmap(rng):
     Drb, Rb = jnp.stack(Drs), jnp.stack(Rs)
     bd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
     xa = jnp.asarray(rng.standard_normal((nat, t, k)), jnp.float32)
-    yb, ab = jax.vmap(lambda d, r: band_forward_sweep_pallas(d, r, bd))(Drb, Rb)
-    xb = jax.vmap(lambda d, r: band_backward_sweep_pallas(d, r, bd, xa))(Drb, Rb)
+    yb, ab = jax.vmap(lambda d, r: band_forward_sweep_pallas(
+        d, r, bd, interpret=True))(Drb, Rb)
+    xb = jax.vmap(lambda d, r: band_backward_sweep_pallas(
+        d, r, bd, xa, interpret=True))(Drb, Rb)
     for i in range(nb):
         yr, ar = ref.band_forward_sweep_ref(Drb[i], Rb[i], bd)
         np.testing.assert_allclose(np.asarray(yb[i]), np.asarray(yr),
@@ -251,7 +256,8 @@ def test_band_cholesky_sweep(n, bw, ar, t, nchunks):
     factored arrow rows and the per-chunk corner-Schur partial sums."""
     bm, grid = _spd_ctsf(n, bw, ar, t)
     Ac = band_row_to_col(bm.Dr)
-    got = band_cholesky_sweep_pallas(Ac, bm.R, nchunks=nchunks)
+    got = band_cholesky_sweep_pallas(Ac, bm.R, nchunks=nchunks,
+                                     interpret=True)
     want = ref.band_cholesky_sweep_ref(Ac, bm.R, nchunks=nchunks)
     for g, w, name in zip(got, want, ("panels", "R_out", "schur", "status")):
         assert g.shape == w.shape, name
@@ -265,7 +271,8 @@ def test_band_cholesky_sweep_vmap(rng):
     mats = [_spd_ctsf(130, 40, 30, 16, seed=s)[0] for s in range(3)]
     Acb = jnp.stack([band_row_to_col(m.Dr) for m in mats])
     Rb = jnp.stack([m.R for m in mats])
-    got = jax.vmap(lambda a, r: band_cholesky_sweep_pallas(a, r, nchunks=2))(
+    got = jax.vmap(lambda a, r: band_cholesky_sweep_pallas(
+        a, r, nchunks=2, interpret=True))(
         Acb, Rb)
     for i in range(3):
         want = ref.band_cholesky_sweep_ref(Acb[i], Rb[i], nchunks=2)
@@ -282,7 +289,7 @@ def test_selinv_sweep(n, bw, ar, t):
     f = factorize_window(bm, options=SolverOptions(impl="ref")).ctsf
     lcol = band_row_to_col(f.Dr)
     sc = _corner_sigma(f.C, grid.n_arrow_tiles, t)
-    gp, ga = selinv_sweep_pallas(lcol, f.R, sc)
+    gp, ga = selinv_sweep_pallas(lcol, f.R, sc, interpret=True)
     wp, wa = ref.selinv_sweep_ref(lcol, f.R, sc)
     np.testing.assert_allclose(np.asarray(gp), np.asarray(wp),
                                rtol=2e-4, atol=2e-4)
@@ -298,7 +305,8 @@ def test_selinv_sweep_vmap():
     Rb = jnp.stack([f.R for f in fs])
     scb = jnp.stack([_corner_sigma(f.C, grids[0].n_arrow_tiles, 8)
                      for f in fs])
-    gp, ga = jax.vmap(selinv_sweep_pallas)(lcolb, Rb, scb)
+    gp, ga = jax.vmap(functools.partial(selinv_sweep_pallas,
+                                        interpret=True))(lcolb, Rb, scb)
     for i in range(2):
         wp, wa = ref.selinv_sweep_ref(lcolb[i], Rb[i], scb[i])
         np.testing.assert_allclose(np.asarray(gp[i]), np.asarray(wp),
@@ -322,10 +330,12 @@ def test_fused_sweeps_are_single_launch():
     bm, grid = _spd_ctsf(130, 40, 30, 16)
     Ac = band_row_to_col(bm.Dr)
     jx = jax.make_jaxpr(
-        lambda a, r: band_cholesky_sweep_pallas(a, r, nchunks=4))(Ac, bm.R)
+        lambda a, r: band_cholesky_sweep_pallas(a, r, nchunks=4,
+                                                interpret=True))(Ac, bm.R)
     assert _count_pallas_calls(jx) == 1
     sc = jnp.zeros((2, 2, 16, 16), jnp.float32)   # tracing only needs shapes
-    jx2 = jax.make_jaxpr(selinv_sweep_pallas)(Ac, bm.R, sc)
+    jx2 = jax.make_jaxpr(functools.partial(selinv_sweep_pallas,
+                                         interpret=True))(Ac, bm.R, sc)
     assert _count_pallas_calls(jx2) == 1
 
 
@@ -344,7 +354,8 @@ def test_band_cholesky_sweep_start_tile(start_tile):
     Ac = band_row_to_col(emb.Dr)
     # traced start (as the serving path passes it) and both backends
     st = jnp.asarray(start_tile, jnp.int32)
-    got = band_cholesky_sweep_pallas(Ac, emb.R, nchunks=3, start_tile=st)
+    got = band_cholesky_sweep_pallas(Ac, emb.R, nchunks=3, start_tile=st,
+                                     interpret=True)
     want = ref.band_cholesky_sweep_ref(Ac, emb.R, nchunks=3, start_tile=st)
     for g, w, name in zip(got, want, ("panels", "R_out", "schur", "status")):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
@@ -373,7 +384,8 @@ def test_selinv_sweep_start_tile():
     lcol = band_row_to_col(f.Dr)
     sc = _corner_sigma(f.C, cgrid.n_arrow_tiles, 8)
     st = jnp.asarray(pad, jnp.int32)
-    gp, ga = selinv_sweep_pallas(lcol, f.R, sc, start_tile=st)
+    gp, ga = selinv_sweep_pallas(lcol, f.R, sc, start_tile=st,
+                                 interpret=True)
     wp, wa = ref.selinv_sweep_ref(lcol, f.R, sc, start_tile=st)
     np.testing.assert_allclose(np.asarray(gp), np.asarray(wp),
                                rtol=2e-4, atol=2e-4)
@@ -399,7 +411,8 @@ def test_band_backward_sweep_start_tile(rng, start_tile):
     yd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
     xa = jnp.asarray(rng.standard_normal((nat, t, k)), jnp.float32)
     st = jnp.asarray(start_tile, jnp.int32)
-    xd = band_backward_sweep_pallas(Dr, R, yd, xa, start_tile=st)
+    xd = band_backward_sweep_pallas(Dr, R, yd, xa, start_tile=st,
+                                    interpret=True)
     xr = ref.band_backward_sweep_ref(Dr, R, yd, xa, start_tile=st)
     np.testing.assert_allclose(np.asarray(xd), np.asarray(xr),
                                rtol=2e-4, atol=2e-4)

@@ -51,7 +51,7 @@ def test_status_word_parity_clean(n, bw, ar, t):
     word on SPD inputs: finite, positive pivot, first_bad == -1."""
     g, bm, _ = _spd(n, bw, ar, t)
     Ac = band_row_to_col(bm.Dr)
-    *_, sp = band_cholesky_sweep_pallas(Ac, bm.R)
+    *_, sp = band_cholesky_sweep_pallas(Ac, bm.R, interpret=True)
     *_, sr = ref.band_cholesky_sweep_ref(Ac, bm.R)
     sp, sr = np.asarray(sp), np.asarray(sr)
     np.testing.assert_allclose(sp[0], sr[0], rtol=2e-4)
@@ -68,7 +68,7 @@ def test_status_word_parity_corrupted(n, bw, ar, t):
     tile = g.n_diag_tiles // 2
     bad = _corrupt_diag(bm, tile=tile)
     Ac = band_row_to_col(bad.Dr)
-    *_, sp = band_cholesky_sweep_pallas(Ac, bad.R)
+    *_, sp = band_cholesky_sweep_pallas(Ac, bad.R, interpret=True)
     *_, sr = ref.band_cholesky_sweep_ref(Ac, bad.R)
     sp, sr = np.asarray(sp), np.asarray(sr)
     assert sp[1] == sr[1]

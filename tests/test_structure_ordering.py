@@ -27,6 +27,37 @@ def test_tile_grid_counts():
     assert g.padded_n == 13 * 16
 
 
+@pytest.mark.parametrize("n,bw,arrow,t,grid_bw", [
+    (96, 10, 0, 16, None),      # no arrow, tile-aligned
+    (100, 12, 5, 16, None),     # diagonal and arrow tile padding
+    (130, 20, 30, 8, None),     # nat > 1
+    (120, 30, 8, 8, 9),         # band entries past band_tiles are dropped
+])
+def test_banded_from_sparse_matches_dense_path(n, bw, arrow, t, grid_bw):
+    """The COO scatter builds exactly the arrays the padded dense matrix
+    yields, bit for bit."""
+    from repro.core import BandedCTSF
+    from repro.core.ctsf import _dense_padded
+    A, st = make_arrowhead(n, bw, arrow, rho=0.6, seed=1)
+    if grid_bw is not None:
+        st = ArrowheadStructure(n=st.n, bandwidth=grid_bw, arrow=st.arrow)
+    g = TileGrid(st, t=t)
+    got = BandedCTSF.from_sparse(A, g)
+    want = BandedCTSF.from_dense_padded(_dense_padded(A, g), g)
+    for a, b in zip(got.arrays(), want.arrays()):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_padded_index_vectorized():
+    g = TileGrid(ArrowheadStructure(n=100, bandwidth=12, arrow=5), t=16)
+    idx = np.arange(100)
+    got = g.padded_index(idx)
+    assert got.dtype == np.int64
+    assert got.tolist() == [g.padded_index(int(i)) for i in idx]
+    assert g.padded_index(94) == 94 and g.padded_index(95) == 96
+
+
 def test_tile_pattern_matches_band():
     A, st = make_arrowhead(200, 24, 16, seed=1)
     g = TileGrid(st, t=16)
