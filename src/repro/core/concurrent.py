@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.runtime import telemetry
 from .cholesky import (CholeskyFactor, _factorize_window_impl,
                        factorize_window_batched)
 from .ctsf import BandedCTSF
@@ -164,18 +165,21 @@ def concurrent_solve(factor: CholeskyFactor, B: jnp.ndarray,
     """
     from .solve import _embedded_panels, _merge_panels, _solve_panels, \
         _split_rhs
-    opts = resolve_options(options, _where="concurrent_solve",
-                           impl=impl, policy=policy)
-    impl = opts.impl
-    panel = B[:, None] if B.ndim == 1 else B
-    ctsf, _, g, panel, start, restrict = _embedded_panels(factor, opts.policy,
-                                                          panel)
-    bd, ba = _split_rhs(g, panel)
-    xd, xa = jax.vmap(
-        lambda dr, r, c: _solve_panels(dr, r, c, bd, ba, g, impl, start))(
-        ctsf.Dr, ctsf.R, ctsf.C)
-    out = restrict(jax.vmap(_merge_panels)(xd, xa))
-    return out[..., 0] if B.ndim == 1 else out
+    with telemetry.span("concurrent.solve"):
+        opts = resolve_options(options, _where="concurrent_solve",
+                               impl=impl, policy=policy)
+        impl = opts.impl
+        panel = B[:, None] if B.ndim == 1 else B
+        ctsf, _, g, panel, start, restrict = _embedded_panels(
+            factor, opts.policy, panel)
+        bd, ba = _split_rhs(g, panel)
+        with telemetry.span("solve.enqueue"):
+            xd, xa = jax.vmap(
+                lambda dr, r, c: _solve_panels(dr, r, c, bd, ba, g, impl,
+                                               start))(
+                ctsf.Dr, ctsf.R, ctsf.C)
+        out = restrict(jax.vmap(_merge_panels)(xd, xa))
+        return out[..., 0] if B.ndim == 1 else out
 
 
 def concurrent_selinv(factor: CholeskyFactor, mesh: Optional[Mesh] = None,
@@ -251,12 +255,13 @@ def concurrent_quadratic_forms(factor: CholeskyFactor, y: jnp.ndarray,
 def concurrent_logdet(factor: CholeskyFactor) -> jnp.ndarray:
     """Batched log-determinants from a batched factor (INLA's per-evaluation
     quantity)."""
-    ctsf = factor.ctsf
-    g = ctsf.grid
-    diag_band = jnp.diagonal(ctsf.Dr[:, :, 0], axis1=-2, axis2=-1)
-    total = jnp.sum(jnp.log(jnp.abs(diag_band)), axis=(-2, -1))
-    if g.n_arrow_tiles > 0:
-        ar = jnp.arange(g.n_arrow_tiles)
-        dc = jnp.diagonal(ctsf.C[:, ar, ar], axis1=-2, axis2=-1)
-        total = total + jnp.sum(jnp.log(jnp.abs(dc)), axis=(-2, -1))
-    return 2.0 * total
+    with telemetry.span("concurrent.logdet"):
+        ctsf = factor.ctsf
+        g = ctsf.grid
+        diag_band = jnp.diagonal(ctsf.Dr[:, :, 0], axis1=-2, axis2=-1)
+        total = jnp.sum(jnp.log(jnp.abs(diag_band)), axis=(-2, -1))
+        if g.n_arrow_tiles > 0:
+            ar = jnp.arange(g.n_arrow_tiles)
+            dc = jnp.diagonal(ctsf.C[:, ar, ar], axis1=-2, axis2=-1)
+            total = total + jnp.sum(jnp.log(jnp.abs(dc)), axis=(-2, -1))
+        return 2.0 * total

@@ -96,20 +96,21 @@ class SelectedInverse:
         """diag(Σ) — INLA's posterior marginal variances, every latent at
         once.  Returns the unpadded (n,) diagonal unless ``padded``."""
         g = self.grid
-        d0 = jnp.take(self.Dr, 0, axis=-3)               # (..., ndt, t, t)
-        db = jnp.diagonal(d0, axis1=-2, axis2=-1)        # (..., ndt, t)
-        db = db.reshape(db.shape[:-2] + (-1,))
-        if g.n_arrow_tiles:
-            ct = jnp.diagonal(self.C, axis1=-4, axis2=-3)   # (..., t, t, nat)
-            dc = jnp.diagonal(ct, axis1=-3, axis2=-2)       # (..., nat, t)
-            dc = dc.reshape(dc.shape[:-2] + (-1,))
-            full = jnp.concatenate([db, dc], axis=-1)
-        else:
-            full = db
-        if padded:
-            return full
-        idx = g.padded_index(np.arange(g.structure.n))
-        return jnp.take(full, jnp.asarray(idx), axis=-1)
+        with telemetry.span("selinv.diagonal"):
+            d0 = jnp.take(self.Dr, 0, axis=-3)             # (..., ndt, t, t)
+            db = jnp.diagonal(d0, axis1=-2, axis2=-1)      # (..., ndt, t)
+            db = db.reshape(db.shape[:-2] + (-1,))
+            if g.n_arrow_tiles:
+                ct = jnp.diagonal(self.C, axis1=-4, axis2=-3)  # (..,t,t,nat)
+                dc = jnp.diagonal(ct, axis1=-3, axis2=-2)      # (..., nat, t)
+                dc = dc.reshape(dc.shape[:-2] + (-1,))
+                full = jnp.concatenate([db, dc], axis=-1)
+            else:
+                full = db
+            if padded:
+                return full
+            idx = g.padded_index(np.arange(g.structure.n))
+            return jnp.take(full, jnp.asarray(idx), axis=-1)
 
     def covariance(self, i: int, j: int) -> jnp.ndarray:
         """Σ_ij for element indices of the *original* matrix.  Defined
@@ -225,14 +226,16 @@ def selected_inverse(factor: CholeskyFactor,
     with telemetry.span("selinv.selected_inverse") as sp:
         ctsf, src, pad = _resolve_embedding(factor, opts.policy)
         sp.tag(grid=telemetry.rung_tag(ctsf.grid))
+        # the plain path keeps its static-zero start_tile trace
+        start = () if src is None else (jnp.asarray(pad, jnp.int32),)
+        with telemetry.span("selinv.enqueue"):
+            sd, sr, sc = _selinv_impl(ctsf.Dr, ctsf.R, ctsf.C, ctsf.grid,
+                                      impl, *start)
+        out = SelectedInverse(ctsf.grid, sd, sr, sc)
         if src is not None:
             from .gridpolicy import restrict_selinv
-            sd, sr, sc = _selinv_impl(ctsf.Dr, ctsf.R, ctsf.C, ctsf.grid,
-                                      impl, jnp.asarray(pad, jnp.int32))
-            return restrict_selinv(SelectedInverse(ctsf.grid, sd, sr, sc),
-                                   src)
-        sd, sr, sc = _selinv_impl(ctsf.Dr, ctsf.R, ctsf.C, ctsf.grid, impl)
-        return SelectedInverse(ctsf.grid, sd, sr, sc)
+            out = restrict_selinv(out, src)
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -254,13 +257,14 @@ def _batched_selinv_fn(grid, opts, use_start=False):
     key = (grid, opts.compile_key(), use_start)
     impl = opts.impl
 
+    def batched_selinv(dr, r, c, *start):
+        with jax.named_scope("selinv.batched"):
+            return _selinv_impl(dr, r, c, grid, impl, *start)
+
     def build():
         if use_start:
-            return jax.jit(jax.vmap(
-                lambda dr, r, c, s: _selinv_impl(dr, r, c, grid, impl, s),
-                in_axes=(0, 0, 0, None)))
-        return jax.jit(jax.vmap(
-            lambda dr, r, c: _selinv_impl(dr, r, c, grid, impl)))
+            return jax.jit(jax.vmap(batched_selinv, in_axes=(0, 0, 0, None)))
+        return jax.jit(jax.vmap(batched_selinv))
 
     return _BATCHED_SELINV_CACHE.get_or_create(key, build)
 

@@ -272,6 +272,7 @@ def band_cholesky_sweep_pallas(Ac, R, nchunks: int = 1, start_tile=0,
         ],
         compiler_params=_compiler_params(b1, nat_p, t, ("arbitrary",)),
         interpret=interpret,
+        name="band_cholesky_sweep_pallas",
     )(start, Ac, rp)
     return panels, ro[:, :nat], schur[:, :nat, :nat], st[0, 0, :3]
 
@@ -416,6 +417,7 @@ def band_cholesky_partitioned_sweep_pallas(Ac, R, boundaries, start_tile=0,
         compiler_params=_compiler_params(b1, nat_p, t,
                                          ("parallel", "arbitrary")),
         interpret=interpret,
+        name="band_cholesky_partitioned_sweep_pallas",
     )(bounds_arr, start, Ac, rp)
     return (panels, ro[:, :nat], schur[:, :nat, :nat],
             combine_sweep_status(st[:, 0, :3]))

@@ -152,6 +152,7 @@ def band_forward_sweep_pallas(Dr, R, bd, start_tile=0, *, interpret: bool):
                         pltpu.VMEM((nat_p, t, k), jnp.float32)],
         compiler_params=_compiler_params(bt, nat_p, t, k),
         interpret=interpret,
+        name="band_forward_sweep_pallas",
     )(start, Dr, rp, bd)
     return yd, acca[:nat]
 
@@ -248,4 +249,5 @@ def band_backward_sweep_pallas(Dr, R, yd, xa, start_tile=0,
         scratch_shapes=[pltpu.VMEM((max(bt, 1), t, k), jnp.float32)],
         compiler_params=_compiler_params(bt, nat_p, t, k),
         interpret=interpret,
+        name="band_backward_sweep_pallas",
     )(start, lcol, rp, yd, xap)

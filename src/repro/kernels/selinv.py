@@ -259,6 +259,7 @@ def selinv_sweep_pallas(lcol, R, sc_full, start_tile=0,
             blocks=(2 * (b1 + nat_p) + nat_p * nat_p) * tile,
             temps=(3 * b1 + 2 * nat_p + 4) * tile),
         interpret=interpret,
+        name="selinv_sweep_pallas",
     )(start, lcol, rp, scp)
     return panels, acols[:, :nat]
 

@@ -18,7 +18,6 @@ import numpy as np
 from repro import configs
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models.registry import get_model
-from repro.runtime import telemetry
 from repro.sharding.partition import make_rules
 from .mesh import make_local_mesh
 from .train import reduce_config
@@ -58,36 +57,26 @@ class Server:
 
     def generate(self, batch: Dict[str, np.ndarray], gen_len: int
                  ) -> Dict[str, Any]:
-        with telemetry.span("serve.request", b=batch["tokens"].shape[0],
-                            gen_len=gen_len):
-            t0 = time.perf_counter()
-            logits, caches = self._prefill(self.params, batch)
-            caches = _pad_caches(caches, self.max_len)
-            prefill_t = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        logits, caches = self._prefill(self.params, batch)
+        caches = _pad_caches(caches, self.max_len)
+        prefill_t = time.perf_counter() - t0
+        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        out = [tok]
+        pos = batch["tokens"].shape[1]
+        t0 = time.perf_counter()
+        for i in range(gen_len - 1):
+            logits, caches = self._decode(self.params, caches, tok,
+                                          jnp.asarray(pos + i, jnp.int32))
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-            out = [tok]
-            pos = batch["tokens"].shape[1]
-            t0 = time.perf_counter()
-            for i in range(gen_len - 1):
-                logits, caches = self._decode(self.params, caches, tok,
-                                              jnp.asarray(pos + i, jnp.int32))
-                tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-                out.append(tok)
-            tokens = jnp.concatenate(out, axis=1)
-            tokens.block_until_ready()
-            decode_t = time.perf_counter() - t0
-            b = tokens.shape[0]
-            if telemetry.enabled():
-                telemetry.inc("serve.requests")
-                telemetry.inc("serve.tokens_generated", b * gen_len)
-                telemetry.observe("serve.prefill_seconds", prefill_t)
-                telemetry.observe("serve.decode_seconds", decode_t)
-                telemetry.observe("serve.request_seconds",
-                                  prefill_t + decode_t)
-            return {"tokens": np.asarray(tokens),
-                    "prefill_s": prefill_t, "decode_s": decode_t,
-                    "decode_tok_per_s": b * (gen_len - 1) / max(decode_t,
-                                                                1e-9)}
+            out.append(tok)
+        tokens = jnp.concatenate(out, axis=1)
+        tokens.block_until_ready()
+        decode_t = time.perf_counter() - t0
+        b = tokens.shape[0]
+        return {"tokens": np.asarray(tokens),
+                "prefill_s": prefill_t, "decode_s": decode_t,
+                "decode_tok_per_s": b * (gen_len - 1) / max(decode_t, 1e-9)}
 
 
 def main():

@@ -13,7 +13,11 @@ two halves:
 * **histograms** (count/sum/min/max plus p50/p90/p99 over a bounded
   sample reservoir), and
 * **nestable wall-clock spans** (per-thread stacks; every finished span
-  records its parent, so exporters can rebuild the call tree).
+  records its parent, so exporters can rebuild the call tree).  While
+  telemetry is enabled each span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so under a running
+  profiler it lands on the trace's host plane, on the same clock as the
+  device's ops; its tags stay in the registry.
 
 Recording happens at *dispatch* level only — the Python host code around
 ``jax.jit`` boundaries — never inside traced computations, following the
@@ -82,6 +86,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Telemetry", "KernelReport", "get_registry", "enable", "disable",
     "enabled", "reset", "inc", "gauge", "observe", "span", "capture",
@@ -129,8 +135,9 @@ _NOOP_SPAN = _NoopSpan()
 
 class _Span:
     """A live span: context manager that pushes onto the per-thread stack
-    on entry (capturing its parent) and records itself on exit."""
-    __slots__ = ("_reg", "name", "tags", "id", "parent", "t0")
+    on entry (capturing its parent) and records itself on exit.  It holds
+    a profiler annotation of its name open for as long as it is open."""
+    __slots__ = ("_reg", "name", "tags", "id", "parent", "t0", "_ann")
 
     def __init__(self, reg: "Telemetry", name: str, tags: Dict[str, Any]):
         self._reg = reg
@@ -139,6 +146,7 @@ class _Span:
         self.id = None
         self.parent = None
         self.t0 = None
+        self._ann = TraceAnnotation(name)
 
     def tag(self, **tags) -> "_Span":
         """Attach tags discovered mid-span (e.g. the canonical rung after
@@ -151,11 +159,13 @@ class _Span:
         self.parent = stack[-1].id if stack else None
         self.id = next(self._reg._ids)
         stack.append(self)
+        self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         stack = self._reg._span_stack()
         if stack and stack[-1] is self:
             stack.pop()

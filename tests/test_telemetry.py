@@ -167,6 +167,8 @@ def test_disabled_mode_records_nothing():
     snap = telemetry.snapshot()
     assert snap["counters"] == {} and snap["gauges"] == {}
     assert snap["histograms"] == {} and snap["spans"] == []
+    # the disabled path builds nothing, profiler annotation included
+    assert telemetry.span("s") is telemetry._NOOP_SPAN
 
 
 def test_capture_restores_previous_state():
@@ -461,6 +463,54 @@ def test_mixed_grid_replay_snapshot_and_trace():
     ids = {e["args"]["span_id"] for e in trace["traceEvents"]}
     assert all(e["args"]["parent_id"] in ids | {None}
                for e in trace["traceEvents"])
+
+
+def _theta_step(grid, m):
+    """One step of INLA's mode search as the benchmark runs it: a batched
+    factorization, its log-determinants and its conditional means."""
+    from repro import api
+    batch = BandedCTSF(grid, *(np.stack([a, a]) for a in (m.Dr, m.R, m.C)))
+    y = jax.numpy.ones(grid.padded_n, np.float32)
+
+    def step():
+        fac = api.factorize_window_batched(batch)
+        jax.block_until_ready((api.concurrent_logdet(fac),
+                               api.concurrent_solve(fac, y)))
+    return step
+
+
+def _marginals_step(grid, m):
+    """One step of INLA's posterior marginals from a factor made in
+    set-up: a selected inversion and its diagonal."""
+    from repro import api
+    f = factorize_window(m)
+
+    def step():
+        jax.block_until_ready(api.selected_inverse(f).diagonal())
+    return step
+
+
+@pytest.mark.parametrize("make,tree", [
+    (_theta_step, [("factorize.window_batched", ["factorize.enqueue"]),
+                   ("concurrent.logdet", []),
+                   ("concurrent.solve", ["solve.enqueue"])]),
+    (_marginals_step, [("selinv.selected_inverse", ["selinv.enqueue"]),
+                       ("selinv.diagonal", [])]),
+], ids=["theta", "marginals"])
+def test_step_emits_its_entry_point_spans(make, tree):
+    """Each entry point a benchmark step calls opens one outermost span,
+    with its call into the compiled executable as a child."""
+    step = make(*_problem())
+    step()                                          # warm the caches
+    telemetry.enable()
+    step()
+    spans = telemetry.snapshot()["spans"]
+    top = sorted((s for s in spans if s["parent"] is None),
+                 key=lambda s: s["ts_us"])
+    assert [s["name"] for s in top] == [name for name, _ in tree]
+    for s, (_, children) in zip(top, tree):
+        assert [c["name"] for c in spans if c["parent"] == s["id"]] \
+            == children
 
 
 def test_robustness_ladder_counters():
