@@ -20,9 +20,12 @@ the paper's left-looking accumulator reading of GEMM chains (§II):
 
   entirely from VMEM — the ``band_update`` contraction with no HBM reads;
 * the diagonal tile factorizes in-kernel (:func:`potrf.factorize_tile`,
-  shared with the single-tile POTRF kernel) and the whole sub-diagonal
-  panel + arrow rows substitute in one batched
-  :func:`trsm.substitute_right` call (shared with the TRSM kernel);
+  shared with the single-tile POTRF kernel) and is inverted once
+  (:func:`trsm.substitute_panel` against the identity, as the selinv
+  sweep seeds its columns); each sub-diagonal and arrow tile of the
+  column is then one MXU product ``X = A L_kk^{-T}`` (``tile_dot``), so
+  the column's t-step VPU loops run over one tile whatever bt + nat, and
+  each tile is stored as it is formed;
 * the corner Schur complement rides the sweep: partial sums
   ``sum_k L_a[k] L_a[k]^T`` accumulate in a VMEM scratch and emit once
   per chunk, so the corner factorization reads a precomputed
@@ -32,7 +35,7 @@ the paper's left-looking accumulator reading of GEMM chains (§II):
 
 VMEM budget per step: the panel ring bt·(bt+1)·t², the arrow ring
 bt·nat·t², the Schur accumulator nat²·t², the (bt+1+nat)·t² in/out blocks
-and the step's live values; the kernel asks Mosaic for exactly that
+and the step's live values; the kernel asks Mosaic for that
 (``_compiler_params``) — e.g. 35.6 MiB at bt=16, t=128, nat=2, of the
 128 MiB of VMEM on a v5e core.
 
@@ -49,9 +52,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .potrf import factorize_tile
-from .ring import (chunk_layout, identity_prefix_panel, ring_read, ring_write,
-                   sweep_compiler_params, tile_dot)
-from .trsm import substitute_right
+from .ring import (chunk_layout, eye_tile, identity_prefix_panel, ring_read,
+                   ring_write, sweep_compiler_params, tile_dot)
+from .trsm import substitute_panel
 
 __all__ = ["band_cholesky_sweep_pallas", "band_cholesky_partitioned_sweep_pallas"]
 
@@ -114,28 +117,43 @@ def _cholesky_column(ac_ref, r_ref, p_ref, ro_ref, sch_ref, st_ref,
               jnp.zeros((t, t), jnp.float32))
           for i in range(nat_p)]
 
-    # diagonal tile, then the whole sub-diagonal panel + arrow rows in one
-    # batched right-substitution against the fresh L_kk
+    # diagonal tile, then its inverse once: every sub-diagonal and arrow
+    # tile of the column is X = A L_kk^{-T}, one MXU product each, so the
+    # only t-step VPU loops a column runs are over a single tile
     lkk = factorize_tile(ac_ref[0, 0].astype(jnp.float32) - band_term(0))
-    stack = jnp.stack(
-        [ac_ref[0, e].astype(jnp.float32) - band_term(e)
-         for e in range(1, bt + 1)]
-        + [r_ref[0, i].astype(jnp.float32) - va[i] for i in range(nat_p)])
-    sol = substitute_right(lkk, stack)                  # (bt+nat_p, t, t)
-    panel = jnp.concatenate([lkk[None], sol[:bt]], axis=0)
-    la = sol[bt:]
+    winv = substitute_panel(lkk, eye_tile(t))           # L_kk^{-1}
 
+    def not_finite(x):
+        return jnp.max(jnp.max(jnp.where(jnp.isfinite(x), 0.0, 1.0), axis=0))
+
+    # each tile goes to its outputs as it is formed, so no value of the
+    # whole column stays live; ring slot k overwrites column k-bt, which
+    # band_term(0) and rhs were the last to read
+    p_ref[0, 0] = lkk.astype(p_ref.dtype)
     if bt:
-        ring_write(ring_ref, k, bt, panel)
-        ring_write(ringa_ref, k, bt, la)
+        ring_write(ring_ref, k, bt, lkk, 0)
+    nonfinite = not_finite(lkk)
+    for e in range(1, bt + 1):
+        x = tile_dot(ac_ref[0, e].astype(jnp.float32) - band_term(e), winv,
+                     trans_b=True)
+        p_ref[0, e] = x.astype(p_ref.dtype)
+        ring_write(ring_ref, k, bt, x, e)
+        nonfinite = jnp.maximum(nonfinite, not_finite(x))
+    la = []
+    for i in range(nat_p):
+        x = tile_dot(r_ref[0, i].astype(jnp.float32) - va[i], winv,
+                     trans_b=True)
+        ro_ref[0, i] = x.astype(ro_ref.dtype)
+        if bt:
+            ring_write(ringa_ref, k, bt, x, i)
+        nonfinite = jnp.maximum(nonfinite, not_finite(x))
+        la.append(x)
 
     # corner-Schur partial sums on the fly: sacc[i,j] += La[i] @ La[j]^T
     for i in range(nat_p):
         for j in range(nat_p):
             sacc_ref[i, j] += tile_dot(la[i], la[j], trans_b=True)
     sch_ref[0] = sacc_ref[...].astype(sch_ref.dtype)
-    p_ref[0] = panel.astype(p_ref.dtype)
-    ro_ref[0] = la.astype(ro_ref.dtype)
 
     # in-sweep breakdown detection (masked 2-D reductions only)
     rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
@@ -144,9 +162,6 @@ def _cholesky_column(ac_ref, r_ref, p_ref, ro_ref, sch_ref, st_ref,
     dsq = jnp.where(dmask, lkk * lkk, jnp.float32(jnp.inf))
     nonfin_d = jnp.max(jnp.where(dmask & ~jnp.isfinite(lkk), 1.0, 0.0))
     piv = jnp.where(nonfin_d > 0.0, jnp.float32(jnp.inf), jnp.min(dsq))
-    nonfinite = jnp.maximum(
-        jnp.max(jnp.max(jnp.where(jnp.isfinite(panel), 0.0, 1.0), axis=0)),
-        jnp.max(jnp.max(jnp.where(jnp.isfinite(la), 0.0, 1.0), axis=0)))
     bad = (nonfinite > 0.0) | (piv <= 0.0)
     _status_fold(st_ref, piv, nonfinite, bad, col)
 
@@ -195,7 +210,7 @@ def _band_cholesky_kernel(start_ref, ac_ref, r_ref, p_ref, ro_ref, sch_ref,
 def _compiler_params(b1, nat_p, t, semantics):
     """VMEM budget of one Cholesky sweep step: the panel and arrow rings
     and the Schur accumulator (scratch), the in/out column blocks, and the
-    step's live values (band update, substitution stack and carry)."""
+    step's live values (an allowance sized for the whole column)."""
     tile = t * t * 4
     bt = max(b1 - 1, 1)
     return sweep_compiler_params(

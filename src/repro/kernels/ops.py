@@ -162,9 +162,10 @@ def band_cholesky_sweep(Ac: jnp.ndarray, R: jnp.ndarray, nchunks: int = 1,
     factorization ever raising mid-batch.
 
     ``"pallas"`` runs one fused kernel for the entire factorization (VMEM
-    ring of the last band_tiles panels + arrow ring, in-kernel potrf/trsm,
-    Schur accumulated on the fly); ``"ref"`` the ring-buffer ``lax.scan``
-    that dispatches per-panel tile ops.  This is what
+    ring of the last band_tiles panels + arrow ring, in-kernel potrf, the
+    panel solved by products with L_kk^{-1}, Schur accumulated on the
+    fly); ``"ref"`` the ring-buffer ``lax.scan`` that dispatches per-panel
+    tile ops.  This is what
     ``core.cholesky._factorize_window_impl`` rides on every backend.
 
     ``start_tile`` (traced) declares the first ``start_tile`` columns an

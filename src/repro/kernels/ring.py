@@ -116,10 +116,11 @@ def ring_read(ring_ref, row, depth: int, *idx):
     return ring_ref[(jax.lax.rem(row + depth, depth),) + idx]
 
 
-def ring_write(ring_ref, row, depth: int, panel):
-    """Store ``panel`` as absolute row ``row`` in the ring, overwriting the
-    entry ``depth`` rows back (which no later step can need)."""
-    ring_ref[jax.lax.rem(row + depth, depth)] = panel
+def ring_write(ring_ref, row, depth: int, panel, *idx):
+    """Store ``panel`` as absolute row ``row`` in the ring (``idx`` selects
+    one tile of it), overwriting the entry ``depth`` rows back (which no
+    later step can need)."""
+    ring_ref[(jax.lax.rem(row + depth, depth),) + idx] = panel
 
 
 def ring_accumulate(ring_ref, row, depth: int, init, term, step: int = -1):

@@ -22,8 +22,10 @@ def substitute_panel(l: jnp.ndarray, b: jnp.ndarray,
     ``L^T X = B``) for one (t, t) lower-triangular tile against a (t, k)
     panel, using only masked 2-D vector ops (no gather/scatter, no 1-D
     vectors, no matrix-vector ``dot``) so it lowers inside a Mosaic kernel
-    body.  Shared by :func:`solve_panel_pallas` and the fused band sweeps
-    in ``kernels/band_solve.py``.  Operates in and returns float32."""
+    body.  Shared by :func:`solve_panel_pallas`, the fused band sweeps
+    in ``kernels/band_solve.py``, and the Cholesky and selinv sweeps,
+    which form ``L_kk^{-1}`` with it (``B`` the identity).  Operates in
+    and returns float32."""
     t, k = l.shape[-1], b.shape[-1]
     lrows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
     lcols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
@@ -50,10 +52,9 @@ def substitute_right(l: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
     """In-kernel right triangular substitution: solve ``X L^T = A`` (i.e.
     ``X = A L^{-T}``, the TRSM of the tile Cholesky) for a ``(..., t, t)``
     batch of tiles A against one (t, t) lower tile L, using only masked
-    2-D vector ops.  Shared by :func:`trsm_pallas` and the fused
-    band-Cholesky sweep in ``kernels/band_cholesky.py`` (which substitutes
-    its whole sub-diagonal panel + arrow rows in one batched call).
-    Operates in and returns float32."""
+    2-D vector ops.  The kernel of :func:`trsm_pallas`; the fused sweeps
+    invert their diagonal tile once with :func:`substitute_panel` and
+    multiply instead.  Operates in and returns float32."""
     t = l.shape[-1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)

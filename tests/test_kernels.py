@@ -435,3 +435,72 @@ def test_band_update_ref_semantics(rng):
             want[e] += w[e, e + j] @ w[0, j].T
     np.testing.assert_allclose(np.asarray(ref.band_update_ref(jnp.asarray(w))),
                                want, rtol=1e-4, atol=1e-4)
+
+
+def _near_singular_ctsf(n, bw, ar, t, cond=1e4):
+    """A banded-arrowhead CTSF whose cond(Q) is about ``cond``: the
+    smallest eigenvalue of :func:`near_singular_arrowhead` is set from the
+    spread of the spectrum, so cond(L_kk) reaches about sqrt(cond)."""
+    from repro.core import BandedCTSF, TileGrid
+    from repro.data import near_singular_arrowhead
+    A1, _ = near_singular_arrowhead(n, bw, ar, seed=0, eig_min=1.0)
+    spread = np.linalg.eigvalsh(A1.toarray())[-1] - 1.0
+    A, st = near_singular_arrowhead(n, bw, ar, seed=0,
+                                    eig_min=spread / (cond - 1.0))
+    ev = np.linalg.eigvalsh(A.toarray())
+    assert 0.5 * cond < ev[-1] / ev[0] < 2.0 * cond
+    grid = TileGrid(st, t=t)
+    return BandedCTSF.from_sparse(A, grid), grid
+
+
+def _factor_error(panels, r_out, L, ndt, t):
+    """Frobenius error of the emitted band panels and arrow rows against
+    the float64 dense factor ``L``, over the norm of the same tiles of L."""
+    panels = np.asarray(panels, np.float64)
+    r_out = np.asarray(r_out, np.float64)
+    got, want = [], []
+    for k in range(ndt):
+        blk = slice(k * t, (k + 1) * t)
+        for e in range(panels.shape[1]):
+            row = (k + e) * t
+            ref_tile = (L[row:row + t, blk] if k + e < ndt
+                        else np.zeros((t, t)))
+            got.append(panels[k, e])
+            want.append(ref_tile)
+        for i in range(r_out.shape[1]):
+            row = (ndt + i) * t
+            got.append(r_out[k, i])
+            want.append(L[row:row + t, blk])
+    got, want = np.stack(got), np.stack(want)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("sweep", ["plain", "partitioned"])
+@pytest.mark.parametrize("nat", [0, 1, 2])
+@pytest.mark.parametrize("bt", [0, 2, 3])
+def test_band_cholesky_sweep_accuracy_poorly_conditioned(bt, nat, sweep):
+    """On a band with cond(Q) about 1e4 the fused sweep, which applies
+    L_kk^{-1} to each column's tiles by one MXU product, is no less accurate
+    against a float64 dense Cholesky than the ``ref`` backend, which
+    substitutes each tile against L_kk: at most twice its factor error.
+    The partitioned kernel runs a one-partition plan: a cut through a
+    near-singular band would change the matrix."""
+    from repro.kernels.band_cholesky import (
+        band_cholesky_partitioned_sweep_pallas)
+    t = 8
+    nd, bw = {0: (8, 3), 2: (64, 12), 3: (64, 20)}[bt]
+    bm, grid = _near_singular_ctsf(nd + 6 * nat, bw, 6 * nat, t)
+    ndt = grid.n_diag_tiles
+    assert (grid.band_tiles, grid.n_arrow_tiles) == (bt, nat)
+    L = np.linalg.cholesky(bm.to_dense(lower_only=False).astype(np.float64))
+    Ac = band_row_to_col(bm.Dr)
+    if sweep == "plain":
+        got = band_cholesky_sweep_pallas(Ac, bm.R, interpret=True)
+        want = ref.band_cholesky_sweep_ref(Ac, bm.R)
+    else:
+        got = band_cholesky_partitioned_sweep_pallas(Ac, bm.R, (0, ndt),
+                                                     interpret=True)
+        want = ref.band_cholesky_partitioned_sweep_ref(Ac, bm.R, (0, ndt))
+    err = _factor_error(got[0], got[1], L, ndt, t)
+    err_ref = _factor_error(want[0], want[1], L, ndt, t)
+    assert np.isfinite(err) and err <= 2.0 * err_ref, (err, err_ref)
