@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops
+from repro.kernels import ops, ring
+from repro.kernels.band_cholesky import stream_bytes, sweep_path
 from repro.kernels.ref import sweep_status
 from repro.kernels.ring import band_col_to_row, band_row_to_col
 from repro.runtime import telemetry
@@ -309,49 +310,11 @@ def _corner_schur(R_L: jnp.ndarray, tree_chunks: int) -> jnp.ndarray:
     return terms.sum(axis=0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("grid", "impl", "tree_chunks", "sweep",
-                                    "plan"))
-def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
-                           start_tile=0, plan=None):
-    """Window factorization with sweep-mode dispatch:
-
-    * ``"auto"`` (default) — ``"partitioned"`` when ``plan`` (a
-      :class:`~repro.core.ordering.PartitionPlan`) has more than one
-      partition; else ``"fused"`` on the Pallas backend (native TPU or an
-      explicit ``impl="pallas"``), else ``"ring"``: every caller
-      (:func:`factorize_window`, :func:`factorize_window_batched`,
-      ``concurrent_factorize``) rides the fused kernel wherever Pallas is
-      the kernel backend.
-    * ``"fused"`` — force the single-launch Pallas sweep
-      (``kernels/band_cholesky.py``).
-    * ``"ring"`` — force the ring-buffer ``lax.scan`` reference.
-    * ``"window"`` — the legacy dynamic-slice window sweep
-      (``kernels.band_update`` per panel), kept for comparison.
-    * ``"partitioned"`` — the multi-partition fused sweep
-      (``kernels.ops.band_cholesky_partitioned_sweep``): one 2D-grid
-      launch over all of ``plan``'s independent band partitions, their
-      per-partition corner-Schur leaves tree-combined before the shared
-      corner factorization.  Requires a ``plan``; a trivial
-      single-partition plan stays on the fused/ring path so its factor is
-      bit-identical to a plan-less call.
-
-    The fused/ring paths read the corner Schur complement from the sweep's
-    per-chunk partial sums (accumulated on the fly in the fused kernel)
-    instead of re-contracting R_out from HBM.
-
-    ``start_tile`` declares the first band columns an identity-embedding
-    prefix (``core/gridpolicy.py``); callers omit it on the plain path so
-    the argument stays a trace-time constant 0 (keeping the static loop
-    bounds), and pass a *traced* scalar on the canonical-grid path so
-    distinct pad depths share one compilation per canonical grid.
-
-    Returns ``(Dr_L, R_L, C_L, status)`` — ``status`` the (3,) float32
-    breakdown word ``[min_pivot, nonfinite, first_bad]`` covering band
-    *and* corner (a corner breakdown reports ``first_bad = ndt``).  It is
-    carried in-graph with no host sync; the jitter ladder
-    (``core/robustness.py``) is the consumer."""
-    nat = grid.n_arrow_tiles
+def _resolve_sweep(grid, impl, sweep, plan=None) -> str:
+    """The sweep :func:`_factorize_window_impl` runs for ``sweep``: one of
+    ``"fused"``, ``"stream"``, ``"ring"``, ``"window"`` or
+    ``"partitioned"`` (``"stream"`` only by the ``"auto"`` choice).
+    Refuses contradictory or impossible requests."""
     if sweep not in ("auto", "fused", "ring", "window", "partitioned"):
         raise ValueError(f"unknown sweep {sweep!r} (want 'auto', 'fused', "
                          "'ring', 'window' or 'partitioned')")
@@ -374,13 +337,88 @@ def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
             f"partition plan covers {plan.n_tiles} diagonal tiles but the "
             f"grid has {grid.n_diag_tiles}; rebuild the plan for this grid "
             "(PartitionPlan.shifted embeds a plan into a canonical grid)")
-    mode = sweep
-    if mode == "auto":
-        if plan is not None and plan.n_partitions > 1:
-            mode = "partitioned"
-        else:
-            mode = "fused" if (impl or ops.default_impl()) == "pallas" \
-                else "ring"
+    path = sweep_path(grid.t, grid.band_tiles, grid.n_arrow_tiles)
+    if sweep == "fused" and path != "fused":
+        raise ValueError(
+            f"sweep='fused' cannot run at t={grid.t}, band_tiles="
+            f"{grid.band_tiles}, arrow tiles {grid.n_arrow_tiles}: its VMEM "
+            f"ring asks for more than the {ring.VMEM_CAP_BYTES / 2**20:g} "
+            "MiB cap (kernels/ring.py); sweep='auto' streams such a band "
+            "from HBM")
+    if sweep != "auto":
+        return sweep
+    if plan is not None and plan.n_partitions > 1:
+        return "partitioned"
+    if (impl or ops.default_impl()) != "pallas":
+        return "ring"
+    return path
+
+
+def _record_sweep(span, grid, opts: SolverOptions, plan):
+    """Counts a dispatch by the sweep it takes (``cholesky.sweep
+    {path=}``) and tags the entry point's span with it; a streamed sweep's
+    span also carries ``stream_bytes``, the HBM bytes its DMAs move for
+    one matrix."""
+    if not telemetry.enabled():
+        return
+    path = _resolve_sweep(grid, opts.impl, opts.sweep, plan)
+    telemetry.inc("cholesky.sweep", path=path)
+    span.tag(sweep=path)
+    if path == "stream":
+        span.tag(stream_bytes=stream_bytes(grid.n_diag_tiles,
+                                           grid.band_tiles,
+                                           grid.n_arrow_tiles, grid.t))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("grid", "impl", "tree_chunks", "sweep",
+                                    "plan"))
+def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
+                           start_tile=0, plan=None):
+    """Window factorization with sweep-mode dispatch
+    (:func:`_resolve_sweep`):
+
+    * ``"auto"`` (default) — ``"partitioned"`` when ``plan`` (a
+      :class:`~repro.core.ordering.PartitionPlan`) has more than one
+      partition; else on the Pallas backend (native TPU or an explicit
+      ``impl="pallas"``) the fused sweep while its VMEM ring fits the
+      chip and the streamed sweep beyond (a choice made from the grid's
+      tile size, band and arrow tiles alone,
+      ``kernels.band_cholesky.sweep_path``); else ``"ring"``: every caller
+      (:func:`factorize_window`, :func:`factorize_window_batched`,
+      ``concurrent_factorize``) rides a fused kernel wherever Pallas is the
+      kernel backend.
+    * ``"fused"`` — force the single-launch Pallas sweep with the VMEM ring
+      (``kernels/band_cholesky.py``); a ``ValueError`` where its ring
+      cannot fit.
+    * ``"ring"`` — force the ring-buffer ``lax.scan`` reference.
+    * ``"window"`` — the legacy dynamic-slice window sweep
+      (``kernels.band_update`` per panel), kept for comparison.
+    * ``"partitioned"`` — the multi-partition fused sweep
+      (``kernels.ops.band_cholesky_partitioned_sweep``): one 2D-grid
+      launch over all of ``plan``'s independent band partitions, their
+      per-partition corner-Schur leaves tree-combined before the shared
+      corner factorization.  Requires a ``plan``; a trivial
+      single-partition plan stays on the fused/ring path so its factor is
+      bit-identical to a plan-less call.
+
+    The fused, streamed and ring paths read the corner Schur complement
+    from the sweep's per-chunk partial sums (accumulated on the fly in the
+    kernels) instead of re-contracting R_out from HBM.
+
+    ``start_tile`` declares the first band columns an identity-embedding
+    prefix (``core/gridpolicy.py``); callers omit it on the plain path so
+    the argument stays a trace-time constant 0 (keeping the static loop
+    bounds), and pass a *traced* scalar on the canonical-grid path so
+    distinct pad depths share one compilation per canonical grid.
+
+    Returns ``(Dr_L, R_L, C_L, status)`` — ``status`` the (3,) float32
+    breakdown word ``[min_pivot, nonfinite, first_bad]`` covering band
+    *and* corner (a corner breakdown reports ``first_bad = ndt``).  It is
+    carried in-graph with no host sync; the jitter ladder
+    (``core/robustness.py``) is the consumer."""
+    nat = grid.n_arrow_tiles
+    mode = _resolve_sweep(grid, impl, sweep, plan)
     if mode == "partitioned":
         panels, R_out, schur, status = ops.band_cholesky_partitioned_sweep(
             band_row_to_col(Dr), R, plan.boundaries, start_tile=start_tile,
@@ -408,7 +446,8 @@ def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
         return Dr_out, R_out, C_out, fold_corner_status(
             status, C_out, grid.n_diag_tiles, nat)
 
-    sweep_impl = "pallas" if mode == "fused" else "ref"
+    # the kernel layer runs the streamed sweep where the ring cannot fit
+    sweep_impl = "pallas" if mode in ("fused", "stream") else "ref"
     nchunks = max(1, min(tree_chunks or 1, grid.n_diag_tiles or 1))
     panels, R_out, schur, status = ops.band_cholesky_sweep(
         band_row_to_col(Dr), R, nchunks=nchunks, start_tile=start_tile,
@@ -495,6 +534,7 @@ def factorize_window(m: BandedCTSF, impl=UNSET,
             call = lambda dr, r, c: _factorize_window_impl(
                 dr, r, c, m.grid, opts.impl, tree_chunks, opts.sweep,
                 plan=plan)
+        _record_sweep(sp, m.grid, opts, plan)
         if pol is None:
             Dr, R, C, _status = call(m.Dr, m.R, m.C)
             info = None
@@ -649,6 +689,7 @@ def factorize_window_batched(batch, impl=UNSET,
             call = lambda dr, r, c: fn(dr, r, c, start)
         else:
             call = _batched_window_fn(grid, opts, tree_chunks)
+        _record_sweep(sp, grid, opts, opts.partition_plan)
         pol = RegularizePolicy.resolve(opts.regularize)
         if pol is None:
             with telemetry.span("factorize.enqueue"):

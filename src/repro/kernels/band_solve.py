@@ -51,8 +51,9 @@ __all__ = ["band_forward_sweep_pallas", "band_backward_sweep_pallas",
 def _compiler_params(bt, nat_p, t, k):
     """VMEM budget of one band-solve step: the (t, k) panel ring and arrow
     accumulator (scratch), the factor column/row block, arrow rows and
-    (t, k) panels in and out, and the step's live panels."""
-    panel = t * k * 4
+    (t, k) panels in and out, and the step's live panels.  A panel takes
+    VMEM for whole 128-lane rows, however few its columns."""
+    panel = t * -(-k // 128) * 128 * 4
     return sweep_compiler_params(
         scratch=(max(bt, 1) + nat_p) * panel,
         blocks=(bt + 1 + nat_p) * t * t * 4 + (2 + 2 * nat_p) * panel,

@@ -20,7 +20,8 @@ from .trsm import solve_panel_pallas, trsm_pallas
 from .gemm import gemm_pallas, syrk_pallas, geadd_pallas
 from .band_update import band_update_pallas
 from .band_cholesky import (band_cholesky_partitioned_sweep_pallas,
-                            band_cholesky_sweep_pallas)
+                            band_cholesky_stream_sweep_pallas,
+                            band_cholesky_sweep_pallas, sweep_path)
 from .band_solve import band_backward_sweep_pallas, band_forward_sweep_pallas
 from .selinv import selinv_step_pallas, selinv_sweep_pallas
 
@@ -164,9 +165,12 @@ def band_cholesky_sweep(Ac: jnp.ndarray, R: jnp.ndarray, nchunks: int = 1,
     ``"pallas"`` runs one fused kernel for the entire factorization (VMEM
     ring of the last band_tiles panels + arrow ring, in-kernel potrf, the
     panel solved by products with L_kk^{-1}, Schur accumulated on the
-    fly); ``"ref"`` the ring-buffer ``lax.scan`` that dispatches per-panel
-    tile ops.  This is what
-    ``core.cholesky._factorize_window_impl`` rides on every backend.
+    fly), or where that ring cannot fit in VMEM
+    (``band_cholesky.sweep_path``) the streamed kernel, which keeps the
+    factor in HBM and streams each column's update through VMEM;
+    ``"ref"`` the ring-buffer ``lax.scan`` that dispatches per-panel tile
+    ops.  This is what ``core.cholesky._factorize_window_impl`` rides on
+    every backend.
 
     ``start_tile`` (traced) declares the first ``start_tile`` columns an
     identity-embedding prefix (``core/gridpolicy.py``): both backends emit
@@ -174,9 +178,12 @@ def band_cholesky_sweep(Ac: jnp.ndarray, R: jnp.ndarray, nchunks: int = 1,
     their compute entirely."""
     impl = impl or default_impl()
     if impl == "pallas":
-        return band_cholesky_sweep_pallas(Ac, R, nchunks=nchunks,
-                                          start_tile=start_tile,
-                                          interpret=_interp())
+        t, bt, nat = Ac.shape[-1], Ac.shape[1] - 1, R.shape[1]
+        sweep = band_cholesky_sweep_pallas \
+            if sweep_path(t, bt, nat) == "fused" \
+            else band_cholesky_stream_sweep_pallas
+        return sweep(Ac, R, nchunks=nchunks, start_tile=start_tile,
+                     interpret=_interp())
     return ref.band_cholesky_sweep_ref(Ac, R, nchunks=nchunks,
                                        start_tile=start_tile)
 

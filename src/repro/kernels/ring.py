@@ -21,8 +21,9 @@ In-kernel helpers (operate on VMEM scratch refs):
   entries that forms each sweep's bounded-history contraction.
   :func:`tile_dot` — the one 2-D float32 MXU contraction every sweep uses.
 
-Compile-time helper: :func:`sweep_compiler_params` — each sweep's VMEM
-limit, computed from its own scratch, block and live-value sizes.
+Compile-time helpers: :func:`vmem_ask` — each sweep's VMEM need, computed
+from its own scratch, block and live-value sizes — and
+:func:`sweep_compiler_params`, that need capped at ``VMEM_CAP_BYTES``.
 
 Host-side helpers (plain jnp, used by the kernel wrappers and the ref
 oracles):
@@ -44,7 +45,7 @@ import jax.numpy as jnp
 __all__ = ["ring_read", "ring_write", "ring_accumulate",
            "band_row_to_col", "band_col_to_row", "chunk_layout",
            "eye_tile", "identity_prefix_panel", "tile_dot",
-           "sweep_compiler_params"]
+           "vmem_ask", "sweep_compiler_params"]
 
 # v5e has 128 MiB of VMEM per core; leave the rest to Mosaic's own scratch.
 VMEM_CAP_BYTES = 100 * 2 ** 20
@@ -68,18 +69,23 @@ def tile_dot(a, b, trans_a: bool = False, trans_b: bool = False):
                                preferred_element_type=jnp.float32)
 
 
+def vmem_ask(*, scratch: int, blocks: int, temps: int) -> int:
+    """The VMEM one sequential sweep needs, uncapped: the kernel's scratch,
+    its double-buffered in/out blocks and its live values (all in bytes)
+    plus headroom, floored at the default scoped limit."""
+    return max(scratch + 2 * blocks + temps + _VMEM_HEADROOM_BYTES,
+               _VMEM_FLOOR_BYTES)
+
+
 def sweep_compiler_params(*, scratch: int, blocks: int, temps: int,
                           semantics=("arbitrary",)):
     """Mosaic compiler params for one sequential sweep: the VMEM limit is
-    the kernel's scratch, its double-buffered in/out blocks and its live
-    values (all in bytes) plus headroom, floored at the default scoped
-    limit and capped below the chip's VMEM."""
+    its :func:`vmem_ask`, capped below the chip's VMEM."""
     from jax.experimental.pallas import tpu as pltpu
-    need = scratch + 2 * blocks + temps + _VMEM_HEADROOM_BYTES
     return pltpu.CompilerParams(
         dimension_semantics=tuple(semantics),
-        vmem_limit_bytes=int(min(max(need, _VMEM_FLOOR_BYTES),
-                                 VMEM_CAP_BYTES)))
+        vmem_limit_bytes=int(min(vmem_ask(scratch=scratch, blocks=blocks,
+                                          temps=temps), VMEM_CAP_BYTES)))
 
 
 def eye_tile(t: int, dtype=jnp.float32) -> jnp.ndarray:

@@ -4,8 +4,10 @@ Nothing runs: the TPU compiler installed with JAX compiles each kernel for
 a chip that is described, not attached, and refuses what the chip would
 refuse (VMEM over-subscription, unsupported layouts or contractions) —
 which interpret-mode tests cannot see.  Shapes are the real tile and band
-widths (t=128, band_tiles 8 and 16 with two arrow tiles) at a small number
-of diagonal tiles; the grid length does not change what Mosaic checks.
+widths (t=128, band_tiles 8 and 16 with two arrow tiles, and Table II
+ID 19's 118 band tiles with one arrow tile, where the streamed Cholesky
+sweep takes over) at a small number of diagonal tiles; the grid length
+does not change what Mosaic checks.
 """
 import functools
 
@@ -15,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.band_cholesky import (band_cholesky_partitioned_sweep_pallas,
+                                         band_cholesky_stream_sweep_pallas,
                                          band_cholesky_sweep_pallas)
 from repro.kernels.band_solve import (band_backward_sweep_pallas,
                                       band_forward_sweep_pallas)
@@ -22,6 +25,8 @@ from repro.kernels.selinv import selinv_sweep_pallas
 
 T, NDT, NAT, K = 128, 12, 2, 8
 WIDTHS = [8, 16]
+# Table II ID 19 at t=128: band tiles and arrow tiles
+WIDE_BT, WIDE_NAT = 118, 1
 
 
 @pytest.fixture(scope="module")
@@ -97,6 +102,32 @@ def test_vmapped_band_cholesky_batch_compiles(shape):
              shape(8, NDT, NAT, T, T))
 
 
+def test_stream_sweep_compiles(shape):
+    _compile(lambda a, r, s: band_cholesky_stream_sweep_pallas(
+        a, r, nchunks=4, start_tile=s, interpret=False),
+        shape(NDT, WIDE_BT + 1, T, T), shape(NDT, WIDE_NAT, T, T),
+        shape(dtype=jnp.int32))
+
+
+def test_vmapped_stream_sweep_compiles(shape):
+    sweep = functools.partial(band_cholesky_stream_sweep_pallas, nchunks=4,
+                              interpret=False)
+    _compile(jax.vmap(sweep), shape(1, NDT, WIDE_BT + 1, T, T),
+             shape(1, NDT, WIDE_NAT, T, T))
+
+
+def test_wide_band_solve_sweeps_compile(shape):
+    """Both band solves at ID 19's width, with the one right-hand side a
+    θ probe solves for: their VMEM ask covers the lane-padded panels."""
+    args = (shape(NDT, WIDE_BT + 1, T, T), shape(NDT, WIDE_NAT, T, T))
+    _compile(lambda d, r, b, s: band_forward_sweep_pallas(
+        d, r, b, s, interpret=False), *args, shape(NDT, T, 1),
+        shape(dtype=jnp.int32))
+    _compile(lambda d, r, y, x, s: band_backward_sweep_pallas(
+        d, r, y, x, s, interpret=False), *args, shape(NDT, T, 1),
+        shape(WIDE_NAT, T, 1), shape(dtype=jnp.int32))
+
+
 KERNEL_NAMES = {
     "band_cholesky_sweep_pallas": lambda shape, bt: (
         lambda a, r, s: band_cholesky_sweep_pallas.__wrapped__(
@@ -113,6 +144,12 @@ KERNEL_NAMES = {
             d, r, y, x, s, interpret=False),
         (shape(NDT, bt + 1, T, T), shape(NDT, NAT, T, T), shape(NDT, T, K),
          shape(NAT, T, K), shape(dtype=jnp.int32))),
+    # the streamed sweep at the width that takes it, whatever ``bt``
+    "band_cholesky_stream_sweep_pallas": lambda shape, bt: (
+        lambda a, r, s: band_cholesky_stream_sweep_pallas.__wrapped__(
+            a, r, 4, s, interpret=False),
+        (shape(NDT, WIDE_BT + 1, T, T), shape(NDT, WIDE_NAT, T, T),
+         shape(dtype=jnp.int32))),
     "selinv_sweep_pallas": lambda shape, bt: (
         lambda l, r, c, s: selinv_sweep_pallas.__wrapped__(
             l, r, c, s, interpret=False),
