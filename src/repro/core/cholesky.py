@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.kernels import ops, ring
 from repro.kernels.band_cholesky import stream_bytes, sweep_path
+from repro.kernels.potrf import tile_block
 from repro.kernels.ref import sweep_status
 from repro.kernels.ring import band_col_to_row, band_row_to_col
 from repro.runtime import telemetry
@@ -356,14 +357,18 @@ def _resolve_sweep(grid, impl, sweep, plan=None) -> str:
 
 def _record_sweep(span, grid, opts: SolverOptions, plan):
     """Counts a dispatch by the sweep it takes (``cholesky.sweep
-    {path=}``) and tags the entry point's span with it; a streamed sweep's
-    span also carries ``stream_bytes``, the HBM bytes its DMAs move for
-    one matrix."""
+    {path=}``) and tags the entry point's span with it; a Pallas sweep's
+    span also carries ``tile_block``, the row block its column finish
+    factors and inverts the diagonal tile by (``potrf.tile_block``: t where
+    unblocked), and a streamed sweep's ``stream_bytes``, the HBM bytes its
+    DMAs move for one matrix."""
     if not telemetry.enabled():
         return
     path = _resolve_sweep(grid, opts.impl, opts.sweep, plan)
     telemetry.inc("cholesky.sweep", path=path)
     span.tag(sweep=path)
+    if path in ("fused", "stream", "partitioned"):
+        span.tag(tile_block=tile_block(grid.t))
     if path == "stream":
         span.tag(stream_bytes=stream_bytes(grid.n_diag_tiles,
                                            grid.band_tiles,

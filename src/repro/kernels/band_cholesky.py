@@ -21,11 +21,15 @@ the paper's left-looking accumulator reading of GEMM chains (§II):
   entirely from VMEM — the ``band_update`` contraction with no HBM reads;
 * the diagonal tile factorizes in-kernel (:func:`potrf.factorize_tile`,
   shared with the single-tile POTRF kernel) and is inverted once
-  (:func:`trsm.substitute_panel` against the identity, as the selinv
-  sweep seeds its columns); each sub-diagonal and arrow tile of the
-  column is then one MXU product ``X = A L_kk^{-T}`` (``tile_dot``), so
-  the column's t-step VPU loops run over one tile whatever bt + nat, and
-  each tile is stored as it is formed;
+  (:func:`trsm.invert_lower_tile`); each sub-diagonal and arrow tile of
+  the column is then one MXU product ``X = A L_kk^{-T}`` (``tile_dot``),
+  so the column's VPU loops run over one tile whatever bt + nat, and each
+  tile is stored as it is formed.  Both tile routines are blocked by
+  nb = 32 rows where t >= 64 and 32 divides t (``potrf.tile_block``, from
+  t alone; every t = 128 column): the factorization runs its t pivots on
+  (nb, t) row slabs with one MXU trailing update a slab, the inverse one
+  nb-step substitution over the diagonal blocks and a few MXU products
+  for the rest.  Smaller tiles run the unblocked t-step loops;
 * the corner Schur complement rides the sweep: partial sums
   ``sum_k L_a[k] L_a[k]^T`` accumulate in a VMEM scratch and emit once
   per chunk, so the corner factorization reads a precomputed
@@ -74,9 +78,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import ring
 from .potrf import factorize_tile
-from .ring import (chunk_layout, eye_tile, identity_prefix_panel, ring_read,
+from .ring import (chunk_layout, identity_prefix_panel, ring_read,
                    ring_write, sweep_compiler_params, tile_dot)
-from .trsm import substitute_panel
+from .trsm import invert_lower_tile
 
 __all__ = ["band_cholesky_sweep_pallas", "band_cholesky_stream_sweep_pallas",
            "band_cholesky_partitioned_sweep_pallas", "sweep_path",
@@ -120,10 +124,11 @@ def _finish_column(a_kk, band_in, arrow_in, put_band, put_arrow, sacc_ref,
                    unroll: bool = True):
     """Everything of one column after its left-looking update, shared by
     every Cholesky sweep: the diagonal tile ``a_kk`` (its update already
-    subtracted) factorizes, is inverted once, and each sub-diagonal tile
-    ``band_in(e)`` (e = 1..bt) and arrow tile ``arrow_in(i)`` (updates
-    subtracted) becomes one MXU product X = A L_kk^{-T}, so the only t-step
-    VPU loops a column runs are over a single tile.  ``put_band(e, x)`` /
+    subtracted) factorizes, is inverted once (both blocked by
+    ``potrf.tile_block``), and each sub-diagonal tile ``band_in(e)``
+    (e = 1..bt) and arrow tile ``arrow_in(i)`` (updates subtracted)
+    becomes one MXU product X = A L_kk^{-T}, so the only VPU loops a
+    column runs are over a single tile.  ``put_band(e, x)`` /
     ``put_arrow(i, x)`` store each factor tile as it is formed (e = 0 is
     L_kk), so no value of the whole column stays live; then the corner-Schur
     partial sums and the status word fold the column in.  ``unroll=False``
@@ -131,7 +136,7 @@ def _finish_column(a_kk, band_in, arrow_in, put_band, put_arrow, sacc_ref,
     bands)."""
     t = a_kk.shape[-1]
     lkk = factorize_tile(a_kk)
-    winv = substitute_panel(lkk, eye_tile(t))           # L_kk^{-1}
+    winv = invert_lower_tile(lkk)                       # L_kk^{-1}
     put_band(0, lkk)
     nonfinite = _not_finite(lkk)
 

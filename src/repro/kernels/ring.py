@@ -20,6 +20,7 @@ In-kernel helpers (operate on VMEM scratch refs):
   :func:`ring_accumulate` — the j = 1..depth accumulation loop over ring
   entries that forms each sweep's bounded-history contraction.
   :func:`tile_dot` — the one 2-D float32 MXU contraction every sweep uses.
+  :func:`unrolled_fori` — a ``fori_loop`` taking a few steps a trip.
 
 Compile-time helpers: :func:`vmem_ask` — each sweep's VMEM need, computed
 from its own scratch, block and live-value sizes — and
@@ -45,7 +46,7 @@ import jax.numpy as jnp
 __all__ = ["ring_read", "ring_write", "ring_accumulate",
            "band_row_to_col", "band_col_to_row", "chunk_layout",
            "eye_tile", "identity_prefix_panel", "tile_dot",
-           "vmem_ask", "sweep_compiler_params"]
+           "unrolled_fori", "vmem_ask", "sweep_compiler_params"]
 
 # v5e has 128 MiB of VMEM per core; leave the rest to Mosaic's own scratch.
 VMEM_CAP_BYTES = 100 * 2 ** 20
@@ -67,6 +68,19 @@ def tile_dot(a, b, trans_a: bool = False, trans_b: bool = False):
     return jax.lax.dot_general(a, b, dims,
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
+
+
+def unrolled_fori(n: int, step, init, unroll: int = 4):
+    """``fori_loop(0, n, step, init)`` with ``unroll`` steps a trip
+    (``unroll`` divides ``n``), so the scheduler can overlap a step's
+    independent work with its neighbours': Mosaic lowers a ``fori_loop``
+    unrolled fully or not at all."""
+    def trip(j, carry):
+        for u in range(unroll):
+            carry = step(j * unroll + u, carry)
+        return carry
+
+    return jax.lax.fori_loop(0, n // unroll, trip, init)
 
 
 def vmem_ask(*, scratch: int, blocks: int, temps: int) -> int:

@@ -3,6 +3,13 @@
 Computes ``X = A @ L^{-T}`` for one (t, t) tile against the freshly
 factorized diagonal tile L (lower).  Forward substitution over columns with
 masked vector ops; the whole tile lives in VMEM for the duration.
+
+Also the in-kernel triangular routines the fused sweeps share:
+:func:`substitute_panel` (``L X = B`` for a (t, k) panel, a t-step loop)
+and :func:`invert_lower_tile` (L⁻¹ for the Cholesky sweeps' column
+finish), which inverts by blocks where ``potrf.tile_block`` blocks the tile
+Cholesky: an nb-step substitution over the t/nb diagonal blocks at once,
+then the strictly block-lower part on the MXU.
 """
 from __future__ import annotations
 
@@ -12,8 +19,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .potrf import tile_block
+from .ring import eye_tile, tile_dot, unrolled_fori
+
 __all__ = ["trsm_pallas", "solve_panel_pallas", "substitute_panel",
-           "substitute_right"]
+           "substitute_right", "invert_lower_tile"]
 
 
 def substitute_panel(l: jnp.ndarray, b: jnp.ndarray,
@@ -46,6 +56,49 @@ def substitute_panel(l: jnp.ndarray, b: jnp.ndarray,
         return jnp.where(prows == j, xrow, x)
 
     return jax.lax.fori_loop(0, t, step, jnp.zeros((t, k), jnp.float32))
+
+
+def invert_lower_tile(l: jnp.ndarray) -> jnp.ndarray:
+    """In-kernel L⁻¹ of one (t, t) lower-triangular tile, for the
+    Cholesky sweeps' column finish.  With nb = ``potrf.tile_block(t)`` < t,
+    L = D + N (D its nb-wide diagonal blocks, N the strictly block-lower
+    rest) = D(I + M) with M = D⁻¹N, so L⁻¹ = (I + M)⁻¹D⁻¹.  D⁻¹ takes one
+    nb-step substitution, every block at once: step s finishes row s of
+    each block and takes it off the block's later rows.  M is strictly
+    block-lower, so M^(t/nb) = 0 and (I + M)⁻¹ = (I − M)(I + M²)(I + M⁴)…
+    exactly, a few MXU products (four at t/nb = 4).  Where t < 2·nb this
+    is :func:`substitute_panel` against the identity, as before.  Operates
+    in and returns float32."""
+    t = l.shape[-1]
+    nb = tile_block(t)
+    eye = eye_tile(t)
+    if nb == t:
+        return substitute_panel(l, eye)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    same = rows // nb == cols // nb
+    rdiag = 1.0 / jnp.sum(jnp.where(rows == cols, l, 0.0), axis=1,
+                          keepdims=True)
+    # D = diag(d)(I + E), E strictly lower in each block: D⁻¹ = (I + E)⁻¹
+    # diag(1/d), so the substitution runs from diag(1/d) and divides by
+    # nothing
+    e = jnp.where(same & (rows > cols), l * rdiag, 0.0)
+    sub = rows % nb
+    lane = cols[:1] % nb
+
+    def step(s, x):
+        # the blocks' finished rows s, side by side (their columns differ)
+        xs = jnp.sum(jnp.where(sub == s, x, 0.0), axis=0, keepdims=True)
+        ecol = jnp.sum(jnp.where(lane == s, e, 0.0), axis=1, keepdims=True)
+        return x - jnp.where(same, ecol * xs, 0.0)
+
+    dinv = unrolled_fori(nb, step, eye * rdiag)
+    m = tile_dot(dinv, jnp.where(same, 0.0, l))
+    inv, power = eye - m, m
+    for _ in range((t // nb - 1).bit_length() - 1):
+        power = tile_dot(power, power)
+        inv = inv + tile_dot(inv, power)
+    return tile_dot(inv, dinv)
 
 
 def substitute_right(l: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:

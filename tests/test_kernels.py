@@ -504,3 +504,27 @@ def test_band_cholesky_sweep_accuracy_poorly_conditioned(bt, nat, sweep):
     err = _factor_error(got[0], got[1], L, ndt, t)
     err_ref = _factor_error(want[0], want[1], L, ndt, t)
     assert np.isfinite(err) and err <= 2.0 * err_ref, (err, err_ref)
+
+
+@pytest.mark.parametrize("sweep", ["plain", "stream"])
+def test_band_cholesky_sweep_accuracy_poorly_conditioned_t128(sweep):
+    """The check above at t = 128, where each column's diagonal tile is
+    factored and inverted by 32-row blocks (``potrf.tile_block``): on a
+    band with cond(Q) about 1e4 the ring and streamed sweeps err at most
+    twice as much as the ``ref`` backend against a float64 Cholesky."""
+    from repro.kernels.band_cholesky import band_cholesky_stream_sweep_pallas
+    from repro.kernels.potrf import tile_block
+    t = 128
+    assert tile_block(t) < t
+    bm, grid = _near_singular_ctsf(5 * t + 64, 2 * t - 8, 64, t)
+    ndt = grid.n_diag_tiles
+    assert (ndt, grid.band_tiles, grid.n_arrow_tiles) == (5, 2, 1)
+    L = np.linalg.cholesky(bm.to_dense(lower_only=False).astype(np.float64))
+    Ac = band_row_to_col(bm.Dr)
+    sweep_fn = {"plain": band_cholesky_sweep_pallas,
+                "stream": band_cholesky_stream_sweep_pallas}[sweep]
+    got = sweep_fn(Ac, bm.R, interpret=True)
+    want = ref.band_cholesky_sweep_ref(Ac, bm.R)
+    err = _factor_error(got[0], got[1], L, ndt, t)
+    err_ref = _factor_error(want[0], want[1], L, ndt, t)
+    assert np.isfinite(err) and err <= 2.0 * err_ref, (err, err_ref)

@@ -158,6 +158,7 @@ def test_auto_streams_a_band_over_the_cap(monkeypatch, fresh_caches):
     span, = [s for s in snap["spans"]
              if s["name"] == "factorize.window_batched"]
     assert span["tags"]["sweep"] == "stream"
+    assert span["tags"]["tile_block"] == grid.t      # t = 8: unblocked
     assert span["tags"]["stream_bytes"] == stream_bytes(
         grid.n_diag_tiles, grid.band_tiles, grid.n_arrow_tiles, grid.t)
     for a, b in zip((fused[0].Dr, fused[0].R, fused[0].C, fused[1],

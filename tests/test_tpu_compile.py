@@ -7,7 +7,9 @@ which interpret-mode tests cannot see.  Shapes are the real tile and band
 widths (t=128, band_tiles 8 and 16 with two arrow tiles, and Table II
 ID 19's 118 band tiles with one arrow tile, where the streamed Cholesky
 sweep takes over) at a small number of diagonal tiles; the grid length
-does not change what Mosaic checks.
+does not change what Mosaic checks.  At t=128 every Cholesky sweep
+finishes its columns with the blocked diagonal-tile routines
+(``potrf.tile_block``).
 """
 import functools
 
@@ -21,6 +23,7 @@ from repro.kernels.band_cholesky import (band_cholesky_partitioned_sweep_pallas,
                                          band_cholesky_sweep_pallas)
 from repro.kernels.band_solve import (band_backward_sweep_pallas,
                                       band_forward_sweep_pallas)
+from repro.kernels.potrf import potrf_pallas, tile_block
 from repro.kernels.selinv import selinv_sweep_pallas
 
 T, NDT, NAT, K = 128, 12, 2, 8
@@ -60,6 +63,13 @@ def test_band_cholesky_sweep_compiles(shape, bt):
         a, r, nchunks=4, start_tile=s, interpret=False),
         shape(NDT, bt + 1, T, T), shape(NDT, NAT, T, T),
         shape(dtype=jnp.int32))
+
+
+def test_blocked_potrf_tile_compiles(shape):
+    """The single-tile POTRF kernel runs the blocked tile Cholesky at
+    t=128, the one the sweeps' column finish runs."""
+    assert tile_block(T) < T
+    _compile(lambda a: potrf_pallas(a, interpret=False), shape(NDT, T, T))
 
 
 @pytest.mark.parametrize("bt", WIDTHS)
