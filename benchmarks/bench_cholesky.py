@@ -11,7 +11,7 @@ Two comparisons on the same banded-arrowhead problem:
 
 Gating is on **counted kernel launches**, not wall time: the fused sweeps
 must trace to exactly one ``pallas_call`` each (counted by jaxpr
-traversal), versus the 3·ndt (potrf + trsm + band_update) / 2·ndt
+traversal), versus the 3·ndt (potrf + trsm + band-update) / 2·ndt
 (solve_panel + selinv_step) per-panel launches the pre-fusion paths
 dispatched.  Launch counts are backend-independent, so this gate holds on
 CPU CI; wall-clock timings on non-TPU hosts run the kernels in interpret
@@ -78,7 +78,7 @@ def run(quick: bool = True):
         sweep="selinv")
     fused_selinv_launches = selinv_report.pallas_launches
     # the pre-fusion per-panel dispatch counts (one potrf + trsm +
-    # band_update launch per band panel; one solve_panel + selinv_step per
+    # band-update launch per band panel; one solve_panel + selinv_step per
     # selinv column)
     scan_fact_launches = 3 * ndt
     scan_selinv_launches = 2 * ndt

@@ -19,9 +19,8 @@ The surface is one matrix type (:class:`BandedCTSF` on a
 * serving: :class:`RungServer` (+ :class:`SimClock` for deterministic
   replay)
 
-Per-call ``impl=`` / ``policy=`` / ``regularize=`` / ``sweep=`` /
-``method=`` kwargs on the entry points are deprecated shims; pass
-``options=SolverOptions(...)``.
+Solver knobs travel only in ``options=SolverOptions(...)``; an entry
+point given anything else there raises ``TypeError``.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@ from .structure import (ArrowheadStructure, TileGrid, measure_arrowhead,
                         tile_pattern_from_coo, banded_arrowhead_tile_pattern)
 from .symbolic import SymbolicFactorization, Task, TaskType, symbolic_factorize
 from .ctsf import BandedCTSF, TileMatrix
-from .options import SolverOptions, resolve_options
+from .options import SolverOptions
 from .ordering import (OrderingResult, PartitionPlan, adaptive_nd_ordering,
                        detect_partition_plan, partition_plan_from_ordering)
 from .cholesky import (CholeskyFactor, factorize_tasklist, factorize_window,
@@ -27,7 +27,7 @@ __all__ = [
     "tile_pattern_from_coo", "banded_arrowhead_tile_pattern",
     "SymbolicFactorization", "Task", "TaskType", "symbolic_factorize",
     "BandedCTSF", "TileMatrix",
-    "SolverOptions", "resolve_options",
+    "SolverOptions",
     "OrderingResult", "PartitionPlan", "adaptive_nd_ordering",
     "detect_partition_plan", "partition_plan_from_ordering",
     "CholeskyFactor", "factorize_tasklist", "factorize_window",

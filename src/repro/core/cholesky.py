@@ -14,9 +14,10 @@ Two numerical backends over the CTSF layouts:
   factorization is one sweep-level primitive
   (``kernels.ops.band_cholesky_sweep``): on the Pallas backend a *single
   fused kernel launch* walks the band with the panel ring resident in
-  VMEM (``sweep="fused"``); on the jnp backend a ring-buffer ``lax.scan``
-  dispatches per-panel tile ops.  Corner Schur partial sums ride the
-  sweep as tree-reduction chunks.
+  VMEM, or streams a band too wide for that ring from HBM; on the jnp
+  backend a ring-buffer ``lax.scan`` dispatches per-panel tile ops.  The
+  sweep is chosen from the input alone (:func:`_resolve_sweep`).  Corner
+  Schur partial sums ride the sweep as tree-reduction chunks.
 
 Both produce bit-comparable factors (tests assert allclose against
 `jnp.linalg.cholesky` of the dense matrix).
@@ -31,10 +32,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops, ring
+from repro.kernels import ops
 from repro.kernels.band_cholesky import stream_bytes, sweep_path
 from repro.kernels.potrf import tile_block
-from repro.kernels.ref import sweep_status
 from repro.kernels.ring import band_col_to_row, band_row_to_col
 from repro.runtime import telemetry
 from .batching import LRUCache, bucketed_batched_call
@@ -43,7 +43,7 @@ from .robustness import (FactorInfo, RegularizePolicy, fold_corner_status,
                          run_ladder)
 from .structure import TileGrid
 from .symbolic import Task, TaskType
-from .options import SolverOptions, UNSET, resolve_options
+from .options import SolverOptions, check_options
 from .tree_reduction import chunked_tree_sum, should_use_tree, tree_combine
 
 __all__ = ["factorize_tasklist", "factorize_window",
@@ -168,10 +168,10 @@ class CholeskyFactor:
     in and restrict results back automatically.  :meth:`restrict` strips
     the embedding when the raw factor is wanted.
 
-    ``info`` is attached when the factorization ran under a
-    ``regularize=`` policy: per-element numerical status (OK / RECOVERED
-    with diagonal jitter / FAILED), attempts, applied jitter and minimum
-    pivot — see :class:`~repro.core.robustness.FactorInfo`.  Serving
+    ``info`` is attached when the factorization ran under an
+    ``options.regularize`` policy: per-element numerical status (OK /
+    RECOVERED with diagonal jitter / FAILED), attempts, applied jitter and
+    minimum pivot — see :class:`~repro.core.robustness.FactorInfo`.  Serving
     callers should consult ``info`` instead of expecting exceptions; a
     FAILED element's factor is numerically unusable but never poisons its
     batch siblings.
@@ -257,102 +257,21 @@ def _band_arrow_sweep_ring(Dr, R, grid, impl, tree_chunks: int = 1):
     return band_col_to_row(panels), R_out, schur
 
 
-def _band_arrow_sweep(Dr, R, grid, impl, start_tile=0):
-    """The sequential panel sweep (thin critical path): factor the band and
-    arrow rows, leaving the corner untouched.  Returns (Dr_L, R_L).
-
-    ``start_tile`` skips the first rows of the sweep, leaving their input
-    values in place — correct exactly when they are the identity-embedding
-    prefix of a canonical grid (whose factor equals the input)."""
-    t, ndt, nat, bt = grid.t, grid.n_diag_tiles, grid.n_arrow_tiles, grid.band_tiles
-    b1 = bt + 1
-
-    # pad: bt trailing zero rows on Dr (window slack), bt leading on R
-    Drp = jnp.pad(Dr, ((0, bt), (0, 0), (0, 0), (0, 0)))
-    Rp = jnp.pad(R, ((bt, 0), (0, 0), (0, 0), (0, 0))) if nat else R
-
-    erange = jnp.arange(b1)
-
-    def panel_step(k, carry):
-        Drp, Rp = carry
-        w = jax.lax.dynamic_slice(Drp, (k, 0, 0, 0), (b1, b1, t, t))
-        u = ops.band_update(w, impl=impl)                       # (b1, t, t)
-        lkk = ops.potrf(w[0, 0] - u[0], impl=impl)
-        # sub-diagonal panel tiles A[k+e, k] live on the window diagonal
-        amk = w[erange[1:], erange[1:]] - u[1:]
-        lmk = ops.trsm(lkk, amk, impl=impl)
-        vals = jnp.concatenate([lkk[None], lmk], axis=0)
-        Drp = Drp.at[k + erange, erange].set(vals)
-        if nat:
-            rwin = jax.lax.dynamic_slice(Rp, (k, 0, 0, 0), (bt, nat, t, t)) \
-                if bt else jnp.zeros((0, nat, t, t), Rp.dtype)
-            # V[i] = sum_{j=1..bt} R[k-j, i] @ L[k, k-j]^T ; rwin[bt-j] = R[k-j]
-            w0rev = jnp.flip(w[0, 1:], axis=0) if bt else jnp.zeros((0, t, t), w.dtype)
-            v = jnp.einsum("jiab,jcb->iac", rwin, w0rev, precision=_HI) \
-                if bt else 0.0
-            lak = ops.trsm(lkk, Rp[k + bt] - v, impl=impl)
-            Rp = jax.lax.dynamic_update_slice(Rp, lak[None], (k + bt, 0, 0, 0))
-        return (Drp, Rp)
-
-    Drp, Rp = jax.lax.fori_loop(start_tile, ndt, panel_step, (Drp, Rp))
-    Dr_out = Drp[:ndt]
-    R_out = Rp[bt:] if nat else R
-    return Dr_out, R_out
-
-
-def _corner_schur(R_L: jnp.ndarray, tree_chunks: int) -> jnp.ndarray:
-    """sum_n R[n] R[n]^T over all band columns — the paper's flagship
-    accumulation chain, computed via Alg. 3's chunked tree."""
-    ndt = R_L.shape[0]
-    terms = jnp.einsum("niab,njcb->nijac", R_L, R_L, precision=_HI)
-    chunks = tree_chunks if tree_chunks else 1
-    if should_use_tree(ndt, chunks):
-        return chunked_tree_sum(terms, chunks)
-    return terms.sum(axis=0)
-
-
-def _resolve_sweep(grid, impl, sweep, plan=None) -> str:
-    """The sweep :func:`_factorize_window_impl` runs for ``sweep``: one of
-    ``"fused"``, ``"stream"``, ``"ring"``, ``"window"`` or
-    ``"partitioned"`` (``"stream"`` only by the ``"auto"`` choice).
-    Refuses contradictory or impossible requests."""
-    if sweep not in ("auto", "fused", "ring", "window", "partitioned"):
-        raise ValueError(f"unknown sweep {sweep!r} (want 'auto', 'fused', "
-                         "'ring', 'window' or 'partitioned')")
-    # "ring" is the jnp scan and "fused" the Pallas kernel by definition —
-    # an explicit impl pointing the other way would silently run a
-    # different backend than asked, so refuse the contradiction.
-    if (sweep == "ring" and impl == "pallas") or \
-            (sweep == "fused" and impl in ("ref", "unrolled")):
-        raise ValueError(
-            f"sweep={sweep!r} contradicts impl={impl!r}: the ring sweep is "
-            "the jnp reference scan and the fused sweep is the Pallas "
-            "kernel; use sweep='auto' to dispatch by impl")
-    if sweep == "partitioned" and plan is None:
-        raise ValueError(
-            "sweep='partitioned' needs a partition plan: pass "
-            "options=SolverOptions(partition_plan=...) (see "
-            "core.ordering.detect_partition_plan)")
+def _resolve_sweep(grid, impl, plan=None) -> str:
+    """The sweep :func:`_factorize_window_impl` runs, chosen from the input
+    alone: ``"partitioned"`` for a plan of more than one partition,
+    ``"ring"`` off the Pallas backend, else ``"fused"`` while the VMEM
+    ring fits and ``"stream"`` beyond (``band_cholesky.sweep_path``)."""
     if plan is not None and plan.n_tiles != grid.n_diag_tiles:
         raise ValueError(
             f"partition plan covers {plan.n_tiles} diagonal tiles but the "
             f"grid has {grid.n_diag_tiles}; rebuild the plan for this grid "
             "(PartitionPlan.shifted embeds a plan into a canonical grid)")
-    path = sweep_path(grid.t, grid.band_tiles, grid.n_arrow_tiles)
-    if sweep == "fused" and path != "fused":
-        raise ValueError(
-            f"sweep='fused' cannot run at t={grid.t}, band_tiles="
-            f"{grid.band_tiles}, arrow tiles {grid.n_arrow_tiles}: its VMEM "
-            f"ring asks for more than the {ring.VMEM_CAP_BYTES / 2**20:g} "
-            "MiB cap (kernels/ring.py); sweep='auto' streams such a band "
-            "from HBM")
-    if sweep != "auto":
-        return sweep
     if plan is not None and plan.n_partitions > 1:
         return "partitioned"
     if (impl or ops.default_impl()) != "pallas":
         return "ring"
-    return path
+    return sweep_path(grid.t, grid.band_tiles, grid.n_arrow_tiles)
 
 
 def _record_sweep(span, grid, opts: SolverOptions, plan):
@@ -364,7 +283,7 @@ def _record_sweep(span, grid, opts: SolverOptions, plan):
     DMAs move for one matrix."""
     if not telemetry.enabled():
         return
-    path = _resolve_sweep(grid, opts.impl, opts.sweep, plan)
+    path = _resolve_sweep(grid, opts.impl, plan)
     telemetry.inc("cholesky.sweep", path=path)
     span.tag(sweep=path)
     if path in ("fused", "stream", "partitioned"):
@@ -376,40 +295,26 @@ def _record_sweep(span, grid, opts: SolverOptions, plan):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("grid", "impl", "tree_chunks", "sweep",
-                                    "plan"))
-def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
-                           start_tile=0, plan=None):
-    """Window factorization with sweep-mode dispatch
-    (:func:`_resolve_sweep`):
+                   static_argnames=("grid", "impl", "tree_chunks", "plan"))
+def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, start_tile=0,
+                           plan=None):
+    """Window factorization on the sweep :func:`_resolve_sweep` picks:
 
-    * ``"auto"`` (default) — ``"partitioned"`` when ``plan`` (a
-      :class:`~repro.core.ordering.PartitionPlan`) has more than one
-      partition; else on the Pallas backend (native TPU or an explicit
-      ``impl="pallas"``) the fused sweep while its VMEM ring fits the
-      chip and the streamed sweep beyond (a choice made from the grid's
-      tile size, band and arrow tiles alone,
-      ``kernels.band_cholesky.sweep_path``); else ``"ring"``: every caller
-      (:func:`factorize_window`, :func:`factorize_window_batched`,
-      ``concurrent_factorize``) rides a fused kernel wherever Pallas is the
-      kernel backend.
-    * ``"fused"`` — force the single-launch Pallas sweep with the VMEM ring
-      (``kernels/band_cholesky.py``); a ``ValueError`` where its ring
-      cannot fit.
-    * ``"ring"`` — force the ring-buffer ``lax.scan`` reference.
-    * ``"window"`` — the legacy dynamic-slice window sweep
-      (``kernels.band_update`` per panel), kept for comparison.
+    * ``"fused"`` — the single-launch Pallas sweep with the VMEM ring
+      (``kernels/band_cholesky.py``);
+    * ``"stream"`` — the Pallas sweep that keeps the factor in HBM, for a
+      band whose ring cannot fit in VMEM;
+    * ``"ring"`` — the ring-buffer ``lax.scan`` reference off Pallas;
     * ``"partitioned"`` — the multi-partition fused sweep
       (``kernels.ops.band_cholesky_partitioned_sweep``): one 2D-grid
       launch over all of ``plan``'s independent band partitions, their
       per-partition corner-Schur leaves tree-combined before the shared
-      corner factorization.  Requires a ``plan``; a trivial
-      single-partition plan stays on the fused/ring path so its factor is
-      bit-identical to a plan-less call.
+      corner factorization.  A single-partition plan stays on the band
+      sweep, so its factor is bit-identical to a plan-less call.
 
-    The fused, streamed and ring paths read the corner Schur complement
-    from the sweep's per-chunk partial sums (accumulated on the fly in the
-    kernels) instead of re-contracting R_out from HBM.
+    Every path reads the corner Schur complement from the sweep's
+    per-chunk partial sums (accumulated on the fly in the kernels) instead
+    of re-contracting R_out from HBM.
 
     ``start_tile`` declares the first band columns an identity-embedding
     prefix (``core/gridpolicy.py``); callers omit it on the plain path so
@@ -423,7 +328,7 @@ def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
     carried in-graph with no host sync; the jitter ladder
     (``core/robustness.py``) is the consumer."""
     nat = grid.n_arrow_tiles
-    mode = _resolve_sweep(grid, impl, sweep, plan)
+    mode = _resolve_sweep(grid, impl, plan)
     if mode == "partitioned":
         panels, R_out, schur, status = ops.band_cholesky_partitioned_sweep(
             band_row_to_col(Dr), R, plan.boundaries, start_tile=start_tile,
@@ -437,20 +342,6 @@ def _factorize_window_impl(Dr, R, C, grid, impl, tree_chunks, sweep="auto",
             C_out = C
         return Dr_out, R_out, C_out, fold_corner_status(
             status, C_out, grid.n_diag_tiles, nat)
-    if mode == "window":
-        Dr_out, R_out = _band_arrow_sweep(Dr, R, grid, impl, start_tile)
-        # legacy sweep predates the in-sweep status carry: fold the same
-        # word from the emitted factor (row layout keeps diag at [:, 0],
-        # which is all ref.sweep_status reads)
-        status = sweep_status(Dr_out, R_out)
-        if nat:
-            C_out = _corner_dense_cholesky(
-                C - _corner_schur(R_out, tree_chunks))
-        else:
-            C_out = C
-        return Dr_out, R_out, C_out, fold_corner_status(
-            status, C_out, grid.n_diag_tiles, nat)
-
     # the kernel layer runs the streamed sweep where the ring cannot fit
     sweep_impl = "pallas" if mode in ("fused", "stream") else "ref"
     nchunks = max(1, min(tree_chunks or 1, grid.n_diag_tiles or 1))
@@ -480,26 +371,22 @@ def _embed_matrix(m: BandedCTSF, policy):
     return embed_ctsf(m, cgrid), m.grid, start
 
 
-def factorize_window(m: BandedCTSF, impl=UNSET,
-                     tree_chunks: int = 8,
-                     sweep=UNSET, policy=UNSET,
-                     regularize=UNSET,
-                     options: Optional[SolverOptions] = None) -> CholeskyFactor:
+def factorize_window(m: BandedCTSF, tree_chunks: int = 8,
+                     options: Optional[SolverOptions] = None
+                     ) -> CholeskyFactor:
     """Banded-arrowhead factorization (window backend).
 
     ``options`` (a :class:`~repro.core.options.SolverOptions`) carries the
-    solver knobs — backend, sweep mode, bucketing policy, regularization
-    and the partition plan; the bare ``impl=``/``sweep=``/``policy=``/
-    ``regularize=`` kwargs are deprecated aliases for the matching fields
-    (legacy wins when both are given, with a ``DeprecationWarning``).
+    solver knobs — backend, bucketing policy, regularization and the
+    partition plan.
 
     With ``options.impl="pallas"`` (or running natively on TPU) the whole
     band + arrow block factorizes in **one fused Pallas launch**
-    (``kernels.ops.band_cholesky_sweep``); ``options.sweep`` overrides the
-    dispatch (see :func:`_factorize_window_impl`).  An
-    ``options.partition_plan`` with more than one partition upgrades the
-    launch to the 2D partition-parallel sweep — critical path
-    O(max partition tiles) instead of O(ndt).
+    (``kernels.ops.band_cholesky_sweep``; see
+    :func:`_factorize_window_impl`).  An ``options.partition_plan`` with
+    more than one partition upgrades the launch to the 2D
+    partition-parallel sweep — critical path O(max partition tiles)
+    instead of O(ndt).
 
     With a :class:`~repro.core.gridpolicy.GridBucketPolicy` the matrix is
     first embedded into its canonical grid (identity-diagonal padding) and
@@ -510,14 +397,13 @@ def factorize_window(m: BandedCTSF, impl=UNSET,
     transparently, or :meth:`CholeskyFactor.restrict` strips the
     embedding.
 
-    ``regularize`` opts into numerical fault tolerance: ``True`` (default
-    :class:`~repro.core.robustness.RegularizePolicy`) or a policy runs the
-    escalating-jitter retry ladder on breakdown and attaches a
+    ``options.regularize`` opts into numerical fault tolerance: ``True``
+    (default :class:`~repro.core.robustness.RegularizePolicy`) or a policy
+    runs the escalating-jitter retry ladder on breakdown and attaches a
     :class:`~repro.core.robustness.FactorInfo` to the returned factor
     instead of ever raising; an SPD input factorizes on the first attempt
     and its factor is bit-identical to the unregularized call."""
-    opts = resolve_options(options, _where="factorize_window", impl=impl,
-                           sweep=sweep, policy=policy, regularize=regularize)
+    opts = check_options(options)
     with telemetry.span("factorize.window",
                         grid=telemetry.rung_tag(m.grid)) as sp:
         pol = RegularizePolicy.resolve(opts.regularize)
@@ -533,12 +419,10 @@ def factorize_window(m: BandedCTSF, impl=UNSET,
                 # is one compilation — same as the plan-less policy path
                 plan = plan.shifted(m.grid.n_diag_tiles - src_ndt)
             call = lambda dr, r, c: _factorize_window_impl(
-                dr, r, c, m.grid, opts.impl, tree_chunks, opts.sweep, start,
-                plan=plan)
+                dr, r, c, m.grid, opts.impl, tree_chunks, start, plan=plan)
         else:
             call = lambda dr, r, c: _factorize_window_impl(
-                dr, r, c, m.grid, opts.impl, tree_chunks, opts.sweep,
-                plan=plan)
+                dr, r, c, m.grid, opts.impl, tree_chunks, plan=plan)
         _record_sweep(sp, m.grid, opts, plan)
         if pol is None:
             Dr, R, C, _status = call(m.Dr, m.R, m.C)
@@ -566,22 +450,21 @@ def _batched_window_fn(grid, opts: SolverOptions, tree_chunks,
     repeated θ-sweeps reuse the same traced function object (and
     therefore XLA's compile cache).  Keying on the options object's
     compile-relevant subset means option-equal calls share an entry no
-    matter which construction path (legacy kwargs, facade, replace())
-    produced them.
+    matter which construction path (facade, replace()) produced them.
 
     ``use_start=True`` (the canonical-grid path) adds a *traced*
     ``start_tile`` argument broadcast across the batch, so every source
     grid embedding into ``grid`` — whatever its pad depth — shares this
     one cache entry; the plain path keeps its static-zero trace."""
     key = (grid, opts.compile_key(), tree_chunks, use_start)
-    impl, sweep, plan = opts.impl, opts.sweep, opts.partition_plan
+    impl, plan = opts.impl, opts.partition_plan
 
     def batched_window(dr, r, c, *start):
         # the entry point's name rides the op metadata of everything
         # traced here, the XLA work around the kernel included
         with jax.named_scope("factorize.window_batched"):
             return _factorize_window_impl(dr, r, c, grid, impl, tree_chunks,
-                                          sweep, *start, plan=plan)
+                                          *start, plan=plan)
 
     def build():
         if use_start:
@@ -591,22 +474,16 @@ def _batched_window_fn(grid, opts: SolverOptions, tree_chunks,
     return _BATCHED_WINDOW_CACHE.get_or_create(key, build)
 
 
-def factorize_window_batched(batch, impl=UNSET,
-                             tree_chunks: int = 8,
+def factorize_window_batched(batch, tree_chunks: int = 8,
                              bucket: bool = True,
-                             sweep=UNSET,
-                             policy=UNSET,
-                             regularize=UNSET,
                              start_tile=None,
                              options: Optional[SolverOptions] = None
                              ) -> CholeskyFactor:
     """Factorize a batch of same-grid matrices in one vmapped dispatch.
 
-    ``options`` (a :class:`~repro.core.options.SolverOptions`) is the
-    preferred way to pass the solver knobs; the bare ``impl=``/``sweep=``/
-    ``policy=``/``regularize=`` kwargs are deprecated aliases (legacy
-    wins, with a ``DeprecationWarning``).  ``tree_chunks``, ``bucket`` and
-    ``start_tile`` are per-call arguments, not options.
+    ``options`` (a :class:`~repro.core.options.SolverOptions`) carries the
+    solver knobs; ``tree_chunks``, ``bucket`` and ``start_tile`` are
+    per-call arguments, not options.
 
     ``batch`` is either a list of :class:`BandedCTSF` or one whose arrays
     carry a leading batch axis (cf. ``concurrent.stack_ctsf``).  This is the
@@ -614,25 +491,26 @@ def factorize_window_batched(batch, impl=UNSET,
     matrix rides the same ring sweep + corner Schur, so a sweep of B
     candidates costs one kernel launch sequence instead of B — and on the
     Pallas backend the whole band+arrow factorization of every candidate
-    is one fused launch (``sweep`` as in :func:`factorize_window`).
+    is one fused launch (as in :func:`factorize_window`).
 
     With ``bucket=True`` the batch is padded (by repeating the last matrix)
     to the next power of two before dispatch and the padding results are
     dropped — bounding XLA compiles per grid at log2(max batch) instead of
     one per distinct sweep size.  The vmapped callable itself is cached per
-    (grid, impl, tree_chunks, sweep), so factorizing a new batch of a known
-    shape costs zero retracing.
+    (grid, options compile key, tree_chunks), so factorizing a new batch of
+    a known shape costs zero retracing.
 
-    ``policy`` (a :class:`~repro.core.gridpolicy.GridBucketPolicy`) extends
-    the bucketing across *grids*: the batch is embedded into its canonical
-    grid, the cache keys on that canonical grid, and the sweep skips the
-    identity prefix via a traced ``start_tile`` — so mixed-size serving
-    traffic compiles O(#canonical rungs) sweeps instead of one per distinct
-    grid.  The returned factor carries ``source_grid`` (see
+    ``options.policy`` (a :class:`~repro.core.gridpolicy.GridBucketPolicy`)
+    extends the bucketing across *grids*: the batch is embedded into its
+    canonical grid, the cache keys on that canonical grid, and the sweep
+    skips the identity prefix via a traced ``start_tile`` — so mixed-size
+    serving traffic compiles O(#canonical rungs) sweeps instead of one per
+    distinct grid.  The returned factor carries ``source_grid`` (see
     :func:`factorize_window`).
 
-    ``regularize`` (bool or :class:`~repro.core.robustness.RegularizePolicy`)
-    runs the escalating-jitter ladder *per batch element*: retries
+    ``options.regularize`` (bool or
+    :class:`~repro.core.robustness.RegularizePolicy`) runs the
+    escalating-jitter ladder *per batch element*: retries
     refactorize the whole (bucketed) batch through the same compiled
     callable with only the failed elements' diagonals jittered, healthy
     elements keep their first-attempt factors bit-for-bit, and the
@@ -649,9 +527,7 @@ def factorize_window_batched(batch, impl=UNSET,
     computes its own start); the returned factor keeps ``source_grid``
     None — restriction stays with the caller who owns the embedding.
     """
-    opts = resolve_options(options, _where="factorize_window_batched",
-                           impl=impl, sweep=sweep, policy=policy,
-                           regularize=regularize)
+    opts = check_options(options)
     if start_tile is not None and opts.policy is not None:
         raise ValueError(
             "start_tile= is for pre-embedded batches and the bucketing "

@@ -20,7 +20,7 @@ from repro.runtime import telemetry
 from .cholesky import (CholeskyFactor, _factorize_window_impl,
                        factorize_window_batched)
 from .ctsf import BandedCTSF
-from .options import UNSET, resolve_options
+from .options import SolverOptions, check_options
 from .selinv import SelectedInverse, _selinv_impl, selinv_batched
 from .structure import TileGrid
 
@@ -39,9 +39,10 @@ def stack_ctsf(mats: list, policy=None) -> BandedCTSF:
     canonical rung (``policy.join``) with identity-diagonal padding, so a
     mixed-size batch can ride one vmapped factorization.  Note the stacked
     result is a plain canonical-grid matrix batch: factorize it with
-    ``factorize_window_batched(..., policy=policy)`` (a no-op embedding,
-    since every grid is already canonical) to get a factor whose solves
-    restrict back to the canonical — not the per-matrix source — layout.
+    ``factorize_window_batched(..., options=SolverOptions(policy=policy))``
+    (a no-op embedding, since every grid is already canonical) to get a
+    factor whose solves restrict back to the canonical — not the
+    per-matrix source — layout.
     """
     if not mats:
         raise ValueError("stack_ctsf needs at least one matrix")
@@ -79,10 +80,9 @@ def _per_device(fn, mesh: Mesh, axis: str, n_out: int):
 
 
 def concurrent_factorize(batch: BandedCTSF, mesh: Optional[Mesh] = None,
-                         axis: str = "data", impl=UNSET,
-                         tree_chunks: int = 8,
-                         policy=UNSET, regularize=UNSET,
-                         options=None) -> CholeskyFactor:
+                         axis: str = "data", tree_chunks: int = 8,
+                         options: Optional[SolverOptions] = None
+                         ) -> CholeskyFactor:
     """Factorize a batch of matrices concurrently.
 
     With ``mesh``, the batch axis is sharded over ``axis`` — one factorization
@@ -90,28 +90,28 @@ def concurrent_factorize(batch: BandedCTSF, mesh: Optional[Mesh] = None,
     to the cached batched serving path (``factorize_window_batched``) so
     repeated same-structure sweeps never retrace.
 
-    With a ``policy`` the batch is embedded onto its canonical grid first
-    (``core/gridpolicy.py``) — the sharded sweep then runs on the
-    canonical grid with its identity prefix skipped, and the returned
+    With an ``options.policy`` the batch is embedded onto its canonical
+    grid first (``core/gridpolicy.py``) — the sharded sweep then runs on
+    the canonical grid with its identity prefix skipped, and the returned
     factor carries ``source_grid`` for the policy-aware solve/selinv
     entry points.
 
-    ``regularize`` (bool or :class:`~repro.core.robustness.RegularizePolicy`)
-    enables per-element breakdown recovery: the escalating-jitter ladder
+    ``options.regularize`` (bool or
+    :class:`~repro.core.robustness.RegularizePolicy`) enables per-element
+    breakdown recovery: the escalating-jitter ladder
     retries only the failed elements (on the mesh path the retries ride
     the same sharded callable; only the (B, 3) status words are read back
     to the host) and the returned
     ``factor.info`` flags each element OK / RECOVERED / FAILED instead of
     one bad θ-candidate raising mid-sweep.
     """
-    opts = resolve_options(options, _where="concurrent_factorize",
-                           impl=impl, policy=policy, regularize=regularize)
+    opts = check_options(options)
     if mesh is None:
         return factorize_window_batched(batch, tree_chunks=tree_chunks,
                                         bucket=False, options=opts)
     from .robustness import RegularizePolicy, run_ladder
     pol = RegularizePolicy.resolve(opts.regularize)
-    impl, sweep, plan = opts.impl, opts.sweep, opts.partition_plan
+    impl, plan = opts.impl, opts.partition_plan
     source = None
     if opts.policy is not None:
         from .cholesky import _embed_matrix
@@ -121,11 +121,11 @@ def concurrent_factorize(batch: BandedCTSF, mesh: Optional[Mesh] = None,
             plan = plan.shifted(batch.grid.n_diag_tiles - src_ndt)
         fn = jax.vmap(
             lambda dr, r, c: _factorize_window_impl(
-                dr, r, c, batch.grid, impl, tree_chunks, sweep, start, plan))
+                dr, r, c, batch.grid, impl, tree_chunks, start, plan))
     else:
         fn = jax.vmap(
             lambda dr, r, c: _factorize_window_impl(
-                dr, r, c, batch.grid, impl, tree_chunks, sweep, 0, plan))
+                dr, r, c, batch.grid, impl, tree_chunks, 0, plan))
     fn = _per_device(fn, mesh, axis, n_out=4)
     if pol is None:
         dr, r, c, _status = fn(batch.Dr, batch.R, batch.C)
@@ -138,7 +138,7 @@ def concurrent_factorize(batch: BandedCTSF, mesh: Optional[Mesh] = None,
 
 
 def concurrent_solve(factor: CholeskyFactor, B: jnp.ndarray,
-                     impl=UNSET, policy=UNSET, options=None) -> jnp.ndarray:
+                     options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """Solve ``A_i X_i = B`` for every factor in the batch, one vmapped
     multi-RHS sweep.
 
@@ -148,9 +148,10 @@ def concurrent_solve(factor: CholeskyFactor, B: jnp.ndarray,
       B: RHS shared across the batch, shape ``(padded_n,)`` or
         ``(padded_n, k)`` in the padded layout (zero rows in the padding
         region).
-      impl: kernel backend for the sweeps; ``"pallas"`` vmaps the *fused*
-        band-sweep kernels (``kernels.ops.band_forward_sweep`` /
-        ``band_backward_sweep``) — the batch rides the kernel grid for free.
+      options: ``options.impl`` is the sweeps' kernel backend; ``"pallas"``
+        vmaps the *fused* band-sweep kernels
+        (``kernels.ops.band_forward_sweep`` / ``band_backward_sweep``) —
+        the batch rides the kernel grid for free.
 
     Returns: ``(batch, padded_n)`` or ``(batch, padded_n, k)``.
 
@@ -159,15 +160,14 @@ def concurrent_solve(factor: CholeskyFactor, B: jnp.ndarray,
     RHS without ever leaving the device.  Recompiles once per
     ``(grid, impl, k, batch)``.
 
-    Embedded factors (``factor.source_grid`` set, or ``policy`` given)
-    take ``B`` and return ``X`` in the *source* layout; the canonical
+    Embedded factors (``factor.source_grid`` set, or ``options.policy``
+    given) take ``B`` and return ``X`` in the *source* layout; the canonical
     embedding and the identity-prefix skip ride the batched sweep.
     """
     from .solve import _embedded_panels, _merge_panels, _solve_panels, \
         _split_rhs
     with telemetry.span("concurrent.solve"):
-        opts = resolve_options(options, _where="concurrent_solve",
-                               impl=impl, policy=policy)
+        opts = check_options(options)
         impl = opts.impl
         panel = B[:, None] if B.ndim == 1 else B
         ctsf, _, g, panel, start, restrict = _embedded_panels(
@@ -184,8 +184,8 @@ def concurrent_solve(factor: CholeskyFactor, B: jnp.ndarray,
 
 def concurrent_selinv(factor: CholeskyFactor, mesh: Optional[Mesh] = None,
                       axis: str = "data",
-                      impl=UNSET, policy=UNSET,
-                      options=None) -> SelectedInverse:
+                      options: Optional[SolverOptions] = None
+                      ) -> SelectedInverse:
     """Selected inversion of a batch of factors concurrently.
 
     With ``mesh``, the batch axis is sharded over ``axis`` — one backward
@@ -194,12 +194,11 @@ def concurrent_selinv(factor: CholeskyFactor, mesh: Optional[Mesh] = None,
     their posterior marginals stay device-resident end to end; without, it
     delegates to the cached batched path (:func:`selinv_batched`).
 
-    Embedded factors (``factor.source_grid`` set, or ``policy`` given)
-    run the sweep on the canonical grid with the identity prefix skipped
+    Embedded factors (``factor.source_grid`` set, or ``options.policy``
+    given) run the sweep on the canonical grid with the identity prefix skipped
     and return the selected inverse restricted to the source grid.
     """
-    opts = resolve_options(options, _where="concurrent_selinv",
-                           impl=impl, policy=policy)
+    opts = check_options(options)
     if mesh is None:
         return selinv_batched(factor, bucket=False, options=opts)
     from .solve import _resolve_embedding
@@ -222,21 +221,20 @@ def concurrent_selinv(factor: CholeskyFactor, mesh: Optional[Mesh] = None,
 
 
 def concurrent_quadratic_forms(factor: CholeskyFactor, y: jnp.ndarray,
-                               impl=UNSET, policy=UNSET,
-                               options=None) -> jnp.ndarray:
+                               options: Optional[SolverOptions] = None
+                               ) -> jnp.ndarray:
     """``y^T A_i^{-1} y`` for each factor in the batch.
 
     Uses ``‖L_i^{-1} y‖²`` — only the *forward* sweep, vmapped over the
     batch — which is half the work of a full solve and exactly the
     quadratic-form term INLA's objective needs per θ candidate.
 
-    Embedded factors (``factor.source_grid`` set, or ``policy`` given)
-    take ``y`` in the source layout; the identity-prefix rows of the
+    Embedded factors (``factor.source_grid`` set, or ``options.policy``
+    given) take ``y`` in the source layout; the identity-prefix rows of the
     embedded sweep are zero, so the squared norm needs no restriction.
     """
     from .solve import _embedded_panels, _forward_impl, _split_rhs
-    opts = resolve_options(options, _where="concurrent_quadratic_forms",
-                           impl=impl, policy=policy)
+    opts = check_options(options)
     impl = opts.impl
     ctsf, _, g, panel, start, _ = _embedded_panels(factor, opts.policy,
                                                    y.reshape(-1, 1))

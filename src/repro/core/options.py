@@ -1,21 +1,12 @@
 """Unified solver options for every serving entry point.
 
-Nine PRs of organic growth left the entry points threading the same small
-set of knobs — ``policy=``, ``regularize=``, ``impl=``, ``sweep=``, and
-``marginal_variances``'s oddly-named ``method=`` — through a dozen
-signatures, and every new feature (the partitioned sweep's
-``partition_plan`` being the motivating case) had to widen all of them
-again.  :class:`SolverOptions` consolidates that surface: one frozen,
-hashable dataclass accepted as a single ``options=`` kwarg by
+:class:`SolverOptions` is the one knob object: a frozen, hashable
+dataclass accepted as a single ``options=`` argument by
 ``factorize_window(_batched)``, the ``solve_many`` family,
 ``selected_inverse``/``selinv_batched``, the ``concurrent_*`` wrappers
-and the rung server.
-
-Legacy per-kwarg signatures keep working through :func:`resolve_options`,
-which folds them into an options object while emitting one
-``DeprecationWarning`` per legacy kwarg actually passed — internal code
-is fully migrated (CI runs the suite under ``-W
-error::DeprecationWarning`` excluding the shim tests to prove it).
+and the rung server.  Per-call data — right-hand sides, ``start_tile``
+prefixes, batch bucketing — stays out of it: options describe *how*,
+arguments describe *what*.
 
 Hashability is load-bearing, not cosmetic: the batching compile caches
 key on :meth:`SolverOptions.compile_key` — the compile-relevant subset of
@@ -25,35 +16,13 @@ which construction path produced the object.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional, Union
 
 from .gridpolicy import GridBucketPolicy
 from .ordering import PartitionPlan
 from .robustness import RegularizePolicy
 
-__all__ = ["SolverOptions", "resolve_options", "UNSET"]
-
-
-class _Unset:
-    """Sentinel distinguishing "kwarg not passed" from an explicit None
-    (``impl=None`` is a meaningful value: the per-backend default)."""
-
-    _instance = None
-
-    def __new__(cls):
-        if cls._instance is None:
-            cls._instance = super().__new__(cls)
-        return cls._instance
-
-    def __repr__(self):
-        return "<UNSET>"
-
-    def __bool__(self):
-        return False
-
-
-UNSET = _Unset()
+__all__ = ["SolverOptions", "check_options"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,29 +35,22 @@ class SolverOptions:
       regularize: numerical fault tolerance — ``None``/``False`` off,
         ``True`` the default :class:`~repro.core.robustness.RegularizePolicy`,
         or an explicit policy (the escalating-jitter retry ladder).
-      impl: kernel backend — ``"pallas"``, ``"ref"``, ``"unrolled"`` or
-        None for the per-backend default (pallas on TPU, ref elsewhere).
-      sweep: factorization sweep mode — ``"auto"`` (default), ``"fused"``,
-        ``"ring"``, ``"window"`` or ``"partitioned"`` (see
-        ``core.cholesky._factorize_window_impl``).
+      impl: kernel backend — ``"pallas"``, ``"ref"`` or None for the
+        per-backend default (pallas on TPU, ref elsewhere).
       partition_plan: a :class:`~repro.core.ordering.PartitionPlan` of
-        independent band partitions; with >1 partition, ``sweep="auto"``
-        dispatches the multi-partition fused sweep (2D Pallas grid, one
-        parallel axis over partitions).
+        independent band partitions; with >1 partition the factorization
+        runs the multi-partition fused sweep (2D Pallas grid, one parallel
+        axis over partitions).
       method: marginal-variance path — None (= ``"selinv"``) or
-        ``"panels"``; folds ``marginal_variances``'s old ``method=``
-        kwarg into the shared options surface.
+        ``"panels"``.
 
     Frozen and hashable (all fields are immutables or frozen dataclasses),
-    so an options object can key compile caches directly.  Per-call data —
-    RHS panels, ``start_tile`` prefixes, batch bucketing — stays out by
-    design: options describe *how*, arguments describe *what*.
+    so an options object can key compile caches directly.
     """
 
     policy: Optional[GridBucketPolicy] = None
     regularize: Union[None, bool, RegularizePolicy] = None
     impl: Optional[str] = None
-    sweep: str = "auto"
     partition_plan: Optional[PartitionPlan] = None
     method: Optional[str] = None
 
@@ -109,32 +71,14 @@ class SolverOptions:
         return dataclasses.replace(self, **changes)
 
 
-def resolve_options(options: Optional[SolverOptions] = None, *,
-                    _where: str = "this entry point",
-                    _stacklevel: int = 3,
-                    **legacy) -> SolverOptions:
-    """Merge legacy per-kwarg arguments into a :class:`SolverOptions`.
-
-    Every entry point calls this once: ``legacy`` maps field names to the
-    caller's legacy kwarg values, with :data:`UNSET` marking "not
-    passed".  Each legacy kwarg actually passed emits one
-    ``DeprecationWarning`` naming the replacement, then overrides the
-    corresponding field of ``options`` (legacy wins, so half-migrated
-    call sites behave exactly as they read).  With no legacy kwargs the
-    options object passes through untouched — the zero-warning path the
-    ``-W error::DeprecationWarning`` CI leg locks in.
-    """
-    base = options if options is not None else SolverOptions()
-    if not isinstance(base, SolverOptions):
+def check_options(options: Optional[SolverOptions],
+                  default: Optional[SolverOptions] = None) -> SolverOptions:
+    """The options an entry point runs with: ``options`` itself, or
+    ``default`` (a plain :class:`SolverOptions` unless given) when it is
+    None.  Anything else raises ``TypeError`` at the entry point."""
+    if options is None:
+        return default if default is not None else SolverOptions()
+    if not isinstance(options, SolverOptions):
         raise TypeError(
-            f"options= must be a SolverOptions, got {type(base).__name__}")
-    updates = {}
-    for name, value in legacy.items():
-        if value is UNSET:
-            continue
-        warnings.warn(
-            f"{_where}: the `{name}=` kwarg is deprecated; pass "
-            f"options=SolverOptions({name}=...) instead",
-            DeprecationWarning, stacklevel=_stacklevel)
-        updates[name] = value
-    return dataclasses.replace(base, **updates) if updates else base
+            f"options= must be a SolverOptions, got {type(options).__name__}")
+    return options

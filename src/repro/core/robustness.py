@@ -88,8 +88,8 @@ class RegularizePolicy:
 
     @staticmethod
     def resolve(regularize) -> Optional["RegularizePolicy"]:
-        """Normalize a ``regularize=`` argument: None/False -> None,
-        True -> default policy, a policy -> itself."""
+        """Normalize a ``SolverOptions.regularize`` value: None/False ->
+        None, True -> default policy, a policy -> itself."""
         if regularize is None or regularize is False:
             return None
         if regularize is True:

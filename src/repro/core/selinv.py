@@ -50,7 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +62,7 @@ from repro.runtime import telemetry
 from .batching import LRUCache, bucketed_batched_call
 from .cholesky import CholeskyFactor
 from .ctsf import BandedCTSF
-from .options import UNSET, resolve_options
+from .options import SolverOptions, check_options
 from .structure import TileGrid
 
 __all__ = ["SelectedInverse", "selected_inverse", "selinv_batched"]
@@ -206,22 +206,20 @@ def _tril_tiles(sc_full: jnp.ndarray, nat: int) -> jnp.ndarray:
 
 
 def selected_inverse(factor: CholeskyFactor,
-                     impl=UNSET,
-                     policy=UNSET,
-                     options=None) -> SelectedInverse:
+                     options: Optional[SolverOptions] = None
+                     ) -> SelectedInverse:
     """Band + arrow block of Σ = A^{-1} from a banded-arrowhead Cholesky
     factor, via the blocked Takahashi recurrence (one backward tile sweep,
     cost independent of how many entries are selected).
 
     Canonical-grid embedded factors (``factor.source_grid`` set, or
-    ``policy`` given) run the recurrence on the canonical grid — one
+    ``options.policy`` given) run the recurrence on the canonical grid — one
     compile per canonical rung across all source grids, prefix columns
     skipped via the sweep's traced ``start_tile`` — and the result is
     restricted back to the source grid, so every returned entry is an
     exact entry of the source problem's inverse."""
     from .solve import _resolve_embedding
-    opts = resolve_options(options, _where="selected_inverse",
-                           impl=impl, policy=policy)
+    opts = check_options(options)
     impl = opts.impl
     with telemetry.span("selinv.selected_inverse") as sp:
         ctsf, src, pad = _resolve_embedding(factor, opts.policy)
@@ -269,9 +267,9 @@ def _batched_selinv_fn(grid, opts, use_start=False):
     return _BATCHED_SELINV_CACHE.get_or_create(key, build)
 
 
-def selinv_batched(factor: CholeskyFactor, impl=UNSET,
-                   bucket: bool = True, policy=UNSET,
-                   options=None) -> SelectedInverse:
+def selinv_batched(factor: CholeskyFactor, bucket: bool = True,
+                   options: Optional[SolverOptions] = None
+                   ) -> SelectedInverse:
     """Selected inversion of a batch of same-grid factors (leading batch
     axis on the CTSF arrays, as returned by ``factorize_window_batched``) in
     one vmapped dispatch.
@@ -279,25 +277,24 @@ def selinv_batched(factor: CholeskyFactor, impl=UNSET,
     Args:
       factor: batched factor — ``ctsf.Dr`` must be 5-D
         ``(batch, ndt, bt+1, t, t)`` (with matching ``R``/``C``).
-      impl: kernel backend forwarded to the recurrence's tile primitives
-        (``solve_panel`` seeds and ``selinv_step`` contractions).
       bucket: pad the batch (by repeating the last factor) to the next
         power of two before dispatch and drop the padding results — the
         same pow2 bucketing compile cache as the batched factorization,
         bounding XLA compiles per grid at log2(max batch).  With
         ``bucket=False`` every distinct batch size compiles once.
+      options: the solver knobs; ``options.impl`` picks the recurrence's
+        kernel backend.
 
     Returns: a :class:`SelectedInverse` whose arrays carry the leading
     batch axis; ``diagonal()`` / ``covariance(i, j)`` broadcast over it.
 
     Canonical-grid embedded factors (``factor.source_grid`` set, or
-    ``policy`` given) run on the canonical grid — the cache keys on the
-    canonical grid, so mixed-size traffic compiles one recurrence per
+    ``options.policy`` given) run on the canonical grid — the cache keys on
+    the canonical grid, so mixed-size traffic compiles one recurrence per
     rung — and the result is restricted back to the source grid.
     """
     from .solve import _resolve_embedding
-    opts = resolve_options(options, _where="selinv_batched",
-                           impl=impl, policy=policy)
+    opts = check_options(options)
     with telemetry.span("selinv.batched") as sp:
         ctsf, src, pad = _resolve_embedding(factor, opts.policy)
         if ctsf.Dr.ndim != 5:

@@ -17,7 +17,7 @@ same code path; :func:`solve_many` exposes the panel form, and
 :func:`marginal_variances` / :func:`sample_gmrf` ride one blocked sweep for
 all selected indices / samples.
 
-With ``impl="pallas"`` each band sweep is one *fused* kernel launch
+With ``options.impl="pallas"`` each band sweep is one *fused* kernel launch
 (``kernels/band_solve.py``): a ring of the most recent ``band_tiles``
 solved panels stays resident in VMEM across tile rows, removing the
 per-tile HBM round-trips of the ``fori_loop``-of-``solve_panel`` reference
@@ -37,7 +37,7 @@ from repro.runtime import telemetry
 from .batching import LRUCache, bucketed_batched_call
 from .cholesky import CholeskyFactor
 from .ctsf import BandedCTSF
-from .options import SolverOptions, UNSET, resolve_options
+from .options import SolverOptions, check_options
 
 __all__ = ["forward_solve", "backward_solve", "solve", "logdet",
            "forward_solve_many", "backward_solve_many", "solve_many",
@@ -221,8 +221,7 @@ def _merge_panels(xd: jnp.ndarray, xa: jnp.ndarray) -> jnp.ndarray:
 
 
 def forward_solve_many(factor: CholeskyFactor, B: jnp.ndarray,
-                       impl=UNSET,
-                       start_tile: int = 0, policy=UNSET,
+                       start_tile: int = 0,
                        options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """Solve ``L Y = B`` for a panel of right-hand sides in one blocked sweep.
 
@@ -236,8 +235,7 @@ def forward_solve_many(factor: CholeskyFactor, B: jnp.ndarray,
         solver knobs.  ``options.impl="pallas"`` runs the whole band sweep
         as one fused kernel (``kernels.ops.band_forward_sweep``), ``"ref"``
         the per-tile ``fori_loop`` reference; ``None`` picks per backend
-        (pallas on TPU, ref elsewhere).  The bare ``impl=``/``policy=``
-        kwargs are deprecated aliases.
+        (pallas on TPU, ref elsewhere).
       start_tile: first band tile holding a nonzero (RHS-sparsity fast
         start).  The caller guarantees all rows above ``start_tile * t``
         are zero; the returned Y is identically zero there.
@@ -250,14 +248,13 @@ def forward_solve_many(factor: CholeskyFactor, B: jnp.ndarray,
     path (not reverse-differentiable), so ``start_tile=0`` keeps its own
     static-bound compilation.
 
-    Embedded factors (``factor.source_grid`` set, or ``policy`` given —
-    see ``core/gridpolicy.py``) take and return panels in the *source*
-    grid's padded layout; the canonical embedding, the identity-prefix
-    fast start and the restriction are handled here, and ``start_tile``
-    keeps its source-grid meaning.
+    Embedded factors (``factor.source_grid`` set, or ``options.policy``
+    given — see ``core/gridpolicy.py``) take and return panels in the
+    *source* grid's padded layout; the canonical embedding, the
+    identity-prefix fast start and the restriction are handled here, and
+    ``start_tile`` keeps its source-grid meaning.
     """
-    opts = resolve_options(options, _where="forward_solve_many", impl=impl,
-                           policy=policy)
+    opts = check_options(options)
     impl = opts.impl
     with telemetry.span("solve.forward_many", k=B.shape[-1]) as sp:
         ctsf, src, g, B, start, restrict = _embedded_panels(factor,
@@ -284,16 +281,12 @@ def forward_solve_many(factor: CholeskyFactor, B: jnp.ndarray,
 
 
 def backward_solve_many(factor: CholeskyFactor, Y: jnp.ndarray,
-                        impl=UNSET,
-                        policy=UNSET,
                         options: Optional[SolverOptions] = None
                         ) -> jnp.ndarray:
     """Solve ``L^T X = Y`` for an (padded_n, k) panel of right-hand sides in
     one blocked sweep.  Embedded factors take/return panels in the source
-    layout (cf. :func:`forward_solve_many`).  ``impl=``/``policy=`` are
-    deprecated aliases for the matching ``options`` fields."""
-    opts = resolve_options(options, _where="backward_solve_many", impl=impl,
-                           policy=policy)
+    layout (cf. :func:`forward_solve_many`)."""
+    opts = check_options(options)
     impl = opts.impl
     with telemetry.span("solve.backward_many", k=Y.shape[-1]) as sp:
         ctsf, _, g, Y, start, restrict = _embedded_panels(factor,
@@ -329,7 +322,6 @@ def _refine_panels(fDr, fR, fC, mDr, mR, mC, bd, ba, xd, xa, g, impl, start):
 
 
 def solve_many(factor: CholeskyFactor, B: jnp.ndarray,
-               impl=UNSET, policy=UNSET,
                options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """``A X = B`` for a panel of right-hand sides via ``L L^T``.
 
@@ -342,8 +334,9 @@ def solve_many(factor: CholeskyFactor, B: jnp.ndarray,
       B: ``(padded_n, k)`` panel in the padded layout (zero rows in the
         padding region; use ``grid.padded_index`` to place original-matrix
         entries).
-      impl: ``"pallas"`` = fused forward+backward sweep kernels (one launch
-        per sweep), ``"ref"`` = per-tile loops, ``None`` = backend default.
+      options: ``options.impl="pallas"`` = fused forward+backward sweep
+        kernels (one launch per sweep), ``"ref"`` = per-tile loops,
+        ``None`` = backend default.
 
     Returns: ``(padded_n, k)`` solution panel X.
 
@@ -351,20 +344,19 @@ def solve_many(factor: CholeskyFactor, B: jnp.ndarray,
     width never retraces; pad k up to a bucket if widths vary.
 
     Embedded factors (``factor.source_grid`` set by the policy-aware
-    factorizations, or ``policy`` given) take and return panels in the
-    *source* grid's padded layout: the canonical-grid embedding keys the
-    compile on the canonical grid — one compile per (canonical rung, k)
+    factorizations, or ``options.policy`` given) take and return panels in
+    the *source* grid's padded layout: the canonical-grid embedding keys
+    the compile on the canonical grid — one compile per (canonical rung, k)
     across all source grids — and both sweeps skip the identity prefix
     via their traced ``start_tile``.
 
     Jitter-recovered factors (``factor.info`` with a retained original
-    matrix and ``tau > 0`` — see ``regularize=`` on the factorizations)
-    get one residual-checked iterative-refinement step against the
-    *original* A, correcting most of the O(tau) bias the diagonal
+    matrix and ``tau > 0`` — see ``options.regularize`` on the
+    factorizations) get one residual-checked iterative-refinement step
+    against the *original* A, correcting most of the O(tau) bias the diagonal
     perturbation introduced; clean factors skip it entirely.
     """
-    opts = resolve_options(options, _where="solve_many", impl=impl,
-                           policy=policy)
+    opts = check_options(options)
     impl = opts.impl
     with telemetry.span("solve.solve_many", k=B.shape[-1]) as sp:
         ctsf, _, g, B, start, restrict = _embedded_panels(factor,
@@ -440,7 +432,6 @@ def _batched_refine_fn(grid, opts: SolverOptions, use_start: bool):
 
 
 def solve_many_batched(factor: CholeskyFactor, B: jnp.ndarray,
-                       impl=UNSET,
                        start_tile=None, bucket: bool = True,
                        options: Optional[SolverOptions] = None
                        ) -> jnp.ndarray:
@@ -455,12 +446,13 @@ def solve_many_batched(factor: CholeskyFactor, B: jnp.ndarray,
         CTSF arrays, e.g. from ``factorize_window_batched``).
       B: ``(batch, padded_n, k)`` float32 panels in the padded layout of
         ``factor.ctsf.grid``.
-      impl: kernel backend forwarded to the sweeps.
       start_tile: optional shared identity-prefix depth of a pre-embedded
         canonical batch (``gridpolicy.assemble_rung_batch``), threaded as
         a traced scalar so mixed pad depths share one compilation.
       bucket: pow2-pad the batch axis before dispatch (cf.
         ``factorize_window_batched``).
+      options: the solver knobs; ``options.impl`` picks the sweeps'
+        kernel backend.
 
     Returns: ``(batch, padded_n, k)`` solution panels, still in the
     factor grid's layout — callers owning an embedding restrict each
@@ -472,7 +464,7 @@ def solve_many_batched(factor: CholeskyFactor, B: jnp.ndarray,
     clean siblings of a recovered element return solutions bit-identical
     to an uncontaminated batch.
     """
-    opts = resolve_options(options, _where="solve_many_batched", impl=impl)
+    opts = check_options(options)
     ctsf = factor.ctsf
     g = ctsf.grid
     t, ndt, nat = g.t, g.n_diag_tiles, g.n_arrow_tiles
@@ -514,26 +506,23 @@ def solve_many_batched(factor: CholeskyFactor, B: jnp.ndarray,
 
 
 def forward_solve(factor: CholeskyFactor, b: jnp.ndarray,
-                  impl=UNSET,
                   options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """Solve ``L y = b`` (k=1 specialization of the panel sweep)."""
-    opts = resolve_options(options, _where="forward_solve", impl=impl)
+    opts = check_options(options)
     return forward_solve_many(factor, b.reshape(-1, 1), options=opts)[:, 0]
 
 
 def backward_solve(factor: CholeskyFactor, y: jnp.ndarray,
-                   impl=UNSET,
                    options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """Solve ``L^T x = y`` (k=1 specialization of the panel sweep)."""
-    opts = resolve_options(options, _where="backward_solve", impl=impl)
+    opts = check_options(options)
     return backward_solve_many(factor, y.reshape(-1, 1), options=opts)[:, 0]
 
 
 def solve(factor: CholeskyFactor, b: jnp.ndarray,
-          impl=UNSET, policy=UNSET,
           options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """A x = b via L L^T."""
-    opts = resolve_options(options, _where="solve", impl=impl, policy=policy)
+    opts = check_options(options)
     return solve_many(factor, b.reshape(-1, 1), options=opts)[:, 0]
 
 
@@ -548,28 +537,26 @@ def _rhs_grid(factor: CholeskyFactor):
 
 
 def sample_gmrf(factor: CholeskyFactor, key: jax.Array,
-                impl=UNSET,
                 options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """Draw x ~ N(0, A^{-1}) via x = L^{-T} z (the INLA sampling primitive)."""
-    opts = resolve_options(options, _where="sample_gmrf", impl=impl)
+    opts = check_options(options)
     z = jax.random.normal(key, (_rhs_grid(factor).padded_n,),
                           dtype=jnp.float32)
     return backward_solve(factor, z, options=opts)
 
 
 def sample_gmrf_many(factor: CholeskyFactor, key: jax.Array, num: int,
-                     impl=UNSET,
                      options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """Draw ``num`` samples x ~ N(0, A^{-1}) as one (padded_n, num) panel.
 
     All samples share a single blocked backward sweep (fused into one
-    kernel launch under ``impl="pallas"``) — the serving-path analogue of
-    :func:`sample_gmrf`, amortizing the factor over the whole batch of
-    posterior realizations.  Recompiles once per ``(grid, impl, num)``.
-    For embedded factors ``z`` is drawn in the source layout, so a
+    kernel launch under ``options.impl="pallas"``) — the serving-path
+    analogue of :func:`sample_gmrf`, amortizing the factor over the whole
+    batch of posterior realizations.  Recompiles once per ``(grid, impl,
+    num)``.  For embedded factors ``z`` is drawn in the source layout, so a
     bucketed factor reproduces the unbucketed samples bit-for-bit per key.
     """
-    opts = resolve_options(options, _where="sample_gmrf_many", impl=impl)
+    opts = check_options(options)
     with telemetry.span("solve.sample_gmrf_many", num=num):
         z = jax.random.normal(key, (_rhs_grid(factor).padded_n, num),
                               dtype=jnp.float32)
@@ -592,15 +579,10 @@ def _validate_indices(grid, indices) -> np.ndarray:
 
 
 def marginal_variances(factor: CholeskyFactor, indices: jnp.ndarray,
-                       method=UNSET,
-                       impl=UNSET,
-                       policy=UNSET,
                        options: Optional[SolverOptions] = None) -> jnp.ndarray:
     """Selected diagonal of A^{-1} — INLA's posterior marginal variances.
 
-    Two paths over the same factor, selected by ``options.method`` (the
-    bare ``method=`` kwarg — like ``impl=``/``policy=`` — is a deprecated
-    alias folded into :class:`~repro.core.options.SolverOptions`):
+    Two paths over the same factor, selected by ``options.method``:
 
     * ``method="selinv"`` (default, = ``options.method None``) — the
       blocked Takahashi recurrence
@@ -617,8 +599,8 @@ def marginal_variances(factor: CholeskyFactor, indices: jnp.ndarray,
       indices: 1-D concrete (host) array of element indices of the
         *original* matrix; out-of-range values raise, and arrow indices are
         remapped past the band padding rather than reading padded rows.
-      method: ``"selinv"`` or ``"panels"`` as above.
-      impl: kernel backend forwarded to the underlying sweep
+      options: ``options.method`` picks the path as above and
+        ``options.impl`` the kernel backend of the underlying sweep
         (``"pallas"`` / ``"ref"`` / ``None`` = backend default).
 
     Returns: ``(k,)`` variances, ordered like ``indices``.
@@ -629,14 +611,13 @@ def marginal_variances(factor: CholeskyFactor, indices: jnp.ndarray,
     how many.
 
     Indices always refer to the *source* matrix: for canonical-grid
-    embedded factors (``factor.source_grid`` set, or ``policy`` given)
-    both paths validate against the source structure and return the source
-    problem's variances; the embedding/restriction rides the policy-aware
-    machinery of :func:`repro.core.selinv.selected_inverse` /
+    embedded factors (``factor.source_grid`` set, or ``options.policy``
+    given) both paths validate against the source structure and return the
+    source problem's variances; the embedding/restriction rides the
+    policy-aware machinery of :func:`repro.core.selinv.selected_inverse` /
     :func:`forward_solve_many`.
     """
-    opts = resolve_options(options, _where="marginal_variances",
-                           method=method, impl=impl, policy=policy)
+    opts = check_options(options)
     mth = opts.method or "selinv"
     g = _rhs_grid(factor)
     padded = _validate_indices(g, indices)

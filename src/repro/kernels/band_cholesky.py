@@ -2,7 +2,7 @@
 
 After the solve sweeps were fused (``band_solve.py``), the factorization
 itself was the last per-panel dispatcher: the ring sweep in
-``core/cholesky.py`` ran one ``potrf`` + ``trsm`` + ``band_update`` launch
+``core/cholesky.py`` ran one ``potrf`` + ``trsm`` + band-update launch
 per band panel through a ``lax.scan``, round-tripping the (bt+1, t, t)
 panel ring and the arrow ring through HBM on every step.  This kernel is
 the factorization analogue of the fused solves — the whole band + arrow
@@ -18,7 +18,7 @@ the paper's left-looking accumulator reading of GEMM chains (§II):
 
       U[e] = sum_{j=1..bt} L[k+e, k-j] @ L[k, k-j]^T
 
-  entirely from VMEM — the ``band_update`` contraction with no HBM reads;
+  entirely from VMEM — the band-update contraction with no HBM reads;
 * the diagonal tile factorizes in-kernel (:func:`potrf.factorize_tile`,
   shared with the single-tile POTRF kernel) and is inverted once
   (:func:`trsm.invert_lower_tile`); each sub-diagonal and arrow tile of

@@ -7,7 +7,7 @@ through a ``lax.fori_loop`` — they are latency-bound: each step round-trips
 its (t, k) panel through HBM before the next step may start (cf. Ruipeng
 Li's analysis of GPU sparse triangular solves).  These kernels instead
 execute an *entire* band sweep in one launch, the solve-phase analogue of
-``band_update``'s fused factorization window:
+the fused band-Cholesky sweep (``kernels/band_cholesky.py``):
 
 * grid = (ndt,) — one sequential grid step per band tile row; TPU grid
   iteration order makes the recurrence dependence explicit and legal;
@@ -38,14 +38,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ring import (band_row_to_col, ring_accumulate, ring_read, ring_write,
+from .ring import (band_row_to_col, ring_accumulate, ring_write,
                    sweep_compiler_params, tile_dot)
 from .trsm import substitute_panel
 
-# ring_read/ring_write are re-exported for backward compatibility; the
-# canonical home of the ring machinery is kernels/ring.py.
-__all__ = ["band_forward_sweep_pallas", "band_backward_sweep_pallas",
-           "ring_read", "ring_write"]
+__all__ = ["band_forward_sweep_pallas", "band_backward_sweep_pallas"]
 
 
 def _compiler_params(bt, nat_p, t, k):

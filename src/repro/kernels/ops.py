@@ -18,7 +18,6 @@ from . import ref
 from .potrf import potrf_pallas
 from .trsm import solve_panel_pallas, trsm_pallas
 from .gemm import gemm_pallas, syrk_pallas, geadd_pallas
-from .band_update import band_update_pallas
 from .band_cholesky import (band_cholesky_partitioned_sweep_pallas,
                             band_cholesky_stream_sweep_pallas,
                             band_cholesky_sweep_pallas, sweep_path)
@@ -26,14 +25,14 @@ from .band_solve import band_backward_sweep_pallas, band_forward_sweep_pallas
 from .selinv import selinv_step_pallas, selinv_sweep_pallas
 
 __all__ = ["potrf", "trsm", "solve_panel", "syrk", "gemm", "geadd",
-           "band_update", "selinv_step", "band_forward_sweep",
+           "selinv_step", "band_forward_sweep",
            "band_backward_sweep", "band_cholesky_sweep",
            "band_cholesky_partitioned_sweep", "selinv_sweep",
            "default_impl"]
 
-Impl = Literal["ref", "pallas", "unrolled"]
+Impl = Literal["ref", "pallas"]
 
-_VALID_IMPLS = ("ref", "pallas", "unrolled")
+_VALID_IMPLS = ("ref", "pallas")
 
 
 def default_impl() -> Impl:
@@ -239,12 +238,3 @@ def selinv_sweep(lcol: jnp.ndarray, R: jnp.ndarray, sc_full: jnp.ndarray,
                                    interpret=_interp())
     return ref.selinv_sweep_ref(lcol, R, sc_full, start_tile)
 
-
-def band_update(w: jnp.ndarray, impl: Impl | None = None) -> jnp.ndarray:
-    impl = impl or default_impl()
-    if impl == "pallas":
-        return band_update_pallas(w, interpret=_interp())
-    if impl == "unrolled" or (impl == "ref" and w.shape[0] <= 6):
-        # small bands: skip structurally-zero (e, j) pairs entirely
-        return ref.band_update_unrolled_ref(w)
-    return ref.band_update_ref(w)

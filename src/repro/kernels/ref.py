@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["potrf_ref", "trsm_ref", "solve_panel_ref", "syrk_ref",
-           "gemm_ref", "geadd_ref", "band_update_ref", "selinv_step_ref",
+           "gemm_ref", "geadd_ref", "selinv_step_ref",
            "band_forward_sweep_ref", "band_backward_sweep_ref",
            "band_cholesky_sweep_ref", "band_cholesky_partitioned_sweep_ref",
            "selinv_sweep_ref", "sweep_status", "empty_sweep_status",
@@ -45,9 +45,7 @@ def sweep_status(panels: jnp.ndarray, R_out: jnp.ndarray) -> jnp.ndarray:
     yielding finite negative pivots, but the emitted tiles are the same
     story on both paths.  The fused Pallas kernel folds exactly this
     per-column update into a VMEM status carry as the sweep runs; this
-    helper is the jnp oracle for it (and serves the post-hoc "window"
-    legacy path, whose index is then a *row* index — NaNs propagate
-    forward, so the first bad row and first bad column coincide).
+    helper is the jnp oracle for it.
 
     jit-safe, no host sync, vmap/batch friendly: all three entries are
     data-dependent scalars with static shapes.
@@ -121,8 +119,7 @@ def selinv_step_ref(s_row: jnp.ndarray, g_col: jnp.ndarray) -> jnp.ndarray:
 
     so that Σ[i_e, j] = -u[e] (core/selinv.py's backward recurrence).  Every
     accumulation feeding one selected-inverse column rides this single
-    batched contraction — the selected-inversion analogue of
-    :func:`band_update_ref`.
+    batched contraction.
     """
     return jnp.einsum("ejab,jbc->eac", s_row, g_col, precision=_HI)
 
@@ -473,48 +470,3 @@ def selinv_sweep_ref(lcol: jnp.ndarray, R: jnp.ndarray,
     _, (panels_rev, acols_rev) = jax.lax.scan(body, (ring0, ring_a0), xs)
     return jnp.flip(panels_rev, 0), jnp.flip(acols_rev, 0)
 
-
-def band_update_unrolled_ref(w: jnp.ndarray) -> jnp.ndarray:
-    """Loop-free band update for small bands: only the structurally nonzero
-    (e, j) pairs are computed (no gather, no masked-zero FLOPs).
-
-    For band b this is b·(b+1)/2 tile matmuls vs the masked einsum's b·(b+1)
-    — a 2x FLOP cut that maps to 2x fewer MXU ops on TPU.  Preferred when
-    b is small (the arrowhead regime); the einsum/Pallas path wins for wide
-    bands where one big contraction amortizes better.
-    """
-    b1 = w.shape[0]
-    b = b1 - 1
-    t = w.shape[-1]
-    outs = []
-    for e in range(b1):
-        acc = jnp.zeros((t, t), jnp.float32)
-        for j in range(1, b1 - e):
-            acc = acc + jnp.dot(w[e, e + j], w[0, j].T, precision=_HI)
-        outs.append(acc.astype(w.dtype))
-    return jnp.stack(outs)
-
-
-def band_update_ref(w: jnp.ndarray) -> jnp.ndarray:
-    """Fused left-looking band-panel update (the `window` backend hot spot).
-
-    Input:  w  (b+1, b+1, t, t) — band-window rows k..k+b of the row-band
-            storage: w[e, d] = L_tile[k+e, k+e-d] (zero where out of band).
-    Output: u  (b+1, t, t) with
-
-        u[e] = sum_{j=1..b-e}  w[e, e+j] @ w[0, j]^T
-
-    i.e. every SYRK (e=0) and GEMM (e>0) accumulation feeding panel k, in
-    one batched contraction.  Entries with e+j > b contribute zero.
-    """
-    b1 = w.shape[0]
-    b = b1 - 1
-    # shifted gather: wsh[e, j] = w[e, e+j] (clamped; masked beyond band)
-    e_idx = jnp.arange(b1)[:, None]
-    j_idx = jnp.arange(b1)[None, :]
-    gather = jnp.clip(e_idx + j_idx, 0, b)
-    mask = ((e_idx + j_idx) <= b) & (j_idx >= 1)
-    wsh = jnp.take_along_axis(w, gather[:, :, None, None], axis=1)
-    wsh = jnp.where(mask[:, :, None, None], wsh, 0.0)
-    rhs = jnp.where((j_idx[0] >= 1)[:, None, None], w[0], 0.0)
-    return jnp.einsum("ejab,jcb->eac", wsh, rhs, precision=_HI)

@@ -8,7 +8,7 @@ selected inverse as
 
 where ``S`` is the block row of already-computed Σ tiles visible from column
 j (band window + arrow rows + corner) and ``G`` is the normalized factor
-column ``G[k] = L[k, j] L[j, j]^{-1}``.  Like ``band_update``, the entire
+column ``G[k] = L[k, j] L[j, j]^{-1}``.  As in the fused Cholesky, the entire
 accumulation chain feeding one output tile runs inside a single kernel whose
 accumulator never leaves VMEM: grid = (e_n target tiles, j-blocks); each
 target revisits its VMEM accumulator across j-blocks (the grid iterates the

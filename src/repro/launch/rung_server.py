@@ -19,7 +19,8 @@ drain     explicit shutdown/idle drain — everything left flushes
 A flushed batch is embedded onto its canonical grid
 (:func:`~repro.core.gridpolicy.assemble_rung_batch`), factorized through
 the rung-keyed compiled sweep (compile count stays O(#rungs), not
-O(#grids)) under the jitter ladder (``regularize=``), and solved with
+O(#grids)) under the jitter ladder (``SolverOptions.regularize``, on
+unless the caller's options say otherwise), and solved with
 per-request RHS panels (:func:`~repro.core.solve.solve_many_batched`).
 Each request's future resolves with its restricted solution/factor, the
 per-element :class:`~repro.core.robustness.FactorInfo` outcome (a failed
@@ -41,7 +42,7 @@ async dispatch returns unblocked device arrays, so the server dispatches
 batch N, assembles and dispatches batch N+1 on the host, and only then
 blocks on N's results (:meth:`RungExecutor.finalize`) — host assembly
 overlaps device execution with no threads in the data path.  (With
-``regularize=`` on, the jitter ladder's one status readback synchronizes
+``regularize`` on, the jitter ladder's one status readback synchronizes
 the *factorization*; the solve sweep — the long stage for wide panels —
 still overlaps.)  The optional threaded pump (:meth:`RungServer.start`)
 only moves the same synchronous ``pump()`` loop off the caller's thread.
@@ -95,7 +96,7 @@ from repro.core.cholesky import CholeskyFactor, factorize_window_batched
 from repro.core.ctsf import BandedCTSF
 from repro.core.gridpolicy import (GridBucketPolicy, assemble_rung_batch,
                                    assemble_rung_rhs, restrict_rhs)
-from repro.core.options import SolverOptions, UNSET, resolve_options
+from repro.core.options import SolverOptions, check_options
 from repro.core.robustness import (STATUS_FAILED, STATUS_OK,
                                    STATUS_RECOVERED, STATUS_SHED, FactorInfo)
 from repro.core.solve import solve_many_batched
@@ -656,6 +657,11 @@ class _Inflight:
     X: Optional[jnp.ndarray]
 
 
+# what the server runs with when no options are given: the jitter ladder
+# on; an explicit options object is taken verbatim
+SERVING_OPTIONS = SolverOptions(regularize=True)
+
+
 class RungExecutor:
     """Assembles, dispatches and finalizes rung batches.
 
@@ -665,16 +671,9 @@ class RungExecutor:
     works.  ``finalize`` blocks on the results, restricts each element
     back to its source layout, and resolves the futures."""
 
-    def __init__(self, impl=UNSET, tree_chunks: int = 8,
-                 sweep=UNSET, regularize=UNSET, bucket: bool = True,
+    def __init__(self, tree_chunks: int = 8, bucket: bool = True,
                  options: Optional[SolverOptions] = None):
-        opts = resolve_options(options, _where="RungExecutor",
-                               impl=impl, sweep=sweep, regularize=regularize)
-        # the server's historical default is the jitter ladder ON; an
-        # explicit options object is respected verbatim
-        if options is None and regularize is UNSET:
-            opts = opts.replace(regularize=True)
-        self.options = opts
+        self.options = check_options(options, SERVING_OPTIONS)
         self.tree_chunks = tree_chunks
         self.bucket = bucket
 
@@ -1025,8 +1024,7 @@ class RungServer:
 
     def __init__(self, policy: Optional[GridBucketPolicy] = None,
                  max_batch: int = 8, max_delay: float = 10e-3,
-                 impl=UNSET, tree_chunks: int = 8,
-                 sweep=UNSET, regularize=UNSET, bucket: bool = True,
+                 tree_chunks: int = 8, bucket: bool = True,
                  options: Optional[SolverOptions] = None,
                  clock=None, poll_interval: float = 1e-3,
                  max_queue: Optional[int] = None,
@@ -1040,11 +1038,7 @@ class RungServer:
         if on_overload not in ("raise", "shed"):
             raise ValueError(f"on_overload must be 'raise' or 'shed', "
                              f"got {on_overload!r}")
-        opts = resolve_options(options, _where="RungServer",
-                               impl=impl, sweep=sweep, regularize=regularize)
-        if options is None and regularize is UNSET:
-            opts = opts.replace(regularize=True)
-        self.options = opts
+        self.options = check_options(options, SERVING_OPTIONS)
         self.scheduler = RungScheduler(policy=policy, max_batch=max_batch,
                                        max_delay=max_delay,
                                        max_queue=max_queue,
@@ -1067,7 +1061,7 @@ class RungServer:
         sleep_fn = clock.advance if isinstance(clock, SimClock) \
             else time.sleep
         inner = executor if executor is not None else RungExecutor(
-            tree_chunks=tree_chunks, bucket=bucket, options=opts)
+            tree_chunks=tree_chunks, bucket=bucket, options=self.options)
         self.executor = ResilientRungExecutor(
             inner, clock=self.clock, sleep_fn=sleep_fn, events=self.events,
             max_retries=max_retries, backoff_base=backoff_base,

@@ -111,7 +111,7 @@ def test_gmrf_sampling_covariance():
 
 def test_pallas_impl_matches_ref_end_to_end():
     """impl="pallas" now rides the single-launch fused band-Cholesky sweep
-    (sweep="auto" resolves to "fused" on the Pallas backend)."""
+    (the dispatch picks "fused" on the Pallas backend)."""
     A, g, bm, dense = _setup(128, 16, 16, 16, 0.6)
     f_ref = factorize_window(bm, options=SolverOptions(impl="ref"))
     f_pl = factorize_window(bm, options=SolverOptions(impl="pallas"))
@@ -120,14 +120,15 @@ def test_pallas_impl_matches_ref_end_to_end():
 
 @pytest.mark.parametrize("n,bw,ar,t,rho", CASES)
 def test_fused_sweep_matches_dense(n, bw, ar, t, rho):
-    """The one-launch factorization (sweep="fused") is a drop-in for the
-    scan path on every grid shape, not just where Pallas is the default."""
+    """The one-launch factorization (impl="pallas") is a drop-in for the
+    scan path (impl="ref") on every grid shape, not just where Pallas is
+    the default."""
     A, g, bm, dense = _setup(n, bw, ar, t, rho)
-    f = factorize_window(bm, options=SolverOptions(sweep="fused"))
+    f = factorize_window(bm, options=SolverOptions(impl="pallas"))
     Lref = np.linalg.cholesky(dense)
     err = np.abs(f.ctsf.to_dense() - np.tril(Lref)).max()
     assert err < 1e-3 * max(1.0, np.abs(Lref).max())
-    f_ring = factorize_window(bm, options=SolverOptions(sweep="ring"))
+    f_ring = factorize_window(bm, options=SolverOptions(impl="ref"))
     assert np.allclose(f.ctsf.to_dense(), f_ring.ctsf.to_dense(), atol=2e-4)
 
 

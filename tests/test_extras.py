@@ -108,23 +108,3 @@ def test_moe_expert_padding_routes_only_real_experts():
     y2 = moe_apply(p2, x, top_k=2, capacity_factor=4.0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y2), atol=1e-6)
 
-
-def test_ring_sweep_equals_window_sweep():
-    import repro.core.cholesky as C
-    A, struct = make_arrowhead(320, 24, 16, rho=0.7, seed=5)
-    g = TileGrid(struct, t=16)
-    bm = BandedCTSF.from_sparse(A, g)
-    ring = C._factorize_window_impl(bm.Dr, bm.R, bm.C, g, "ref", 4, "ring")
-    win = C._factorize_window_impl(bm.Dr, bm.R, bm.C, g, "ref", 4, "window")
-    for a, b in zip(ring, win):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_band_update_unrolled_matches_einsum(rng):
-    from repro.kernels import ref
-    for b1 in (2, 4, 6):
-        w = jnp.asarray(rng.standard_normal((b1, b1, 8, 8)), jnp.float32)
-        np.testing.assert_allclose(
-            np.asarray(ref.band_update_unrolled_ref(w)),
-            np.asarray(ref.band_update_ref(w)), rtol=1e-4, atol=1e-4)

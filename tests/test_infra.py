@@ -20,7 +20,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ---------------------------------------------------------------------------
 
 def test_default_impl_env_override(monkeypatch):
-    for valid in ("ref", "pallas", "unrolled"):
+    for valid in ("ref", "pallas"):
         monkeypatch.setenv("REPRO_KERNEL_IMPL", valid)
         assert ops.default_impl() == valid
     monkeypatch.delenv("REPRO_KERNEL_IMPL")

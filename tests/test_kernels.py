@@ -11,7 +11,6 @@ from repro.kernels import ref
 from repro.kernels.band_cholesky import band_cholesky_sweep_pallas
 from repro.kernels.band_solve import (band_backward_sweep_pallas,
                                       band_forward_sweep_pallas)
-from repro.kernels.band_update import band_update_pallas
 from repro.kernels.gemm import gemm_pallas, geadd_pallas, syrk_pallas
 from repro.kernels.potrf import potrf_pallas
 from repro.kernels.ring import band_row_to_col
@@ -87,17 +86,6 @@ def test_geadd(rng, t):
     b = jnp.asarray(rng.standard_normal((5, t, t)), jnp.float32)
     np.testing.assert_allclose(np.asarray(geadd_pallas(a, b, interpret=True)),
                                np.asarray(a + b), rtol=1e-6, atol=1e-6)
-
-
-@pytest.mark.parametrize("b1", [2, 3, 5, 9])
-@pytest.mark.parametrize("t", [8, 16, 32])
-@pytest.mark.parametrize("jblock", [2, 4, 16])
-def test_band_update(rng, b1, t, jblock):
-    w = jnp.asarray(rng.standard_normal((b1, b1, t, t)), jnp.float32)
-    out = band_update_pallas(w, jblock=jblock, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(ref.band_update_ref(w)),
-                               rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("e_n,j_n", [(1, 1), (3, 5), (4, 9), (2, 17)])
@@ -244,9 +232,16 @@ def _corner_sigma(C, nat, t):
 
 
 # grids cover: single tile (bt=0), bt=0 + arrow, nat=0 with bt=1, thick
-# arrow / wide band, deep band with small tiles
+# arrow / wide band, deep band with small tiles; then every band width
+# bt in {1, 2, 4, 8} at every tile size t in {8, 16, 32}, full bands of
+# bt + 3 columns, six of them with one or two arrow tiles — the in-VMEM
+# left-looking update at each (bt, t)
 CHOLESKY_GRIDS = [(16, 4, 0, 16), (30, 6, 14, 16), (160, 8, 0, 16),
-                  (130, 40, 30, 16), (96, 40, 16, 8)]
+                  (130, 40, 30, 16), (96, 40, 16, 8),
+                  (34, 7, 6, 8), (112, 31, 0, 32),
+                  (36, 15, 0, 8), (84, 31, 12, 16), (200, 63, 56, 32),
+                  (66, 31, 14, 8), (104, 63, 0, 16), (232, 127, 24, 32),
+                  (84, 63, 0, 8), (196, 127, 28, 16), (336, 255, 0, 32)]
 
 
 @pytest.mark.parametrize("n,bw,ar,t", CHOLESKY_GRIDS)
@@ -423,18 +418,6 @@ def test_band_backward_sweep_start_tile(rng, start_tile):
     np.testing.assert_allclose(np.asarray(xd)[start_tile:],
                                np.asarray(xfull)[start_tile:],
                                rtol=2e-4, atol=2e-4)
-
-
-def test_band_update_ref_semantics(rng):
-    """Cross-check the fused contraction against the naive task loop."""
-    b1, t = 4, 8
-    w = np.asarray(rng.standard_normal((b1, b1, t, t)), np.float32)
-    want = np.zeros((b1, t, t), np.float32)
-    for e in range(b1):
-        for j in range(1, b1 - e):
-            want[e] += w[e, e + j] @ w[0, j].T
-    np.testing.assert_allclose(np.asarray(ref.band_update_ref(jnp.asarray(w))),
-                               want, rtol=1e-4, atol=1e-4)
 
 
 def _near_singular_ctsf(n, bw, ar, t, cond=1e4):

@@ -3,10 +3,9 @@
 
 Two golden snapshots pin the public surface — ``repro.api.__all__`` and
 the ``SolverOptions`` field set/defaults — so additions are deliberate
-diffs and removals are loud failures.  The shim tests (marked
-``legacy_shim``) assert every deprecated per-call kwarg still works and
-warns exactly once; the options-path tests assert the blessed spelling
-is silent under ``-W error::DeprecationWarning``.
+diffs and removals are loud failures.  Every entry point that takes
+``options=`` refuses anything but a ``SolverOptions`` there, and the
+options path is silent under ``-W error::DeprecationWarning``.
 """
 import dataclasses
 import warnings
@@ -19,7 +18,8 @@ jax.config.update("jax_platform_name", "cpu")
 
 from repro import api
 from repro.core import BandedCTSF, TileGrid
-from repro.core.options import SolverOptions, resolve_options
+from repro.core.options import SolverOptions
+from repro.launch.rung_server import RungExecutor
 from repro.data import make_arrowhead
 
 API_SNAPSHOT = [
@@ -51,7 +51,6 @@ OPTIONS_FIELDS = {
     "policy": None,
     "regularize": None,
     "impl": None,
-    "sweep": "auto",
     "partition_plan": None,
     "method": None,
 }
@@ -88,8 +87,8 @@ def test_solver_options_frozen_and_hashable():
         opts.impl = "pallas"
     assert hash(opts) == hash(SolverOptions(impl="ref"))
     assert opts != SolverOptions(impl="pallas")
-    assert opts.replace(sweep="fused").sweep == "fused"
-    assert opts.replace(sweep="fused") is not opts
+    assert opts.replace(method="panels").method == "panels"
+    assert opts.replace(method="panels") is not opts
 
 
 def test_compile_key_drops_non_compile_fields():
@@ -101,9 +100,71 @@ def test_compile_key_drops_non_compile_fields():
     assert a.compile_key() != SolverOptions(impl="pallas").compile_key()
 
 
-def test_resolve_options_rejects_wrong_type():
-    with pytest.raises(TypeError):
-        resolve_options({"impl": "ref"})
+# every entry point that takes options=, called with its data as usual
+ENTRY_POINTS = {
+    "factorize_window": lambda p, o: api.factorize_window(p["m"], options=o),
+    "factorize_window_batched": lambda p, o: api.factorize_window_batched(
+        [p["m"], p["m"]], options=o),
+    "forward_solve_many": lambda p, o: api.forward_solve_many(
+        p["f"], p["B"], options=o),
+    "backward_solve_many": lambda p, o: api.backward_solve_many(
+        p["f"], p["B"], options=o),
+    "solve_many": lambda p, o: api.solve_many(p["f"], p["B"], options=o),
+    "solve_many_batched": lambda p, o: api.solve_many_batched(
+        p["fb"], p["Bb"], options=o),
+    "forward_solve": lambda p, o: api.forward_solve(
+        p["f"], p["B"][:, 0], options=o),
+    "backward_solve": lambda p, o: api.backward_solve(
+        p["f"], p["B"][:, 0], options=o),
+    "solve": lambda p, o: api.solve(p["f"], p["B"][:, 0], options=o),
+    "sample_gmrf": lambda p, o: api.sample_gmrf(
+        p["f"], jax.random.PRNGKey(0), options=o),
+    "sample_gmrf_many": lambda p, o: api.sample_gmrf_many(
+        p["f"], jax.random.PRNGKey(0), 2, options=o),
+    "marginal_variances": lambda p, o: api.marginal_variances(
+        p["f"], np.arange(4), options=o),
+    "selected_inverse": lambda p, o: api.selected_inverse(p["f"], options=o),
+    "selinv_batched": lambda p, o: api.selinv_batched(p["fb"], options=o),
+    "concurrent_factorize": lambda p, o: api.concurrent_factorize(
+        api.stack_ctsf([p["m"], p["m"]]), options=o),
+    "concurrent_solve": lambda p, o: api.concurrent_solve(
+        p["fb"], p["B"], options=o),
+    "concurrent_selinv": lambda p, o: api.concurrent_selinv(
+        p["fb"], options=o),
+    "concurrent_quadratic_forms": lambda p, o: api.concurrent_quadratic_forms(
+        p["fb"], p["B"][:, 0], options=o),
+    "RungExecutor": lambda p, o: RungExecutor(options=o),
+    "RungServer": lambda p, o: api.RungServer(clock=api.SimClock(),
+                                              options=o),
+}
+
+
+@pytest.fixture(scope="module")
+def problem():
+    opts = SolverOptions(impl="ref")
+    f, m = _factor(opts)
+    B = np.zeros((m.grid.padded_n, 2), np.float32)
+    B[:3, :] = 1.0
+    fb = api.concurrent_factorize(api.stack_ctsf([m, m]), options=opts)
+    return {"f": f, "m": m, "B": B, "fb": fb, "Bb": B[None].repeat(2, 0)}
+
+
+@pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
+def test_entry_point_rejects_a_non_options_object(entry, problem):
+    """A dict, a string or a bare kwarg spelling in ``options=`` is a
+    ``TypeError`` at the entry point, not a silently ignored knob."""
+    with pytest.raises(TypeError, match="must be a SolverOptions"):
+        ENTRY_POINTS[entry](problem, {"impl": "ref"})
+
+
+def test_rung_server_defaults_to_the_jitter_ladder():
+    # with no options the server and its executor run the ladder; an
+    # explicit options object is taken verbatim
+    assert RungExecutor().options.regularize is True
+    assert api.RungServer(clock=api.SimClock()).options.regularize is True
+    explicit = api.RungServer(clock=api.SimClock(),
+                              options=SolverOptions(impl="ref"))
+    assert explicit.options.regularize is None
 
 
 # ---------------------------------------------------------------------------
@@ -127,79 +188,3 @@ def test_options_path_emits_no_deprecation_warnings():
         api.selinv_batched(fb, options=opts)
         api.concurrent_selinv(fb, options=opts)
         api.solve_many_batched(fb, b[None].repeat(2, 0), options=opts)
-
-
-# ---------------------------------------------------------------------------
-# every legacy kwarg warns (one DeprecationWarning per kwarg passed)
-# ---------------------------------------------------------------------------
-
-def _one_deprecation(fn):
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        out = fn()
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1, [str(w.message) for w in rec]
-    assert "options=SolverOptions(" in str(dep[0].message)
-    return out
-
-
-@pytest.mark.legacy_shim
-def test_factorize_window_legacy_kwargs_warn():
-    A, st = make_arrowhead(64, 6, 4, seed=0)
-    m = BandedCTSF.from_sparse(A, TileGrid(st, 8))
-    f_new = api.factorize_window(m, options=SolverOptions(impl="ref"))
-    f_old = _one_deprecation(lambda: api.factorize_window(m, impl="ref"))
-    np.testing.assert_array_equal(np.asarray(f_old.ctsf.Dr),
-                                  np.asarray(f_new.ctsf.Dr))
-    _one_deprecation(lambda: api.factorize_window(m, sweep="ring"))
-    _one_deprecation(lambda: api.factorize_window(m, regularize=True))
-
-
-@pytest.mark.legacy_shim
-def test_solve_and_selinv_legacy_kwargs_warn():
-    f, m = _factor()
-    b = np.zeros((m.grid.padded_n, 2), np.float32)
-    b[:3, :] = 1.0
-    _one_deprecation(lambda: api.solve_many(f, b, impl="ref"))
-    _one_deprecation(lambda: api.forward_solve_many(f, b, impl="ref"))
-    _one_deprecation(lambda: api.backward_solve_many(f, b, impl="ref"))
-    _one_deprecation(lambda: api.selected_inverse(f, impl="ref"))
-    _one_deprecation(
-        lambda: api.marginal_variances(f, np.arange(4), method="panels"))
-
-
-@pytest.mark.legacy_shim
-def test_batched_and_concurrent_legacy_kwargs_warn():
-    _, m = _factor()
-    batch = api.stack_ctsf([m, m])
-    fb = _one_deprecation(lambda: api.concurrent_factorize(batch, impl="ref"))
-    _one_deprecation(lambda: api.selinv_batched(fb, impl="ref"))
-    _one_deprecation(lambda: api.concurrent_selinv(fb, impl="ref"))
-    _one_deprecation(
-        lambda: api.factorize_window_batched([m, m], impl="ref"))
-
-
-@pytest.mark.legacy_shim
-def test_legacy_kwarg_wins_over_options_field():
-    # transition-period contract: an explicitly passed legacy kwarg
-    # overrides the same field in options (and still warns)
-    A, st = make_arrowhead(64, 6, 4, seed=0)
-    m = BandedCTSF.from_sparse(A, TileGrid(st, 8))
-    f = _one_deprecation(lambda: api.factorize_window(
-        m, impl="ref", options=SolverOptions(impl="pallas", sweep="ring")))
-    f_ref = api.factorize_window(
-        m, options=SolverOptions(impl="ref", sweep="ring"))
-    np.testing.assert_array_equal(np.asarray(f.ctsf.Dr),
-                                  np.asarray(f_ref.ctsf.Dr))
-
-
-@pytest.mark.legacy_shim
-def test_rung_server_legacy_kwargs_warn():
-    from repro.launch.rung_server import RungExecutor, RungServer, SimClock
-    _one_deprecation(lambda: RungExecutor(impl="ref"))
-    _one_deprecation(lambda: RungServer(clock=SimClock(), impl="ref"))
-    # default server behavior keeps the jitter ladder on
-    srv = RungServer(clock=SimClock())
-    assert srv.options.regularize is True
-    explicit = RungServer(clock=SimClock(), options=SolverOptions(impl="ref"))
-    assert explicit.options.regularize is None

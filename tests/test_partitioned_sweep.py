@@ -29,6 +29,7 @@ from repro.core.ordering import PartitionPlan
 from repro.data import block_separable_arrowhead, make_arrowhead
 from repro.kernels import ops, ref
 from repro.kernels.ring import band_row_to_col
+from repro.runtime import telemetry
 
 CASE = dict(n=100, bandwidth=5, arrow=4, t=8)
 
@@ -113,18 +114,30 @@ def test_detect_partition_plan_certifies_generator_cuts():
 
 
 def test_auto_sweep_dispatches_partitioned_only_for_multi_partition_plans():
+    """The sweep is chosen from the input alone: a two-partition plan takes
+    the partitioned sweep; a one-partition plan or none takes the band
+    sweep (the ref backend's ring scan).  Read from the ``cholesky.sweep
+    {path=}`` counter and the entry point's span tag."""
     _, g, m, bounds = _split_inputs(2)
     plan = PartitionPlan(boundaries=bounds, sep_tiles=g.n_arrow_tiles)
     assert plan.n_partitions == 2
-    f_auto = factorize_window(
-        m, options=SolverOptions(impl="ref", partition_plan=plan))
-    f_expl = factorize_window(
-        m, options=SolverOptions(impl="ref", sweep="partitioned",
-                                 partition_plan=plan))
-    for a, b in zip(f_auto.ctsf.arrays(), f_expl.ctsf.arrays()):
-        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
-    with pytest.raises(ValueError):
-        factorize_window(m, options=SolverOptions(sweep="partitioned"))
+    for p, want in ((plan, "partitioned"),
+                    (PartitionPlan.trivial(g.n_diag_tiles), "ring"),
+                    (None, "ring")):
+        telemetry.reset()
+        with telemetry.capture():
+            factorize_window(
+                m, options=SolverOptions(impl="ref", partition_plan=p))
+            snap = telemetry.snapshot()
+        telemetry.reset()
+        assert snap["counters"][f"cholesky.sweep{{path={want}}}"] == 1.0
+        span, = [s for s in snap["spans"] if s["name"] == "factorize.window"]
+        assert span["tags"]["sweep"] == want
+    # a plan for another grid is refused, not silently misapplied
+    with pytest.raises(ValueError, match="rebuild the plan"):
+        factorize_window(m, options=SolverOptions(
+            impl="ref", partition_plan=PartitionPlan.trivial(
+                g.n_diag_tiles + 1)))
 
 
 @pytest.mark.parametrize("impl", ["ref", "pallas"])

@@ -168,10 +168,3 @@ def test_auto_streams_a_band_over_the_cap(monkeypatch, fresh_caches):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=2e-4, atol=2e-4)
 
-
-def test_forced_fused_over_the_cap_raises(monkeypatch, fresh_caches):
-    bm, _ = _spd_ctsf(104, 88, 8, 8)
-    monkeypatch.setattr(ring, "VMEM_CAP_BYTES", 2 ** 20)
-    with pytest.raises(ValueError, match="VMEM"):
-        api.factorize_window(
-            bm, options=api.SolverOptions(impl="pallas", sweep="fused"))
