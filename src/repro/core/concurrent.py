@@ -165,13 +165,14 @@ def concurrent_solve(factor: CholeskyFactor, B: jnp.ndarray,
     embedding and the identity-prefix skip ride the batched sweep.
     """
     from .solve import _embedded_panels, _merge_panels, _solve_panels, \
-        _split_rhs
-    with telemetry.span("concurrent.solve"):
+        _split_rhs, _tag_tile_block
+    with telemetry.span("concurrent.solve") as sp:
         opts = check_options(options)
         impl = opts.impl
         panel = B[:, None] if B.ndim == 1 else B
         ctsf, _, g, panel, start, restrict = _embedded_panels(
             factor, opts.policy, panel)
+        _tag_tile_block(sp, g, impl)
         bd, ba = _split_rhs(g, panel)
         with telemetry.span("solve.enqueue"):
             xd, xa = jax.vmap(

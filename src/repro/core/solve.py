@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.potrf import tile_block
 from repro.runtime import telemetry
 from .batching import LRUCache, bucketed_batched_call
 from .cholesky import CholeskyFactor
@@ -59,6 +60,15 @@ def _split_rhs(g, b: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     bd = b[: ndt * t].reshape(ndt, t, k)
     ba = b[ndt * t:].reshape(nat, t, k) if nat else jnp.zeros((0, t, k), b.dtype)
     return bd, ba
+
+
+def _tag_tile_block(span, grid, impl):
+    """Tags a solve span, where the Pallas band sweeps run, with
+    ``tile_block``: the row block their diagonal tiles are solved by
+    (``potrf.tile_block``: 32 where the blocked inverse runs, t where the
+    t-step substitution does)."""
+    if (impl or ops.default_impl()) == "pallas":
+        span.tag(tile_block=tile_block(grid.t))
 
 
 @functools.partial(jax.jit, static_argnames=("grid", "impl"))
@@ -260,6 +270,7 @@ def forward_solve_many(factor: CholeskyFactor, B: jnp.ndarray,
         ctsf, src, g, B, start, restrict = _embedded_panels(factor,
                                                             opts.policy, B)
         sp.tag(grid=telemetry.rung_tag(g))
+        _tag_tile_block(sp, g, impl)
         bd, ba = _split_rhs(g, B)
         if start is not None:
             # caller's start_tile is in source band-tile coordinates; the
@@ -292,6 +303,7 @@ def backward_solve_many(factor: CholeskyFactor, Y: jnp.ndarray,
         ctsf, _, g, Y, start, restrict = _embedded_panels(factor,
                                                           opts.policy, Y)
         sp.tag(grid=telemetry.rung_tag(g))
+        _tag_tile_block(sp, g, impl)
         yd, ya = _split_rhs(g, Y)
         if start is not None:
             xd, xa = _backward_impl(ctsf.Dr, ctsf.R, ctsf.C, yd, ya, g, impl,
@@ -362,6 +374,7 @@ def solve_many(factor: CholeskyFactor, B: jnp.ndarray,
         ctsf, _, g, B, start, restrict = _embedded_panels(factor,
                                                           opts.policy, B)
         sp.tag(grid=telemetry.rung_tag(g))
+        _tag_tile_block(sp, g, impl)
         bd, ba = _split_rhs(g, B)
         xd, xa = _solve_panels(ctsf.Dr, ctsf.R, ctsf.C, bd, ba, g, impl,
                                start)
@@ -478,7 +491,8 @@ def solve_many_batched(factor: CholeskyFactor, B: jnp.ndarray,
             f"got {B.shape}")
     k = B.shape[2]
     with telemetry.span("solve.solve_many_batched", b=nb, k=k,
-                        grid=telemetry.rung_tag(g)):
+                        grid=telemetry.rung_tag(g)) as sp:
+        _tag_tile_block(sp, g, opts.impl)
         bd = B[:, :ndt * t].reshape(nb, ndt, t, k)
         ba = B[:, ndt * t:].reshape(nb, nat, t, k)
         use_start = start_tile is not None

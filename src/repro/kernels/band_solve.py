@@ -15,14 +15,21 @@ the fused band-Cholesky sweep (``kernels/band_cholesky.py``):
   (``kernels/ring.py`` — the ring discipline shared with the fused
   band-Cholesky and selinv sweeps), so the ``L[m, m-j] @ Y_{m-j}``
   (t, t) @ (t, k) MXU accumulations never touch HBM;
-* the per-tile triangular solve is :func:`kernels.trsm.substitute_panel`,
-  shared with the ``solve_panel`` kernel;
+* the diagonal tile's solve is chosen from t alone, as the Cholesky
+  sweeps' column finish chooses (``potrf.tile_block``): where the tile is
+  blocked (every t = 128 tile) it is the blocked inverse
+  :func:`kernels.trsm.invert_lower_tile` (an nb-step VPU loop and a few
+  MXU products, none of which reads the panel) and one (t, t) @ (t, k)
+  product; smaller tiles keep the t-step
+  :func:`kernels.trsm.substitute_panel` of the ``solve_panel`` kernel;
 * forward only: the arrow-row contributions ``sum_m R[m, i] @ Y_m`` are
   accumulated into a VMEM scratch as the sweep passes each row and emitted
   once at the end — the arrow RHS correction comes for free.
 
-VMEM budget per step: (bt+1)·t² + (bt + 2·nat)·t·k floats — e.g. bt=8,
-t=128, k=64, nat=2: ~1.1 MB, far under the ~16 MB/core of v5e.
+Each sweep asks Mosaic for the VMEM of its step (``_compiler_params``):
+the panel ring and arrow accumulator, the double-buffered factor blocks
+and panels, and the live values, the blocked inverse's five (t, t)
+temporaries among them.
 
 ``start_tile`` (forward) supports the RHS-sparsity path of
 ``marginal_variances(method="panels")``: it is a *traced* scalar (SMEM
@@ -40,7 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .ring import (band_row_to_col, ring_accumulate, ring_write,
                    sweep_compiler_params, tile_dot)
-from .trsm import substitute_panel
+from .potrf import tile_block
+from .trsm import invert_lower_tile, substitute_panel
 
 __all__ = ["band_forward_sweep_pallas", "band_backward_sweep_pallas"]
 
@@ -48,13 +56,26 @@ __all__ = ["band_forward_sweep_pallas", "band_backward_sweep_pallas"]
 def _compiler_params(bt, nat_p, t, k):
     """VMEM budget of one band-solve step: the (t, k) panel ring and arrow
     accumulator (scratch), the factor column/row block, arrow rows and
-    (t, k) panels in and out, and the step's live panels.  A panel takes
-    VMEM for whole 128-lane rows, however few its columns."""
+    (t, k) panels in and out, and the step's live panels and tiles: the
+    blocked inverse keeps five (t, t) values live (E, D⁻¹, M, its power
+    and the inverse).  A panel takes VMEM for whole 128-lane rows, however
+    few its columns."""
     panel = t * -(-k // 128) * 128 * 4
+    tiles = 2 + (5 if tile_block(t) < t else 0)
     return sweep_compiler_params(
         scratch=(max(bt, 1) + nat_p) * panel,
         blocks=(bt + 1 + nat_p) * t * t * 4 + (2 + 2 * nat_p) * panel,
-        temps=8 * panel + 2 * t * t * 4)
+        temps=8 * panel + tiles * t * t * 4)
+
+
+def _solve_diagonal(l, rhs, trans=False):
+    """``L X = B`` (``trans``: ``L^T X = B``) for the diagonal tile L and
+    the step's (t, k) panel: L⁻¹ by blocks and one MXU product where
+    ``potrf.tile_block`` blocks the tile, else the t-step substitution."""
+    t = l.shape[-1]
+    if tile_block(t) == t:
+        return substitute_panel(l, rhs, trans=trans)
+    return tile_dot(invert_lower_tile(l), rhs, trans_a=trans)
 
 
 # ---------------------------------------------------------------------------
@@ -94,7 +115,7 @@ def _band_forward_kernel(start_ref, dr_ref, r_ref, b_ref, y_ref, acca_ref,
             step=-1)
 
         rhs = b_ref[0].astype(jnp.float32) - acc
-        ym = substitute_panel(dr_ref[0, 0].astype(jnp.float32), rhs)
+        ym = _solve_diagonal(dr_ref[0, 0].astype(jnp.float32), rhs)
         y_ref[0] = ym.astype(y_ref.dtype)
         if bt:
             ring_write(ring_ref, m, bt, ym)
@@ -198,8 +219,8 @@ def _band_backward_kernel(start_ref, lcol_ref, r_ref, y_ref, xa_ref, x_ref,
                                    trans_a=True)
 
         rhs = y_ref[0].astype(jnp.float32) - acc2
-        xm = substitute_panel(lcol_ref[0, 0].astype(jnp.float32), rhs,
-                              trans=True)
+        xm = _solve_diagonal(lcol_ref[0, 0].astype(jnp.float32), rhs,
+                             trans=True)
         x_ref[0] = xm.astype(x_ref.dtype)
         if bt:
             ring_write(ring_ref, m, bt, xm)

@@ -7,9 +7,10 @@ masked vector ops; the whole tile lives in VMEM for the duration.
 Also the in-kernel triangular routines the fused sweeps share:
 :func:`substitute_panel` (``L X = B`` for a (t, k) panel, a t-step loop)
 and :func:`invert_lower_tile` (L⁻¹ for the Cholesky sweeps' column
-finish), which inverts by blocks where ``potrf.tile_block`` blocks the tile
-Cholesky: an nb-step substitution over the t/nb diagonal blocks at once,
-then the strictly block-lower part on the MXU.
+finish and the band solves' diagonal tiles), which inverts by blocks where
+``potrf.tile_block`` blocks the tile Cholesky: an nb-step substitution
+over the t/nb diagonal blocks at once, then the strictly block-lower part
+on the MXU.
 """
 from __future__ import annotations
 
@@ -32,10 +33,11 @@ def substitute_panel(l: jnp.ndarray, b: jnp.ndarray,
     ``L^T X = B``) for one (t, t) lower-triangular tile against a (t, k)
     panel, using only masked 2-D vector ops (no gather/scatter, no 1-D
     vectors, no matrix-vector ``dot``) so it lowers inside a Mosaic kernel
-    body.  Shared by :func:`solve_panel_pallas`, the fused band sweeps
-    in ``kernels/band_solve.py``, and the Cholesky and selinv sweeps,
-    which form ``L_kk^{-1}`` with it (``B`` the identity).  Operates in
-    and returns float32."""
+    body.  Shared by :func:`solve_panel_pallas`, the fused band sweeps in
+    ``kernels/band_solve.py`` at tiles ``potrf.tile_block`` leaves whole,
+    and, with ``B`` the identity, the selinv sweep, which forms
+    ``L_jj^{-1}`` with it, and :func:`invert_lower_tile` at those tiles.
+    Operates in and returns float32."""
     t, k = l.shape[-1], b.shape[-1]
     lrows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
     lcols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
@@ -60,7 +62,8 @@ def substitute_panel(l: jnp.ndarray, b: jnp.ndarray,
 
 def invert_lower_tile(l: jnp.ndarray) -> jnp.ndarray:
     """In-kernel L⁻¹ of one (t, t) lower-triangular tile, for the
-    Cholesky sweeps' column finish.  With nb = ``potrf.tile_block(t)`` < t,
+    Cholesky sweeps' column finish and the band solves' diagonal tiles
+    (``kernels/band_solve.py``, which apply it with one product).  With nb = ``potrf.tile_block(t)`` < t,
     L = D + N (D its nb-wide diagonal blocks, N the strictly block-lower
     rest) = D(I + M) with M = D⁻¹N, so L⁻¹ = (I + M)⁻¹D⁻¹.  D⁻¹ takes one
     nb-step substitution, every block at once: step s finishes row s of
@@ -105,8 +108,8 @@ def substitute_right(l: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
     """In-kernel right triangular substitution: solve ``X L^T = A`` (i.e.
     ``X = A L^{-T}``, the TRSM of the tile Cholesky) for a ``(..., t, t)``
     batch of tiles A against one (t, t) lower tile L, using only masked
-    2-D vector ops.  The kernel of :func:`trsm_pallas`; the fused sweeps
-    invert their diagonal tile once with :func:`substitute_panel` and
+    2-D vector ops.  The kernel of :func:`trsm_pallas`; the fused Cholesky
+    sweeps invert their diagonal tile once (:func:`invert_lower_tile`) and
     multiply instead.  Operates in and returns float32."""
     t = l.shape[-1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)

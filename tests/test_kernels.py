@@ -123,12 +123,15 @@ def _band_factor(rng, ndt, bt, nat, t):
 
 # grids cover: single tile (bt=0), no arrow, bandwidth > 1, deep band
 SWEEP_GRIDS = [(1, 0, 0), (5, 1, 0), (6, 2, 2), (9, 4, 1)]
+# the band solves' diagonal tiles: the t-step substitution at t = 8, the
+# blocked inverse and one product at t = 64 (``potrf.tile_block`` 32)
+SOLVE_TILES = [8, 64]
 
 
+@pytest.mark.parametrize("t", SOLVE_TILES)
 @pytest.mark.parametrize("ndt,bt,nat", SWEEP_GRIDS)
 @pytest.mark.parametrize("k", [1, 13])
-def test_band_forward_sweep(rng, ndt, bt, nat, k):
-    t = 8
+def test_band_forward_sweep(rng, ndt, bt, nat, k, t):
     Dr, R = _band_factor(rng, ndt, bt, nat, t)
     bd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
     yd, acca = band_forward_sweep_pallas(Dr, R, bd, interpret=True)
@@ -139,11 +142,12 @@ def test_band_forward_sweep(rng, ndt, bt, nat, k):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("t", SOLVE_TILES)
 @pytest.mark.parametrize("start_tile", [1, 3, 6])
-def test_band_forward_sweep_start_tile(rng, start_tile):
+def test_band_forward_sweep_start_tile(rng, start_tile, t):
     """Rows above start_tile come out identically zero on both backends,
     even when the RHS is nonzero there (the reference never writes them)."""
-    ndt, bt, nat, t, k = 7, 2, 1, 8, 4
+    ndt, bt, nat, k = 7, 2, 1, 4
     Dr, R = _band_factor(rng, ndt, bt, nat, t)
     bd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
     yd, acca = band_forward_sweep_pallas(Dr, R, bd, start_tile=start_tile,
@@ -156,10 +160,10 @@ def test_band_forward_sweep_start_tile(rng, start_tile):
     assert np.abs(np.asarray(yd[:start_tile])).max() == 0.0
 
 
+@pytest.mark.parametrize("t", SOLVE_TILES)
 @pytest.mark.parametrize("ndt,bt,nat", SWEEP_GRIDS)
 @pytest.mark.parametrize("k", [1, 13])
-def test_band_backward_sweep(rng, ndt, bt, nat, k):
-    t = 8
+def test_band_backward_sweep(rng, ndt, bt, nat, k, t):
     Dr, R = _band_factor(rng, ndt, bt, nat, t)
     yd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)
     xa = jnp.asarray(rng.standard_normal((nat, t, k)), jnp.float32)
@@ -169,10 +173,11 @@ def test_band_backward_sweep(rng, ndt, bt, nat, k):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_band_sweeps_vmap(rng):
+@pytest.mark.parametrize("t", SOLVE_TILES)
+def test_band_sweeps_vmap(rng, t):
     """Batched factors (concurrent_solve's shape) ride the fused kernels
     through jax.vmap; the shared RHS panel is broadcast."""
-    ndt, bt, nat, t, k, nb = 6, 2, 1, 8, 5, 3
+    ndt, bt, nat, k, nb = 6, 2, 1, 5, 3
     Drs, Rs = zip(*[_band_factor(rng, ndt, bt, nat, t) for _ in range(nb)])
     Drb, Rb = jnp.stack(Drs), jnp.stack(Rs)
     bd = jnp.asarray(rng.standard_normal((ndt, t, k)), jnp.float32)

@@ -196,3 +196,74 @@ def test_t128_factorization_span_tags_the_tile_block():
              if s["name"] == "factorize.window_batched"]
     assert span["tags"]["sweep"] == "fused"
     assert span["tags"]["tile_block"] == 32
+
+
+# ------------------------------------------------------ band solves, t = 64
+
+ST = 64
+
+
+def _t64_problem(cond, seed):
+    """A banded-arrowhead SPD matrix at t = 64 (ndt 5, bt 2, one arrow
+    tile), well conditioned or with cond about ``cond``."""
+    n, bw, ar = 5 * ST + 16, 2 * ST - 8, 16
+    if cond is None:
+        a, st = make_arrowhead(n, bw, ar, rho=0.6, seed=seed)
+    else:
+        a1, st = near_singular_arrowhead(n, bw, ar, seed=seed, eig_min=1.0)
+        spread = np.linalg.eigvalsh(a1.toarray())[-1] - 1.0
+        a, st = near_singular_arrowhead(n, bw, ar, seed=seed,
+                                        eig_min=spread / (cond - 1.0))
+    grid = TileGrid(st, t=ST)
+    assert (grid.n_diag_tiles, grid.band_tiles, grid.n_arrow_tiles) \
+        == (5, 2, 1)
+    return a.toarray(), grid, api.BandedCTSF.from_sparse(a, grid)
+
+
+@pytest.mark.parametrize("cond", [None, 1e4])
+def test_blocked_band_solve_accuracy(cond):
+    """One factor (``ref`` backend) solved by the Pallas band sweeps, whose
+    diagonal tiles take the blocked L⁻¹ and one product at t = 64, and by
+    the ``ref`` sweeps' triangular solves: against float64
+    ``np.linalg.solve`` the blocked solve errs at most twice as much.
+    Errors are summed over four seeds, as for the tile routines above."""
+    assert tile_block(ST) < ST
+    errs = np.zeros(2)
+    for seed in SEEDS:
+        a64, grid, bm = _t64_problem(cond, seed)
+        f = api.factorize_window(bm, options=api.SolverOptions(impl="ref"))
+        b = np.random.default_rng(seed).standard_normal((a64.shape[0], 3))
+        rows = grid.padded_index(np.arange(a64.shape[0]))
+        B = np.zeros((grid.padded_n, 3), np.float32)
+        B[rows] = b
+        want = np.linalg.solve(a64, b)
+        for i, impl in enumerate(("pallas", "ref")):
+            x = api.solve_many(f, jnp.asarray(B),
+                               options=api.SolverOptions(impl=impl))
+            errs[i] += _rel(np.asarray(x)[rows], want)
+    assert np.all(np.isfinite(errs))
+    assert errs[0] <= 2.0 * errs[1], errs
+
+
+@pytest.mark.parametrize("t", [8, ST])
+def test_concurrent_solve_span_tags_the_tile_block(t):
+    """``concurrent.solve`` on the Pallas backend records the row block
+    its band sweeps solve the diagonal tiles by: 32 at t = 64, where the
+    blocked inverse runs, t at t = 8, where the substitution does."""
+    a, st = make_arrowhead(3 * t + 4, t + 4, 4, rho=0.6, seed=0)
+    grid = TileGrid(st, t=t)
+    bm = api.BandedCTSF.from_sparse(a, grid)
+    f = api.factorize_window_batched(
+        [bm, bm], options=api.SolverOptions(impl="ref"))
+    telemetry.reset()
+    with telemetry.capture():
+        api.concurrent_solve(f, jnp.ones((grid.padded_n,), jnp.float32),
+                             options=api.SolverOptions(impl="pallas"))
+        api.concurrent_solve(f, jnp.ones((grid.padded_n,), jnp.float32),
+                             options=api.SolverOptions(impl="ref"))
+        snap = telemetry.snapshot()
+    telemetry.reset()
+    pallas, ref_ = [s for s in snap["spans"]
+                    if s["name"] == "concurrent.solve"]
+    assert pallas["tags"]["tile_block"] == (32 if t == ST else t)
+    assert "tile_block" not in ref_["tags"]

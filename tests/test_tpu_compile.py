@@ -9,7 +9,8 @@ ID 19's 118 band tiles with one arrow tile, where the streamed Cholesky
 sweep takes over) at a small number of diagonal tiles; the grid length
 does not change what Mosaic checks.  At t=128 every Cholesky sweep
 finishes its columns with the blocked diagonal-tile routines
-(``potrf.tile_block``).
+(``potrf.tile_block``), and the band solves solve their diagonal tiles
+with the blocked inverse.
 """
 import functools
 
@@ -72,20 +73,26 @@ def test_blocked_potrf_tile_compiles(shape):
     _compile(lambda a: potrf_pallas(a, interpret=False), shape(NDT, T, T))
 
 
+# the band solves' right-hand sides: a θ probe's one, and a panel
+SOLVE_KS = [1, K]
+
+
+@pytest.mark.parametrize("k", SOLVE_KS)
 @pytest.mark.parametrize("bt", WIDTHS)
-def test_band_forward_sweep_compiles(shape, bt):
+def test_band_forward_sweep_compiles(shape, bt, k):
     _compile(lambda d, r, b, s: band_forward_sweep_pallas(
         d, r, b, s, interpret=False),
-        shape(NDT, bt + 1, T, T), shape(NDT, NAT, T, T), shape(NDT, T, K),
+        shape(NDT, bt + 1, T, T), shape(NDT, NAT, T, T), shape(NDT, T, k),
         shape(dtype=jnp.int32))
 
 
+@pytest.mark.parametrize("k", SOLVE_KS)
 @pytest.mark.parametrize("bt", WIDTHS)
-def test_band_backward_sweep_compiles(shape, bt):
+def test_band_backward_sweep_compiles(shape, bt, k):
     _compile(lambda d, r, y, x, s: band_backward_sweep_pallas(
         d, r, y, x, s, interpret=False),
-        shape(NDT, bt + 1, T, T), shape(NDT, NAT, T, T), shape(NDT, T, K),
-        shape(NAT, T, K), shape(dtype=jnp.int32))
+        shape(NDT, bt + 1, T, T), shape(NDT, NAT, T, T), shape(NDT, T, k),
+        shape(NAT, T, k), shape(dtype=jnp.int32))
 
 
 @pytest.mark.parametrize("bt", WIDTHS)
